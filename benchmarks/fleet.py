@@ -58,6 +58,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.flexibits import iss
 from repro.flexibits.asm import Asm
 from repro.fleet import array_source, run_stream
@@ -642,6 +643,31 @@ SWEEP_FIELDS = ("mean", "p50", "p90", "p99", "min", "max", "mean_emb",
                 "mean_op", "fleet_mean", "counts", "hist")
 
 
+def planner_sweep_spec(draws: int = 64):
+    """The planning space of the planner-sweep study: 4 lifetime
+    distributions x 5 task frequencies x 4 grid intensities x 3 fleet
+    volumes x all 11 workloads x 3 timing modes = 7,920 cells, times
+    `draws` Monte Carlo lifetime draws (506,880 scenarios at 64)."""
+    from repro.core.sweep import LifetimeDist, workload_spec
+
+    day = 86_400.0
+    dists = (
+        LifetimeDist.point(30 * day),
+        LifetimeDist.lognormal(100 * day, 1.8),
+        LifetimeDist.weibull(300 * day, 1.5),
+        LifetimeDist.mixture(
+            [(LifetimeDist.point(10 * day), 0.5),
+             (LifetimeDist.lognormal(1000 * day, 0.8), 0.5)]),
+    )
+    return workload_spec(
+        dists=dists,
+        execs_per_day=(1.0, 24.0, 96.0, 960.0, 8640.0),
+        intensities=(0.05, 0.233, 0.367, 0.7),
+        volumes=(1e3, 1e6, 1e9),
+        timing=("base", "dynamic", "wcet"),
+        draws=draws, seed=0)
+
+
 def fleet_planner_sweep(draws: int = 64, tile_cells: int = 1024,
                         n_ref: int = 200):
     """Device-resident Monte Carlo carbon-planner sweep (DESIGN.md
@@ -668,27 +694,12 @@ def fleet_planner_sweep(draws: int = 64, tile_cells: int = 1024,
 
     from repro.core.selection import optimal_core, selection_map, \
         total_grid
-    from repro.core.sweep import (LifetimeDist, SweepSpec, run_sweep,
-                                  workload_spec)
+    from repro.core.sweep import LifetimeDist, SweepSpec, run_sweep
     from repro.flexibits.cycles import CORES
 
     day = 86_400.0
     reps = 3
-    dists = (
-        LifetimeDist.point(30 * day),
-        LifetimeDist.lognormal(100 * day, 1.8),
-        LifetimeDist.weibull(300 * day, 1.5),
-        LifetimeDist.mixture(
-            [(LifetimeDist.point(10 * day), 0.5),
-             (LifetimeDist.lognormal(1000 * day, 0.8), 0.5)]),
-    )
-    spec = workload_spec(
-        dists=dists,
-        execs_per_day=(1.0, 24.0, 96.0, 960.0, 8640.0),
-        intensities=(0.05, 0.233, 0.367, 0.7),
-        volumes=(1e3, 1e6, 1e9),
-        timing=("base", "dynamic", "wcet"),
-        draws=draws, seed=0)
+    spec = planner_sweep_spec(draws)
 
     run_sweep(spec, path="jnp", tile_cells=tile_cells)  # compile warm-up
     res = None
@@ -737,7 +748,7 @@ def fleet_planner_sweep(draws: int = 64, tile_cells: int = 1024,
                     np.asarray(pfreqs))
     smap = selection_map(spec.profiles[0], np.asarray(point_lifes),
                          np.asarray(pfreqs))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         pres = run_sweep(pspec, path="jnp", tile_cells=5,
                          dtype=np.float64)
     sq = np.s_[:, :, 0, 0, 0, 0, 0]
@@ -851,6 +862,10 @@ def fleet_device_scaling(counts=(1, 2, 4, 8), items_per_dev: int = 256,
     """
     def worker(n_dev: int, spec: dict) -> dict:
         env = dict(os.environ)
+        # forced host devices are a CPU construct; pinning the child to
+        # the CPU backend also keeps it off an accelerator this process
+        # may already hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={n_dev}")
@@ -909,6 +924,7 @@ def fleet_device_scaling(counts=(1, 2, 4, 8), items_per_dev: int = 256,
         "points": points, "speedup_vs_1dev": speedups,
         "bit_exact": bit_exact,
         "min_oversubscribed_efficiency": min(effs),
+        "platform": "cpu (forced host devices)",
         "basis": "weak scaling; speedup from per-shard dedicated-device "
                  "replay (collective-free loop => replay wall == "
                  "dedicated-node wall, DESIGN.md §9.12); raw "
@@ -919,6 +935,7 @@ def fleet_device_scaling(counts=(1, 2, 4, 8), items_per_dev: int = 256,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--items", type=int, default=1024)
     ap.add_argument("--chunk", type=int, default=128)
@@ -1040,7 +1057,8 @@ def main():
         print(f"\n{'metric':<22} {'speedup':>14} {'oversub eff':>14}")
         for name, sp, eff in sc_rows:
             print(f"{name:<22} {sp:>14} {eff:>14}")
-        print(f"device scaling (§9.12): replay-basis speedups "
+        print(f"device scaling (§9.12, CPU forced host devices): "
+              f"replay-basis speedups "
               f"{[round(s, 2) for s in sc['speedup_vs_1dev']]}, "
               f"bit-exact={sc['bit_exact']}, min oversubscribed "
               f"efficiency {sc['min_oversubscribed_efficiency']:.2f}")

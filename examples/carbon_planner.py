@@ -28,6 +28,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.planner import plan_grid
 from repro.core.selection import crossover_lifetimes
 from repro.core.sweep import (DAY_S, YEAR_S, LifetimeDist, run_sweep,
@@ -149,7 +150,7 @@ def serving_demo() -> None:
     kw = dict(n_params=8e9, kv_bytes_per_token=kv,
               lifetimes_days=np.array([7.0, 90.0, 3 * 365.0]),
               qps_grid=np.logspace(2, 6, 9))
-    with jax.experimental.enable_x64():   # bit-equality needs float64
+    with jax.enable_x64(True):   # bit-equality needs float64
         plan = serving_plan_jnp(**kw)
     ref = plan_grid(**kw)
     ok = all(np.array_equal(np.asarray(plan[k]), ref[k])
@@ -169,6 +170,7 @@ def serving_demo() -> None:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         description="Monte Carlo what-if carbon planner (§9.13)")
     ap.add_argument("--workloads", default="CT,WQ,GR",

@@ -14,11 +14,13 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.fleet import REFILLS, STEPPERS, FleetGroup, FleetPlan, run_plan
 from repro.launch.mesh import make_host_mesh
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--items", type=int, default=256,
                     help="items per group")

@@ -11,6 +11,10 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro import compile_cache
+
+compile_cache.enable()
+
 # ---------------------------------------------------------------- 1. carbon
 from repro.core.selection import optimal_core
 from repro.core.carbon import DeviceProfile

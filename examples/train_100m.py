@@ -9,6 +9,7 @@ Run:  PYTHONPATH=src python examples/train_100m.py --steps 300
 """
 import argparse
 
+from repro import compile_cache
 from repro.configs.qwen2_1_5b import CONFIG
 from repro.launch.train import train_loop
 
@@ -25,6 +26,7 @@ CFG_100M = CONFIG.replace(
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

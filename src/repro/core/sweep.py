@@ -35,7 +35,7 @@ scenarios instead of items):
 - **Oracles kept.** The numpy `selection.total_grid` / `planner.plan_grid`
   grids stay as host oracles: on point-mass lifetime distributions the
   sweep's totals/argmin equal `total_grid`/`selection_map` bit-for-bit
-  (float64 + `jax.experimental.enable_x64`), and `serving_plan_jnp`
+  (float64 + `jax.enable_x64(True)`), and `serving_plan_jnp`
   mirrors `plan_grid` exactly on shared grid points.
 
 Timing models ride in as a scenario axis: "base" prices the two-bucket
@@ -596,13 +596,13 @@ def run_sweep(spec: SweepSpec, *, path: str = "jnp",
     global int32 histogram flushes into a host int64 tally (and the
     Pareto accumulator merges host-side) every `flush_limit` scenarios,
     so counts can never wrap. float64 sweeps (the oracle-parity mode)
-    require `jax.experimental.enable_x64` around the call.
+    require `jax.enable_x64(True)` around the call.
     """
     spec.validate()
     dtype = np.dtype(dtype)
     if dtype == np.float64 and not jax.config.jax_enable_x64:
-        raise ValueError("float64 sweeps need jax.experimental."
-                         "enable_x64() around run_sweep")
+        raise ValueError("float64 sweeps need jax.enable_x64(True) "
+                         "around run_sweep")
     n_cells = spec.n_cells
     tile = max(1, min(tile_cells, n_cells))
     step, tables = _sweep_step(spec, tile, path, dtype.name, n_hist,

@@ -17,7 +17,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -50,7 +50,7 @@ def compressed_allreduce(grads, residuals, mesh: Mesh, axis: str = "data"):
         @functools.partial(
             shard_map, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False)
+            check_vma=False)
         def reduce_fn(g_local, r_local):
             q, scale, new_r = ef_quantize(g_local, r_local)
             # the int8 payload + fp32 scale are what cross the links

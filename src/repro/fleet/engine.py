@@ -58,7 +58,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed import checkpoint as dckpt
@@ -711,7 +711,7 @@ def _packed_segment_runner(stepper: str, chunk: int, seg_steps: int,
         lane = P(tuple(mesh.axis_names))
         extra_specs = (lane, lane)
     fn = shard_map(seg, mesh=mesh, in_specs=(*bspecs, *extra_specs, specs),
-                   out_specs=specs, check_rep=False)
+                   out_specs=specs, check_vma=False)
     return jax.jit(fn, donate_argnums=donate)
 
 
@@ -1098,8 +1098,20 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
         fn, mesh=mesh,
         in_specs=(*carry_in, *st_specs, lane, P()),
         out_specs=(*carry_out, P(axes, None)),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn, donate_argnums=donate)
+
+
+def _pool_lanes(chunk: int, stepper: str, n_dev: int, dmr: bool) -> int:
+    """Lane-pool size for a requested `chunk`: a multiple of the device
+    count (of 2x it under DMR, so a lane pair never straddles a shard),
+    and for the fused stepper past 128 lanes a multiple of 128 — the
+    kernel tiles lanes 128 at a time on the TPU's lane axis and runs a
+    smaller pool as one full-width block."""
+    round_to = 2 * n_dev if dmr else n_dev
+    if stepper == "pallas" and chunk > 128:
+        round_to = int(np.lcm(128, round_to))
+    return -(-chunk // round_to) * round_to
 
 
 def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
@@ -1208,6 +1220,12 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
                 "fault injection / DMR is incompatible with "
                 "checkpoint_dir: epoch/retry/snapshot state is not "
                 "part of the durable checkpoint schema")
+    if (faults is not None and stepper == "pallas"
+            and jax.default_backend() == "tpu"):
+        raise ValueError(
+            "fault injection with stepper='pallas' does not compile for "
+            "the TPU: the kernel's fault transform works on lane-minor "
+            "views; use stepper='branchless'")
 
     n_groups = len(groups)
     counts = np.array([g.n_items for g in groups], np.int64)
@@ -1287,14 +1305,7 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     n_dev = 1
     if mesh is not None:
         n_dev = int(np.prod(list(mesh.shape.values())))
-    round_to = 2 * n_dev if dmr else n_dev
-    if stepper == "pallas" and chunk > 128:
-        # same wide-lane-tile rule as run_stream: pad the pool to a
-        # 128-multiple (lcm'd with the mesh/pair alignment) instead of
-        # tiling at a prime-ish chunk's largest small divisor
-        round_to = int(np.lcm(128, round_to))
-    if round_to > 1:
-        chunk = -(-chunk // round_to) * round_to
+    chunk = _pool_lanes(chunk, stepper, n_dev, dmr)
 
     clock = _SyncClock()
     controller = _SuperstepController(seg_steps, chunk, adaptive)

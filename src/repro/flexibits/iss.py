@@ -359,8 +359,37 @@ class DecodedInstr(NamedTuple):
     imm_j: jax.Array
 
 
+# The shared helpers compute in int32 only: the uint32 operations of
+# `step` become logical shifts and sign-flipped compares on the same
+# bits, which Mosaic lowers for the TPU (its uint32 support is partial).
+
+def _srl(v, n):
+    """Logical right shift of int32 bits (`step`'s uint32 `>>`)."""
+    v, n = jnp.broadcast_arrays(v, jnp.asarray(n, I32))
+    return lax.shift_right_logical(v, n)
+
+
+def _ult(a, b):
+    """Unsigned less-than of int32 bit patterns."""
+    sign = jnp.asarray(-2**31, I32)
+    return (a ^ sign) < (b ^ sign)
+
+
+def _select(conds, vals, default):
+    """First-match select (`jnp.select` semantics) as a chain of
+    elementwise wheres; boolean values select through logic ops, which
+    Mosaic lowers where a select between masks it does not."""
+    out = default
+    for c, v in zip(reversed(conds), reversed(vals)):
+        if out.dtype == jnp.bool_:
+            out = (c & v) | (~c & out)
+        else:
+            out = jnp.where(c, v, out)
+    return out
+
+
 def decode_fields(instr: jax.Array) -> DecodedInstr:
-    """Bit-op decode of fetched instruction word(s) (uint32 in)."""
+    """Bit-op decode of fetched instruction word(s) (uint32 or int32)."""
     ii = instr.astype(I32)
     return DecodedInstr(
         op=ii & 0x7F,
@@ -369,9 +398,8 @@ def decode_fields(instr: jax.Array) -> DecodedInstr:
         rs1=(ii >> 15) & 0xF,
         rs2=(ii >> 20) & 0xF,
         sub_bit=(ii >> 30) & 1,
-        imm_i=_sx(_u(instr) >> 20, 12),
-        imm_s=_sx(((_u(instr) >> 25) << 5).astype(I32)
-                  | ((ii >> 7) & 0x1F), 12),
+        imm_i=_sx(_srl(ii, 20), 12),
+        imm_s=_sx((_srl(ii, 25) << 5) | ((ii >> 7) & 0x1F), 12),
         imm_b=_sx(((ii >> 31) & 1) << 12 | ((ii >> 7) & 1) << 11
                   | ((ii >> 25) & 0x3F) << 5 | ((ii >> 8) & 0xF) << 1, 13),
         imm_u=ii & jnp.asarray(-4096, I32),
@@ -382,54 +410,48 @@ def decode_fields(instr: jax.Array) -> DecodedInstr:
 
 def alu_result(a, y, f3, is_sub, is_sra):
     """Shared OP-IMM/OP-REG ALU: f3-selected branchless result."""
-    au = _u(a)
-    sh = (y & 31).astype(U32)
-    return jnp.select(
+    sh = y & 31
+    return _select(
         [f3 == 0, f3 == 1, f3 == 2, f3 == 3, f3 == 4, f3 == 5, f3 == 6],
         [jnp.where(is_sub, a - y, a + y),
-         (au << sh).astype(I32),
+         a << sh,
          (a < y).astype(I32),
-         (au < _u(y)).astype(I32),
+         _ult(a, y).astype(I32),
          a ^ y,
-         jnp.where(is_sra, a >> (y & 31), (au >> sh).astype(I32)),
+         jnp.where(is_sra, a >> sh, _srl(a, sh)),
          a | y], a & y)
 
 
 def branch_taken(a, b, f3):
     """BRANCH condition select (f3 in {2,3} never taken, as in `step`)."""
     false = jnp.zeros_like(a, bool)
-    au, bu = _u(a), _u(b)
-    return jnp.select(
+    ult = _ult(a, b)
+    return _select(
         [f3 == 0, f3 == 1, f3 == 2, f3 == 3, f3 == 4, f3 == 5, f3 == 6],
-        [a == b, a != b, false, false, a < b, a >= b, au < bu],
-        au >= bu)
+        [a == b, a != b, false, false, a < b, a >= b, ult], ~ult)
 
 
 def load_value(word, addr, f3):
     """Sub-word load extraction from the fetched memory word."""
-    sh8 = ((addr & 3) * 8).astype(U32)
-    sh16 = ((addr & 2) * 8).astype(U32)
-    byte = (_u(word) >> sh8).astype(I32) & 0xFF
-    half = (_u(word) >> sh16).astype(I32) & 0xFFFF
+    byte = _srl(word, (addr & 3) * 8) & 0xFF
+    half = _srl(word, (addr & 2) * 8) & 0xFFFF
     lf3 = jnp.clip(f3, 0, 5)       # matches step's clipped switch
-    return jnp.select(
+    return _select(
         [lf3 == 0, lf3 == 1, lf3 == 4, lf3 == 5],
         [_sx(byte, 8), _sx(half, 16), byte, half], word)
 
 
 def store_word(word, addr, b, f3):
     """Read-modify-write merge of the store value into the memory word."""
-    sh8 = ((addr & 3) * 8).astype(U32)
-    sh16 = ((addr & 2) * 8).astype(U32)
-    bmask = (jnp.asarray(0xFF, U32) << sh8).astype(I32)
-    hmask = (jnp.asarray(0xFFFF, U32) << sh16).astype(I32)
+    sh8 = (addr & 3) * 8
+    sh16 = (addr & 2) * 8
+    bmask = jnp.left_shift(jnp.asarray(0xFF, I32), sh8)
+    hmask = jnp.left_shift(jnp.asarray(0xFFFF, I32), sh16)
     sf3 = jnp.clip(f3, 0, 2)
-    return jnp.select(
+    return _select(
         [sf3 == 0, sf3 == 1],
-        [(word & ~bmask) | (((b & 0xFF).astype(U32) << sh8
-                             ).astype(I32) & bmask),
-         (word & ~hmask) | (((b & 0xFFFF).astype(U32) << sh16
-                             ).astype(I32) & hmask)], b)
+        [(word & ~bmask) | (((b & 0xFF) << sh8) & bmask),
+         (word & ~hmask) | (((b & 0xFFFF) << sh16) & hmask)], b)
 
 
 def branchless_commits(d: DecodedInstr, a, b, pc, subset, live, *,
@@ -474,7 +496,7 @@ def branchless_commits(d: DecodedInstr, a, b, pc, subset, live, *,
     mem = None
     if on(isa.OP_LOAD, isa.OP_STORE):
         addr = (a + jnp.where(is_store, d.imm_s, d.imm_i)).astype(I32)
-        widx = jnp.where(is_load | is_store, _u(addr).astype(I32) >> 2, 0)
+        widx = jnp.where(is_load | is_store, addr >> 2, 0)
         word = read_word(widx)
         if on(isa.OP_LOAD):
             mem_val = load_value(word, addr, f3)
@@ -541,7 +563,7 @@ def classify(op, f3):
                  | (op == isa.OP_BRANCH) | (op == isa.OP_JAL)
                  | (op == isa.OP_JALR) | is_shift_imm | is_shift_reg
                  | is_slt)
-    mix_idx = jnp.select(
+    mix_idx = _select(
         [op == isa.OP_LOAD, op == isa.OP_STORE, op == isa.OP_BRANCH,
          (op == isa.OP_JAL) | (op == isa.OP_JALR),
          is_shift_imm | is_shift_reg,
@@ -550,7 +572,7 @@ def classify(op, f3):
         [_MIX_IDX["loads"], _MIX_IDX["stores"], _MIX_IDX["branches"],
          _MIX_IDX["jumps"], _MIX_IDX["shifts"], _MIX_IDX["I-type"],
          _MIX_IDX["R-type"]],
-        _MIX_IDX["system"])
+        jnp.full_like(op, _MIX_IDX["system"]))
     return two_stage, mix_idx
 
 
@@ -598,22 +620,24 @@ def dynamic_terms(op, f3, a, b, imm_i, subset: frozenset = None):
 
 
 def timing_ticks(cost, two_stage, mix_idx, taken, shamt, subword):
-    """Ticks retired by one instruction under cost row(s) `cost`.
+    """Ticks retired by one instruction under cost row `cost`.
 
-    `cost` is (..., N_COST): one shared row, or per-lane rows gathered
-    from a per-program cost bank. The (stage, mix-class) base entry is
-    selected with a one-hot reduction over the 8 classes (no gathers —
-    the same trick as the register/mix commits, so the Pallas stepper
-    runs it unchanged), then the dynamic entries are added in.
+    `cost[i]` is cost entry i, broadcastable against `mix_idx`: one
+    (N_COST,) row (the XLA steppers, per lane under vmap), or a
+    sequence of per-entry lane arrays (the Pallas stepper). The (stage,
+    mix-class) base entry is picked by a select chain over the 8
+    classes (no gathers, so the Pallas stepper runs it unchanged), then
+    the dynamic entries are added in.
     """
     n = len(MIX_CLASSES)
-    oh = jnp.arange(n, dtype=I32) == mix_idx[..., None]
-    one_base = jnp.sum(jnp.where(oh, cost[..., :n], 0), axis=-1)
-    two_base = jnp.sum(jnp.where(oh, cost[..., n:2 * n], 0), axis=-1)
+    classes = [mix_idx == k for k in range(n)]
+    zero = jnp.zeros_like(mix_idx)
+    one_base = _select(classes, [cost[k] for k in range(n)], zero)
+    two_base = _select(classes, [cost[n + k] for k in range(n)], zero)
     base = jnp.where(two_stage, two_base, one_base)
-    return (base + taken.astype(I32) * cost[..., TAKEN_IDX]
-            + shamt * cost[..., SHIFT_IDX]
-            + subword.astype(I32) * cost[..., SUBWORD_IDX])
+    return (base + taken.astype(I32) * cost[TAKEN_IDX]
+            + shamt * cost[SHIFT_IDX]
+            + subword.astype(I32) * cost[SUBWORD_IDX])
 
 
 def opcode_subset(code, reachable_only: bool = False) -> frozenset:

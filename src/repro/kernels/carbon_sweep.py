@@ -40,8 +40,11 @@ themselves are evaluated in exactly the numpy oracle's op order
 ``base = kwh * intensity``), so on point-mass lifetime draws the sweep
 is bit-equal to the host planner grid as well.
 
-CPU fallback follows the package convention (`iss_stepper.py`,
-`bitplane_matmul.py`): off-TPU the kernel defaults to `interpret=True`.
+As in `iss_stepper.py`, the kernel always compiles to Mosaic on a TPU
+and defaults to the Pallas interpreter on any other backend. The
+bit-exactness contract holds on the CPU; on the TPU the two paths lower
+their float reductions differently (`chip_smoke.py` reports whether
+they agree there).
 """
 from __future__ import annotations
 
@@ -50,20 +53,21 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 I32 = jnp.int32
 _IMAX = jnp.iinfo(jnp.int32).max
 
 
-def _pick_row_tile(n_rows: int, want: Optional[int]) -> int:
-    """Largest divisor of `n_rows` <= the requested row tile (the
-    `iss_stepper._pick_lane_tile` rule on the cell axis)."""
-    want = n_rows if want is None else max(1, min(want, n_rows))
-    for d in range(want, 0, -1):
+def _pick_row_tile(n_rows: int) -> int:
+    """Cells per grid step. Cells run along the TPU's sublane axis, so the
+    tile is the largest multiple of 8 up to 128 that divides the cell
+    count, or the whole tile when none does."""
+    for d in range(128, 0, -8):
         if n_rows % d == 0:
             return d
-    return 1
+    return n_rows
 
 
 class SweepAcc(NamedTuple):
@@ -110,8 +114,29 @@ def init_acc(n_hist: int, n_pareto: int, dtype) -> SweepAcc:
 
 
 # --------------------------------------------------- shared arithmetic
+# Layout shared by both paths: cells run down the rows, per-cell values
+# are (Tc, 1) columns, draws run along the last axis, and the candidate
+# axis (a handful of cores) is unrolled into lists of per-candidate
+# arrays. Everything is 2-D, and the argmin, gathers and histogram are
+# select chains and reductions, which Mosaic lowers.
+
+def _cols(x):
+    """(Tc, C) -> C per-candidate (Tc, 1) columns."""
+    return [x[:, c:c + 1] for c in range(x.shape[1])]
+
+
+def _place(vals, shape, dtype):
+    """Scatter `vals[i]` (broadcastable) into column i of a `shape`
+    array — the inverse of `_cols`, as a select chain."""
+    iota = lax.broadcasted_iota(I32, shape, 1)
+    out = jnp.zeros(shape, dtype)
+    for i, v in enumerate(vals):
+        out = jnp.where(iota == i, v, out)
+    return out
+
+
 def _totals(emb, kwh, inten, freq, life_days):
-    """Total/embodied/operational surfaces over (cells, draws, cores).
+    """Per-candidate total and operational surfaces over (cells, draws).
 
     EXACTLY the numpy oracle's op order (`selection.total_grid`):
     ``base = kwh * intensity``; ``total = emb + (base * life_days) *
@@ -122,36 +147,42 @@ def _totals(emb, kwh, inten, freq, life_days):
     bits; the remaining ops here are pure multiply chains and a
     contraction-blocked add, which XLA CPU leaves bit-stable.
     """
-    base = kwh * inten[:, None]                       # (Tc, C)
-    op = (base[:, None, :] * life_days[:, :, None]) * freq[:, None, None]
-    # `abs` is a bitwise identity here (op >= 0 always) whose only job
-    # is to break the fadd(fmul) pattern: XLA CPU otherwise contracts
-    # `emb + op` into an FMA, which rounds differently from the numpy
-    # oracle's separate multiply-then-add
-    total = emb[:, None, :] + jnp.abs(op)
-    return total, op
+    totals, ops = [], []
+    for e, k in zip(_cols(emb), _cols(kwh)):
+        op = ((k * inten) * life_days) * freq
+        # `abs` is a bitwise identity here (op >= 0 always) whose only
+        # job is to break the fadd(fmul) pattern: XLA CPU otherwise
+        # contracts `emb + op` into an FMA, which rounds differently
+        # from the numpy oracle's separate multiply-then-add
+        totals.append(e + jnp.abs(op))
+        ops.append(op)
+    return totals, ops
 
 
-def _cell_reduce(total, op, emb, n_cores) -> TileOut:
+def _cell_reduce(totals, ops, emb):
     """argmin core selection + per-cell reductions over the draw axis."""
-    best_core = jnp.argmin(total, axis=-1).astype(I32)   # first-min ties
-    sel = best_core[..., None]
-    best_total = jnp.take_along_axis(total, sel, axis=-1)[..., 0]
-    best_op = jnp.take_along_axis(op, sel, axis=-1)[..., 0]
-    best_emb = jnp.take_along_axis(
-        jnp.broadcast_to(emb[:, None, :], total.shape), sel, axis=-1)[..., 0]
-    onehot = (best_core[..., None]
-              == jnp.arange(n_cores, dtype=I32)).astype(I32)
+    embs = _cols(emb)
+    best_total, best_op = totals[0], ops[0]
+    best_emb = jnp.broadcast_to(embs[0], best_total.shape)
+    best_core = jnp.zeros(best_total.shape, I32)
+    for c in range(1, len(totals)):
+        better = totals[c] < best_total              # first-min ties
+        best_total = jnp.where(better, totals[c], best_total)
+        best_op = jnp.where(better, ops[c], best_op)
+        best_emb = jnp.where(better, embs[c], best_emb)
+        best_core = jnp.where(better, c, best_core)
+    counts = [jnp.sum((best_core == c).astype(I32), axis=1, keepdims=True)
+              for c in range(len(totals))]
     return TileOut(
         best_total=best_total,
         best_core=best_core,
-        counts=jnp.sum(onehot, axis=1, dtype=I32),
-        sum_best=jnp.sum(best_total, axis=1),
-        min_best=jnp.min(best_total, axis=1),
-        max_best=jnp.max(best_total, axis=1),
-        sum_emb=jnp.sum(best_emb, axis=1),
-        sum_op=jnp.sum(best_op, axis=1),
-    ), best_emb, best_op
+        counts=_place(counts, emb.shape, I32),
+        sum_best=jnp.sum(best_total, axis=1, keepdims=True),
+        min_best=jnp.min(best_total, axis=1, keepdims=True),
+        max_best=jnp.max(best_total, axis=1, keepdims=True),
+        sum_emb=jnp.sum(best_emb, axis=1, keepdims=True),
+        sum_op=jnp.sum(best_op, axis=1, keepdims=True),
+    ), best_op
 
 
 def _log_bin(x, lo, inv, n_bins):
@@ -161,17 +192,13 @@ def _log_bin(x, lo, inv, n_bins):
 
 
 def _hist_contrib(best_total, valid, lo, inv, n_bins):
-    """Scatter-add histogram of the tile's best totals.
-
-    Integer adds are exact and order-free, so the scatter is
-    bit-identical to a one-hot reduction at any tile size (and ~2.7x
-    faster on CPU than materializing the (cells, draws, bins) one-hot).
-    Runs under the interpret-mode Pallas path as plain XLA scatter.
-    """
+    """(1, B) histogram of the tile's valid best totals: one masked
+    count per bin (integer adds are exact, so any grouping of cells
+    into tiles sums to the same counts)."""
     bins = _log_bin(best_total, lo, inv, n_bins)        # (Tc, N)
-    w = jnp.broadcast_to(valid[:, None], bins.shape).astype(I32)
-    return jnp.zeros((n_bins,), I32).at[bins.reshape(-1)].add(
-        w.reshape(-1))                                  # (B,)
+    hits = [jnp.sum(((bins == b) & valid).astype(I32))
+            for b in range(n_bins)]
+    return _place(hits, (1, n_bins), I32)
 
 
 def _pareto_candidate(emb, best_op, life_days, cell_idx, best_core,
@@ -189,56 +216,64 @@ def _pareto_candidate(emb, best_op, life_days, cell_idx, best_core,
     draws that actually chose that core), then the per-bin min runs
     over the (cells x cores) champions instead of (cells x draws)
     scenarios. A lexicographic min over any partition equals the global
-    min, so this is bit-identical to the flat reduction.
+    min, so this is bit-identical to the flat reduction. Returns (1, B)
+    rows.
     """
-    n_cells, n_draws = best_op.shape
-    n_cores = emb.shape[1]
+    n_cells = best_op.shape[0]
     inf = jnp.array(jnp.inf, best_op.dtype)
-    # level 1: per-(cell, core) champion draw
-    chose = best_core[..., None] == jnp.arange(n_cores, dtype=I32)
-    opm = jnp.where(chose, best_op[..., None], inf)     # (Tc, N, C)
-    op_cc = jnp.min(opm, axis=1)                        # (Tc, C)
-    tie = chose & (opm == op_cc[:, None, :])
-    drawm = jnp.where(tie, jnp.arange(n_draws, dtype=I32)[None, :, None],
-                      _IMAX)
-    draw_cc = jnp.min(drawm, axis=1)                    # (Tc, C)
-    tie = tie & (drawm == draw_cc[:, None, :])          # exactly one draw
-    life_cc = jnp.sum(jnp.where(tie, life_days[..., None], 0), axis=1,
-                      dtype=life_days.dtype)
-    alive = valid[:, None] & (op_cc < inf)              # (Tc, C)
+    iota_draw = lax.broadcasted_iota(I32, best_op.shape, 1)
+    iota_bin = lax.broadcasted_iota(I32, (n_cells, n_bins), 1)
+    embs = _cols(emb)
 
-    # level 2: per-bin lexicographic min over the champions
-    bins = _log_bin(emb, lo, inv, n_bins)               # (Tc, C)
-    cell = jnp.broadcast_to(cell_idx[:, None], bins.shape)
-    mask = (bins[None] == jnp.arange(n_bins, dtype=I32)[:, None, None]) \
-        & alive[None]                                   # (Bp, Tc, C)
-    opb = jnp.where(mask, op_cc[None], inf)
-    op_min = jnp.min(opb, axis=(1, 2))                  # (Bp,)
+    # level 1: per-(cell, core) champion draw, (Tc, 1) columns
+    masks, opbs, draws, lives = [], [], [], []
+    for c, e in enumerate(embs):
+        chose = best_core == c
+        opm = jnp.where(chose, best_op, inf)
+        op_cc = jnp.min(opm, axis=1, keepdims=True)
+        tie = chose & (opm == op_cc)
+        drawm = jnp.where(tie, iota_draw, _IMAX)
+        draw_cc = jnp.min(drawm, axis=1, keepdims=True)
+        tie = tie & (drawm == draw_cc)                  # exactly one draw
+        lives.append(jnp.sum(jnp.where(tie, life_days, 0), axis=1,
+                             keepdims=True))
+        draws.append(draw_cc)
+        alive = valid & (op_cc < inf)
+        mask = (iota_bin == _log_bin(e, lo, inv, n_bins)) & alive
+        masks.append(mask)                              # (Tc, B)
+        opbs.append(jnp.where(mask, op_cc, inf))
+
+    # level 2: per-bin lexicographic min over the champions, (1, B) rows
+    def col_min(vals):
+        out = jnp.min(vals[0], axis=0, keepdims=True)
+        for v in vals[1:]:
+            out = jnp.minimum(out, jnp.min(v, axis=0, keepdims=True))
+        return out
+
+    op_min = col_min(opbs)
     # bins that are empty OR whose best point overflowed to +inf both
     # keep the (inf, IMAX, IMAX) sentinel record
     finite = op_min < inf
-    tie2 = mask & (opb == op_min[:, None, None]) & finite[:, None, None]
-    cellm = jnp.where(tie2, cell[None], _IMAX)
-    cell_min = jnp.min(cellm, axis=(1, 2))
-    tie2 = tie2 & (cellm == cell_min[:, None, None])
-    drawb = jnp.where(tie2, draw_cc[None], _IMAX)
-    draw_min = jnp.min(drawb, axis=(1, 2))
-    tie2 = tie2 & (drawb == draw_min[:, None, None])
+    ties = [m & (o == op_min) & finite for m, o in zip(masks, opbs)]
+    cellm = [jnp.where(t, cell_idx, _IMAX) for t in ties]
+    cell_min = col_min(cellm)
+    ties = [t & (m == cell_min) for t, m in zip(ties, cellm)]
+    drawb = [jnp.where(t, d, _IMAX) for t, d in zip(ties, draws)]
+    draw_min = col_min(drawb)
+    ties = [t & (m == draw_min) for t, m in zip(ties, drawb)]
 
     def pick(vals, empty):
-        # `tie2` selects exactly one champion per bin with a finite
-        # best point; sentinel bins sum to 0 and take `empty`
-        return jnp.sum(jnp.where(tie2, vals[None], 0), axis=(1, 2),
-                       dtype=vals.dtype) \
-            + jnp.where(finite, 0, empty).astype(vals.dtype)
+        # `ties` selects exactly one champion per bin with a finite best
+        # point; sentinel bins sum to 0 and take `empty`
+        out = jnp.where(finite, 0, empty)
+        for t, v in zip(ties, vals):
+            out = out + jnp.sum(jnp.where(t, v, 0), axis=0, keepdims=True)
+        return out
 
-    core_b = jnp.broadcast_to(jnp.arange(n_cores, dtype=I32)[None, :],
-                              bins.shape)
-    return (jnp.where(finite, op_min, inf), pick(emb, inf),
-            pick(life_cc, inf),
-            jnp.where(finite, cell_min, _IMAX),
+    return (jnp.where(finite, op_min, inf), pick(embs, inf),
+            pick(lives, inf), jnp.where(finite, cell_min, _IMAX),
             jnp.where(finite, draw_min, _IMAX),
-            pick(core_b, _IMAX).astype(I32))
+            pick(list(range(len(embs))), _IMAX).astype(I32))
 
 
 def _pareto_merge(a: Tuple, b: Tuple) -> Tuple:
@@ -257,9 +292,8 @@ def _pareto_merge(a: Tuple, b: Tuple) -> Tuple:
 def _eval_tile(emb, kwh, inten, freq, life_days, valid, cell_idx, *,
                hist_lo, hist_inv, par_lo, par_inv, n_hist, n_pareto):
     """Shared per-(sub)tile pipeline used verbatim by both paths."""
-    n_cores = emb.shape[1]
-    total, op = _totals(emb, kwh, inten, freq, life_days)
-    out, best_emb, best_op = _cell_reduce(total, op, emb, n_cores)
+    totals, ops = _totals(emb, kwh, inten, freq, life_days)
+    out, best_op = _cell_reduce(totals, ops, emb)
     hist = _hist_contrib(out.best_total, valid, hist_lo, hist_inv, n_hist)
     cand = _pareto_candidate(emb, best_op, life_days, cell_idx,
                              out.best_core, valid, par_lo, par_inv,
@@ -271,7 +305,7 @@ def _eval_tile(emb, kwh, inten, freq, life_days, valid, cell_idx, *,
 def _sweep_tile_jnp(emb, kwh, inten, freq, life_days, valid, cell_idx,
                     acc: SweepAcc, **kw):
     out, hist, cand = _eval_tile(emb, kwh, inten, freq, life_days,
-                                 valid, cell_idx, **kw)
+                                 valid != 0, cell_idx, **kw)
     par = _pareto_merge(tuple(acc[1:]), cand)
     return out, SweepAcc(acc.hist + hist, *par)
 
@@ -288,7 +322,7 @@ def _sweep_kernel(emb_ref, kwh_ref, inten_ref, freq_ref, life_ref,
 
     out, hist, cand = _eval_tile(
         emb_ref[...], kwh_ref[...], inten_ref[...], freq_ref[...],
-        life_ref[...], valid_ref[...], cell_ref[...], **kw)
+        life_ref[...], valid_ref[...] != 0, cell_ref[...], **kw)
     bt_ref[...] = out.best_total
     bc_ref[...] = out.best_core
     cnt_ref[...] = out.counts
@@ -321,94 +355,46 @@ def _sweep_kernel(emb_ref, kwh_ref, inten_ref, freq_ref, life_ref,
 
 
 def _sweep_tile_pallas(emb, kwh, inten, freq, life_days, valid,
-                       cell_idx, acc: SweepAcc, row_tile=None,
+                       cell_idx, acc: SweepAcc,
                        interpret=None, **kw):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n_cells, n_draws = life_days.shape
     n_cores = emb.shape[1]
-    n_hist = acc.hist.shape[0]
-    n_par = acc.par_op.shape[0]
     dtype = life_days.dtype
-    rt = _pick_row_tile(n_cells, 128 if row_tile is None else row_tile)
+    rt = _pick_row_tile(n_cells)
 
-    def row(i):
-        return (i,)
+    def rows(width):
+        return pl.BlockSpec((rt, width), lambda i: (i, 0))
 
-    def row2(i):
-        return (i, 0)
-
-    def whole(i):
-        return (0,)
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda i: (0, 0))
 
     outs = pl.pallas_call(
         functools.partial(_sweep_kernel, **kw),
         grid=(n_cells // rt,),
-        in_specs=[
-            pl.BlockSpec((rt, n_cores), row2),     # emb
-            pl.BlockSpec((rt, n_cores), row2),     # kwh
-            pl.BlockSpec((rt,), row),              # intensity
-            pl.BlockSpec((rt,), row),              # freq
-            pl.BlockSpec((rt, n_draws), row2),     # lifetimes
-            pl.BlockSpec((rt,), row),              # valid
-            pl.BlockSpec((rt,), row),              # cell idx
-            pl.BlockSpec((n_hist,), whole),        # running hist
-            pl.BlockSpec((n_par,), whole),         # running pareto x6
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-        ],
-        out_specs=[
-            pl.BlockSpec((rt, n_draws), row2),     # best_total
-            pl.BlockSpec((rt, n_draws), row2),     # best_core
-            pl.BlockSpec((rt, n_cores), row2),     # counts
-            pl.BlockSpec((rt,), row),              # sum_best
-            pl.BlockSpec((rt,), row),              # min_best
-            pl.BlockSpec((rt,), row),              # max_best
-            pl.BlockSpec((rt,), row),              # sum_emb
-            pl.BlockSpec((rt,), row),              # sum_op
-            pl.BlockSpec((n_hist,), whole),        # hist out
-            pl.BlockSpec((n_par,), whole),         # pareto out x6
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-            pl.BlockSpec((n_par,), whole),
-        ],
+        in_specs=[rows(n_cores), rows(n_cores), rows(1), rows(1),
+                  rows(n_draws), rows(1), rows(1)]
+        + [whole(a) for a in acc],
+        out_specs=[rows(n_draws), rows(n_draws), rows(n_cores)]
+        + [rows(1)] * 5 + [whole(a) for a in acc],
         out_shape=[
-            jax.ShapeDtypeStruct((n_cells, n_draws), dtype),
-            jax.ShapeDtypeStruct((n_cells, n_draws), I32),
-            jax.ShapeDtypeStruct((n_cells, n_cores), I32),
-            jax.ShapeDtypeStruct((n_cells,), dtype),
-            jax.ShapeDtypeStruct((n_cells,), dtype),
-            jax.ShapeDtypeStruct((n_cells,), dtype),
-            jax.ShapeDtypeStruct((n_cells,), dtype),
-            jax.ShapeDtypeStruct((n_cells,), dtype),
-            jax.ShapeDtypeStruct((n_hist,), I32),
-            jax.ShapeDtypeStruct((n_par,), dtype),
-            jax.ShapeDtypeStruct((n_par,), dtype),
-            jax.ShapeDtypeStruct((n_par,), dtype),
-            jax.ShapeDtypeStruct((n_par,), I32),
-            jax.ShapeDtypeStruct((n_par,), I32),
-            jax.ShapeDtypeStruct((n_par,), I32),
-        ],
+            jax.ShapeDtypeStruct((n_cells, n_draws), dtype),   # best_total
+            jax.ShapeDtypeStruct((n_cells, n_draws), I32),     # best_core
+            jax.ShapeDtypeStruct((n_cells, n_cores), I32),     # counts
+        ] + [jax.ShapeDtypeStruct((n_cells, 1), dtype)] * 5
+        + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in acc],
         # running accumulators update in place (inputs 7-13 -> outputs
         # 8-14), the iss_stepper donation/aliasing idiom
-        input_output_aliases={7: 8, 8: 9, 9: 10, 10: 11, 11: 12,
-                              12: 13, 13: 14},
+        input_output_aliases={7 + j: 8 + j for j in range(len(acc))},
         interpret=interpret,
-    )(emb, kwh, inten, freq, life_days, valid, cell_idx, acc.hist,
-      acc.par_op, acc.par_emb, acc.par_life, acc.par_cell,
-      acc.par_draw, acc.par_core)
+    )(emb, kwh, inten, freq, life_days, valid, cell_idx, *acc)
     return TileOut(*outs[:8]), SweepAcc(*outs[8:])
 
 
 def sweep_tile(emb, kwh, inten, freq, life_days, valid, cell_idx,
                acc: SweepAcc, *, hist_lo: float, hist_inv: float,
                par_lo: float, par_inv: float, path: str = "jnp",
-               row_tile: Optional[int] = None,
                interpret: Optional[bool] = None):
     """Evaluate-and-reduce one streamed tile of scenario cells.
 
@@ -422,19 +408,27 @@ def sweep_tile(emb, kwh, inten, freq, life_days, valid, cell_idx,
     — per-cell reductions plus the advanced running accumulators.
 
     `path="jnp"` is the pure-broadcast baseline; `path="pallas"` runs
-    the same pipeline as one kernel gridded over row tiles. The paths
-    are bit-identical for identical inputs (tests/test_sweep.py).
+    the same pipeline as one kernel gridded over row tiles (compiled on
+    a TPU, interpreted elsewhere unless `interpret` says otherwise). The
+    paths are bit-identical for identical inputs on CPU
+    (tests/test_sweep.py).
     """
+    if path not in ("jnp", "pallas"):
+        raise ValueError(f"unknown sweep path {path!r} "
+                         f"(expected 'jnp' or 'pallas')")
     kw = dict(hist_lo=hist_lo, hist_inv=hist_inv, par_lo=par_lo,
               par_inv=par_inv, n_hist=acc.hist.shape[0],
               n_pareto=acc.par_op.shape[0])
+    # the shared pipeline's 2-D layout: per-cell columns, (1, B) rows
+    cols = (inten[:, None], freq[:, None], life_days,
+            valid.astype(I32)[:, None], cell_idx.astype(I32)[:, None])
+    acc2 = SweepAcc(*(a[None] for a in acc))
     if path == "jnp":
-        return _sweep_tile_jnp(emb, kwh, inten, freq, life_days, valid,
-                               cell_idx, acc, **kw)
-    if path == "pallas":
-        return _sweep_tile_pallas(emb, kwh, inten, freq, life_days,
-                                  valid, cell_idx, acc,
-                                  row_tile=row_tile,
-                                  interpret=interpret, **kw)
-    raise ValueError(f"unknown sweep path {path!r} "
-                     f"(expected 'jnp' or 'pallas')")
+        out, acc2 = _sweep_tile_jnp(emb, kwh, *cols, acc2, **kw)
+    else:
+        out, acc2 = _sweep_tile_pallas(emb, kwh, *cols, acc2,
+                                       interpret=interpret, **kw)
+    out = out._replace(**{f: getattr(out, f)[:, 0] for f in
+                          ("sum_best", "min_best", "max_best", "sum_emb",
+                           "sum_op")})
+    return out, SweepAcc(*(a[0] for a in acc2))

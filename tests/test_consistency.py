@@ -3,6 +3,7 @@ forward's next-token logits; MoE dispatch modes agree; sharding rules are
 divisibility-safe."""
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -75,7 +76,8 @@ def test_moe_hierarchical_matches_flat():
                           jnp.float32)
     y1, a1 = MOE.moe_ffn(p, x, m_flat)
     from repro.distributed.meshctx import mesh_context
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh_context(mesh):
         y2, a2 = jax.jit(lambda p, x: MOE.moe_ffn(p, x, m_hier))(p, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=2e-4,

@@ -10,6 +10,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -119,7 +120,8 @@ def test_elastic_restart_different_mesh(tmp_path):
     opt = opt_init(params)
     ckpt.save(str(tmp_path), 7, {"params": params, "opt": opt})
 
-    mesh_b = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_b = jax.make_mesh((1, 1), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
     p2, o2, step = resume_elastic(str(tmp_path), model, opt_init, mesh_b)
     assert step == 7
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(p2)):
@@ -300,7 +302,8 @@ print(json.dumps({"ok": True}))
 def test_compressed_allreduce_error_feedback():
     """EF-int8 all-reduce: single-step error bounded; residual carries the
     exact quantization error so the bias vanishes across steps."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(AxisType.Auto,))
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(
         size=(64, 64)).astype(np.float32))}
     r = init_residuals(g)

@@ -267,6 +267,23 @@ def test_resilience_requires_resident_loop():
                           faults=spec)
 
 
+def test_faults_with_pallas_refused_on_tpu(monkeypatch):
+    """The Pallas fault transform does not compile for the TPU: on a TPU
+    backend the engine refuses the combination up front, before any
+    compile, and still runs the same plan with the branchless stepper
+    elsewhere."""
+    code, mems = _fleet(8)
+    spec = faults.FaultSpec(rate=0.02, seed=5)
+    monkeypatch.setattr(engine.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="stepper='branchless'"):
+        engine.run_packed([_group(code, mems)], stepper="pallas",
+                          faults=spec)
+    monkeypatch.undo()
+    res, _ = engine.run_packed([_group(code, mems)], chunk=8,
+                               stepper="pallas", faults=spec)
+    assert len(res[0].n_instr) == 8
+
+
 # ---- measurement and pricing ------------------------------------------
 
 

@@ -117,7 +117,7 @@ def test_banked_segments_match_per_program_monolithic(mode):
             b, c, s, 64, sub))
     elif mode == "pallas":
         seg = jax.jit(lambda b, c, s: iss_segment_banked(
-            b, c, s, seg_steps=64, subset=sub, lane_tile=4))
+            b, c, s, seg_steps=64, subset=sub))
     else:
         seg = jax.jit(lambda b, c, s: iss.PackedState(
             lanes=jax.vmap(lambda p, m, l: iss.run_segment_banked(

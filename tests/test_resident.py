@@ -146,7 +146,7 @@ def test_pallas_refill_swap_matches_jnp_swap():
     bit-identical to the shared jnp helper (`iss.refill_lanes`) over a
     randomized pool + staged batch, including un-taken lanes."""
     rng = np.random.default_rng(7)
-    n, m, s = 8, 16, 5
+    n, m, s = 256, 16, 150          # two 128-lane tiles
     lanes = iss.ISSState(
         regs=jnp.asarray(rng.integers(-9, 9, (n, 16)), iss.I32),
         pc=jnp.asarray(rng.integers(0, 64, n), iss.I32),
@@ -166,7 +166,7 @@ def test_pallas_refill_swap_matches_jnp_swap():
     sprog = jnp.asarray(rng.integers(0, 3, n), iss.I32)
     sms = jnp.asarray(rng.integers(1, 99, n), iss.I32)
     a = iss.refill_lanes(ps, take, src, smem, sprog, sms)
-    b = jax.jit(lambda *xs: iss_refill(*xs, lane_tile=4))(
+    b = jax.jit(iss_refill)(
         ps, take, src, smem, sprog, sms)
     for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))

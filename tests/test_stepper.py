@@ -162,31 +162,32 @@ def test_pallas_segment_bit_exact_instruction_soup():
     OOB memory edges the mem-op lane states are biased toward."""
     rng = np.random.default_rng(21)
     mem_ops = ("lb", "lh", "lw", "lbu", "lhu", "sb", "sh", "sw")
-    lanes = len(isa.ALL_OPS)
+    # 256 lanes (two 128-lane tiles) exercise the lane-tile grid as well;
+    # lane i runs its own random word of opcode i % len(ALL_OPS)
+    names = [isa.ALL_OPS[i % len(isa.ALL_OPS)] for i in range(256)]
     for trial in range(6):
-        words = np.array([_random_instr(rng, n) for n in isa.ALL_OPS],
+        words = np.array([_random_instr(rng, n) for n in names],
                          np.uint32)
         states = []
-        for i, name in enumerate(isa.ALL_OPS):
+        for i, name in enumerate(names):
             s = _random_state(rng, mem_like=name in mem_ops)
             states.append(s._replace(pc=jnp.asarray(4 * i, iss.I32)))
         batched = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
         code = jnp.asarray(words.view(np.int32))
         ref = jax.jit(lambda st: iss.step_lanes(code, st))(batched)
-        # lane_tile < lanes exercises the lane-tile grid as well
-        got = iss_segment(code, batched, seg_steps=1, max_steps=1 << 30,
-                          lane_tile=max(1, lanes // 3))
+        got = iss_segment(code, batched, seg_steps=1, max_steps=1 << 30)
         _assert_state_equal(ref, got, ctx=f"pallas soup trial {trial}")
 
 
 def test_pallas_subset_segment_parity():
     """Fused segments with the derived opcode subset retire the exact
     sequence of the monolithic full-ISA interpreter on a real workload,
-    across many segment boundaries and a tiled lane grid."""
+    across many segment boundaries and a tiled lane grid (256 lanes,
+    two 128-lane tiles)."""
     from repro.flexibench.base import get
     from repro.flexibits.fleet import fleet_inputs
     w = get("MC")
-    n = 12
+    n = 256
     mems = fleet_inputs(w, n, seed=9)
     code = jnp.asarray(w.program.code.view(np.int32))
     sub = iss.opcode_subset(w.program.code)
@@ -203,8 +204,7 @@ def test_pallas_subset_segment_parity():
         n_cycles=jnp.zeros((n,), iss.I32),
     )
     seg = jax.jit(lambda c, st: iss_segment(
-        c, st, seg_steps=64, max_steps=w.max_steps, subset=sub,
-        lane_tile=4))
+        c, st, seg_steps=64, max_steps=w.max_steps, subset=sub))
     for _ in range(10_000):
         states = seg(code, states)
         if bool(np.asarray(states.halted).all()):

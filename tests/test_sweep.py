@@ -101,26 +101,29 @@ def test_pallas_vs_jnp_bit_exact():
 
 
 def test_pallas_row_tiles_bit_exact():
-    rng = np.random.default_rng(0)
-    n_cells, n_draws, n_cores = 12, 8, 3
-    emb = jnp.asarray(rng.uniform(1e-4, 1e-2, (n_cells, n_cores)),
-                      jnp.float32)
-    kwh = jnp.asarray(rng.uniform(1e-9, 1e-6, (n_cells, n_cores)),
-                      jnp.float32)
-    inten = jnp.asarray(rng.uniform(0.01, 1.1, n_cells), jnp.float32)
-    freq = jnp.asarray(rng.uniform(0.5, 100, n_cells), jnp.float32)
-    life = jnp.asarray(rng.uniform(1, 4000, (n_cells, n_draws)),
-                       jnp.float32)  # days — pre-divided like the engine
-    valid = jnp.asarray(rng.random(n_cells) < 0.8)
-    cell = jnp.arange(n_cells, dtype=jnp.int32)
+    """Pallas == jnp at every row tiling the cell count derives: one
+    whole block (12 cells) and tiles of 8 (136 cells), 72 (144) and
+    128 (256)."""
     kw = dict(hist_lo=-4.0, hist_inv=12.8, par_lo=-4.0, par_inv=6.4)
-    acc = csk.init_acc(64, 32, jnp.float32)
-    ref_out, ref_acc = csk.sweep_tile(emb, kwh, inten, freq, life,
-                                      valid, cell, acc, path="jnp", **kw)
-    for rt in (1, 3, 4, 12, None):
+    n_draws, n_cores = 8, 3
+    for n_cells, tile in ((12, 12), (136, 8), (144, 72), (256, 128)):
+        assert csk._pick_row_tile(n_cells) == tile
+        rng = np.random.default_rng(n_cells)
+        emb = jnp.asarray(rng.uniform(1e-4, 1e-2, (n_cells, n_cores)),
+                          jnp.float32)
+        kwh = jnp.asarray(rng.uniform(1e-9, 1e-6, (n_cells, n_cores)),
+                          jnp.float32)
+        inten = jnp.asarray(rng.uniform(0.01, 1.1, n_cells), jnp.float32)
+        freq = jnp.asarray(rng.uniform(0.5, 100, n_cells), jnp.float32)
+        life = jnp.asarray(rng.uniform(1, 4000, (n_cells, n_draws)),
+                           jnp.float32)  # days — pre-divided like the engine
+        valid = jnp.asarray(rng.random(n_cells) < 0.8)
+        cell = jnp.arange(n_cells, dtype=jnp.int32)
+        acc = csk.init_acc(64, 32, jnp.float32)
+        ref_out, ref_acc = csk.sweep_tile(emb, kwh, inten, freq, life,
+                                          valid, cell, acc, path="jnp", **kw)
         out, pacc = csk.sweep_tile(emb, kwh, inten, freq, life, valid,
-                                   cell, acc, path="pallas",
-                                   row_tile=rt, **kw)
+                                   cell, acc, path="pallas", **kw)
         for a, b in zip(ref_out, out):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         for a, b in zip(ref_acc, pacc):
@@ -144,7 +147,7 @@ def test_point_mass_equals_total_grid_bitwise():
     best = tg.min(axis=0)
     smap = selection_map(PROF, np.asarray(lifes),
                          np.asarray(spec.execs_per_day))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         res = run_sweep(spec, path="jnp", tile_cells=5,
                         dtype=np.float64)
         res1 = run_sweep(dataclasses.replace(spec, draws=1),
@@ -186,7 +189,7 @@ def test_serving_plan_jnp_equals_plan_grid_bitwise():
               lifetimes_days=np.array([1.0, 30.0, 365.0, 3 * 365.0]),
               qps_grid=np.logspace(1, 12, 12))
     ref = plan_grid(**kw)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = serving_plan_jnp(**kw)
     for k in ("variant_idx", "chips", "total_kg"):
         np.testing.assert_array_equal(np.asarray(got[k]), ref[k], k)
@@ -271,7 +274,7 @@ def test_redundancy_rate_zero_reproduces_selection():
                                redundancies=("none", "dmr", "tmr"))
     smap = selection_map(PROF, np.asarray(lifes),
                          np.asarray(spec.execs_per_day))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         res = run_sweep(spec, path="jnp", tile_cells=5, dtype=np.float64)
     sq0 = np.s_[:, :, 0, 0, 0, 0, 0]              # fault-rate-0 slice
     np.testing.assert_array_equal(res.best_redundancy[sq0], 0)
@@ -337,7 +340,7 @@ def test_mixture_of_points_hits_both_components():
     cores = list(CORES.values())
     tg = total_grid(cores, PROF, np.array([d1, d2]), np.array([24.0]))
     lo, hi = tg[:, 0, 0].min(), tg[:, 1, 0].min()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         res = run_sweep(spec, path="jnp", dtype=np.float64)
     assert res.min.ravel()[0] == lo
     assert res.max.ravel()[0] == hi
