@@ -38,7 +38,7 @@ most items run short data-dependent paths, a tail runs long ones):
   resident engine as the host device count grows (1..8, subprocesses
   with forced CPU device counts). Forced host devices time-share the
   physical cores, so each point pairs the real oversubscribed run
-  (wall, host_syncs, sync_wait, busy frac) with a bit-exact per-shard
+  (wall, host_syncs, sync_wait) with a bit-exact per-shard
   replay on a dedicated device — the collective-free loop makes the
   replay wall the dedicated-node wall, and that is what must scale
   (monotone, >=2.5x at 4 devices).
@@ -407,9 +407,6 @@ def fleet_resident_vs_host(chunk: int = 256, seg_steps: int = 512,
         ("fleet/resident_syncs", d_stats.host_syncs, h_stats.host_syncs),
         ("fleet/resident_lane_steps", d_stats.lane_steps,
          h_stats.lane_steps),
-        ("fleet/resident_busy_frac",
-         round(d_stats.device_busy_frac, 3),
-         round(h_stats.device_busy_frac, 3)),
     ]
     derived = {
         "group_sizes": list(sizes),
@@ -422,8 +419,6 @@ def fleet_resident_vs_host(chunk: int = 256, seg_steps: int = 512,
         "host_refill_segments": h_stats.n_segments,
         "resident_lane_steps": d_stats.lane_steps,
         "host_refill_lane_steps": h_stats.lane_steps,
-        "resident_busy_frac": d_stats.device_busy_frac,
-        "host_refill_busy_frac": h_stats.device_busy_frac,
         "resident_sync_wait_s": d_stats.sync_wait_s,
         "host_refill_sync_wait_s": h_stats.sync_wait_s,
         "adaptive_rungs": sorted(set(d_stats.seg_schedule)),
@@ -831,7 +826,6 @@ def _scaling_worker(spec: dict) -> dict:
             "n_segments": stats.n_segments,
             "host_syncs": stats.host_syncs,
             "sync_wait_s": stats.sync_wait_s,
-            "device_busy_frac": stats.device_busy_frac,
             "n_shards": stats.n_shards, "check": h.hexdigest()}
 
 
@@ -856,7 +850,7 @@ def fleet_device_scaling(counts=(1, 2, 4, 8), items_per_dev: int = 256,
     achieves — the deployment shape that matters at item-level scale.
     The replay must also be BIT-EXACT with the sharded run's shard-0
     slice (checksummed per point), and the raw oversubscribed wall is
-    recorded with per-point host_syncs/sync_wait_s/device_busy_frac and
+    recorded with per-point host_syncs/sync_wait_s and
     gated by an efficiency floor, so a return of per-segment global
     coordination still fails even time-shared.
     """
@@ -911,7 +905,7 @@ def fleet_device_scaling(counts=(1, 2, 4, 8), items_per_dev: int = 256,
         point = {k: full[k] for k in
                  ("n_devices", "n_items", "items_per_s", "wall_s",
                   "chunk", "n_segments", "host_syncs", "sync_wait_s",
-                  "device_busy_frac", "n_shards")}
+                  "n_shards")}
         point.update(shard_items=items_per_dev,
                      shard_wall_s=shard["wall_s"],
                      speedup_vs_1dev=sp, oversubscribed_efficiency=eff)

@@ -65,9 +65,8 @@ def main():
     if report.packed is not None:
         p = report.packed
         print(f"[fleet] sync: {p.host_syncs} blocking host syncs over "
-              f"{p.n_segments} segments, refill host work "
-              f"{p.refill_wall_s * 1e3:.1f} ms, device busy "
-              f"{100.0 * p.device_busy_frac:.1f}%")
+              f"{p.n_segments} segments ({p.sync_wait_s * 1e3:.1f} ms "
+              f"waited), refill host work {p.refill_wall_s * 1e3:.1f} ms")
     mc = report.groups[0].result
     print(f"[fleet] MC malodor score histogram: "
           f"{np.bincount(mc.out, minlength=5)}")

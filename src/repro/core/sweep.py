@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core.carbon import (REDUNDANCY_MODES, DeviceProfile,
                                operational_kg, redundancy_energy_factor,
@@ -597,52 +598,65 @@ def run_sweep(spec: SweepSpec, *, path: str = "jnp",
     Pareto accumulator merges host-side) every `flush_limit` scenarios,
     so counts can never wrap. float64 sweeps (the oracle-parity mode)
     require `jax.enable_x64(True)` around the call.
-    """
-    spec.validate()
-    dtype = np.dtype(dtype)
-    if dtype == np.float64 and not jax.config.jax_enable_x64:
-        raise ValueError("float64 sweeps need jax.enable_x64(True) "
-                         "around run_sweep")
-    n_cells = spec.n_cells
-    tile = max(1, min(tile_cells, n_cells))
-    step, tables = _sweep_step(spec, tile, path, dtype.name, n_hist,
-                               n_pareto, interpret)
-    C = spec.n_candidates
-    fields = ("mean", "p50", "p90", "p99", "min", "max", "mean_emb",
-              "mean_op", "fleet_mean")
-    host = {f: np.empty(n_cells, dtype) for f in fields}
-    host_counts = np.empty((n_cells, C), np.int32)
-    hist64 = np.zeros(n_hist, np.int64)
-    par_host: Optional[Dict[str, np.ndarray]] = None
-    since_flush = 0
 
-    t0 = time.perf_counter()
-    acc = csk.init_acc(n_hist, n_pareto, jnp.dtype(dtype))
-    for start in range(0, n_cells, tile):
-        acc, stats = step(acc, np.int32(start))
-        k = min(tile, n_cells - start)
-        for f in fields:
-            host[f][start:start + k] = np.asarray(stats[f])[:k]
-        host_counts[start:start + k] = np.asarray(stats["counts"])[:k]
-        since_flush += tile * spec.draws
-        if since_flush >= flush_limit:
+    The call is a `sweep.whatif` span on the profiler's host plane:
+    `sweep.prepare` (validation, the cached step, host buffers), then
+    per tile `sweep.step` and `sweep.readback` (its ten blocking reads),
+    and `sweep.finish` (histogram and Pareto flushes, the result).
+    """
+    with TraceAnnotation("sweep.whatif"):
+        with TraceAnnotation("sweep.prepare"):
+            spec.validate()
+            dtype = np.dtype(dtype)
+            if dtype == np.float64 and not jax.config.jax_enable_x64:
+                raise ValueError("float64 sweeps need jax.enable_x64(True) "
+                                 "around run_sweep")
+            n_cells = spec.n_cells
+            tile = max(1, min(tile_cells, n_cells))
+            step, tables = _sweep_step(spec, tile, path, dtype.name, n_hist,
+                                       n_pareto, interpret)
+            C = spec.n_candidates
+            fields = ("mean", "p50", "p90", "p99", "min", "max",
+                      "mean_emb", "mean_op", "fleet_mean")
+            host = {f: np.empty(n_cells, dtype) for f in fields}
+            host_counts = np.empty((n_cells, C), np.int32)
+            hist64 = np.zeros(n_hist, np.int64)
+            par_host: Optional[Dict[str, np.ndarray]] = None
+            since_flush = 0
+
+            t0 = time.perf_counter()
+            acc = csk.init_acc(n_hist, n_pareto, jnp.dtype(dtype))
+        for start in range(0, n_cells, tile):
+            with TraceAnnotation("sweep.step"):
+                acc, stats = step(acc, np.int32(start))
+            k = min(tile, n_cells - start)
+            with TraceAnnotation("sweep.readback"):
+                for f in fields:
+                    host[f][start:start + k] = np.asarray(stats[f])[:k]
+                host_counts[start:start + k] = \
+                    np.asarray(stats["counts"])[:k]
+            since_flush += tile * spec.draws
+            if since_flush >= flush_limit:
+                with TraceAnnotation("sweep.finish"):
+                    hist64 += np.asarray(acc.hist, np.int64)
+                    par_host = _merge_pareto_host(par_host,
+                                                  _acc_to_host(acc))
+                    acc = csk.init_acc(n_hist, n_pareto, jnp.dtype(dtype))
+                since_flush = 0
+        with TraceAnnotation("sweep.finish"):
             hist64 += np.asarray(acc.hist, np.int64)
             par_host = _merge_pareto_host(par_host, _acc_to_host(acc))
-            acc = csk.init_acc(n_hist, n_pareto, jnp.dtype(dtype))
-            since_flush = 0
-    hist64 += np.asarray(acc.hist, np.int64)
-    par_host = _merge_pareto_host(par_host, _acc_to_host(acc))
-    wall = time.perf_counter() - t0
+            wall = time.perf_counter() - t0
 
-    shape = spec.axis_sizes
-    return SweepResult(
-        spec=spec, path=path,
-        **{f: host[f].reshape(shape) for f in fields},
-        counts=host_counts.reshape(shape + (C,)),
-        hist=hist64, hist_edges=tables.hist_edges(n_hist),
-        pareto=par_host, n_cells=n_cells,
-        n_scenarios=spec.n_scenarios, wall_s=wall,
-        scenarios_per_s=spec.n_scenarios / max(wall, 1e-12))
+            shape = spec.axis_sizes
+            return SweepResult(
+                spec=spec, path=path,
+                **{f: host[f].reshape(shape) for f in fields},
+                counts=host_counts.reshape(shape + (C,)),
+                hist=hist64, hist_edges=tables.hist_edges(n_hist),
+                pareto=par_host, n_cells=n_cells,
+                n_scenarios=spec.n_scenarios, wall_s=wall,
+                scenarios_per_s=spec.n_scenarios / max(wall, 1e-12))
 
 
 # ------------------------------------------------- workload spec helper

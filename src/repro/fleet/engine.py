@@ -50,6 +50,7 @@ This engine fixes both (DESIGN.md §9):
 from __future__ import annotations
 
 import concurrent.futures
+import contextlib
 import dataclasses
 import functools
 import time
@@ -445,11 +446,11 @@ class PackedStats:
 
     The sync-stats fields (DESIGN.md §9.9) make the host<->device
     cadence a first-class output: `host_syncs` counts every blocking
-    device->host read the run performed, `sync_wait_s` the host time
-    spent inside them, `refill_wall_s` the host time spent assembling/
-    staging refills, and `device_busy_frac` estimates the fraction of
-    the wall clock during which the device had work in flight (1 minus
-    the host-only intervals where the device queue was observed empty).
+    device->host read the run performed (the `fleet.sync` spans),
+    `sync_wait_s` the host time spent inside them, and `refill_wall_s`
+    the host time spent in the `fleet.restock` spans that retire and
+    stage items. How long the device itself sat idle is read from a
+    profiler trace, where these spans share the device planes' clock.
     `seg_schedule` records the seg_steps actually used per segment —
     constant for a fixed run, the controller's trace for an adaptive
     one (pinned deterministic by tests/test_resident.py).
@@ -474,7 +475,6 @@ class PackedStats:
     host_syncs: int = 0           # blocking device->host reads
     sync_wait_s: float = 0.0      # host time blocked in those reads
     refill_wall_s: float = 0.0    # host time assembling/staging refills
-    device_busy_frac: float = 1.0
     seg_schedule: tuple = ()      # seg_steps used, one entry per segment
     n_shards: int = 1             # lane-pool shards (§9.12)
     shard_retired: tuple = ()     # items retired per shard (resident)
@@ -493,27 +493,42 @@ class PackedStats:
 
 
 class _SyncClock:
-    """Counts/times every blocking device->host read plus the host-side
-    refill work, and accumulates device-idle intervals for the
-    `device_busy_frac` estimate (DESIGN.md §9.9)."""
+    """The stream loop's host clock and its program spans.
+
+    `span(name)` opens a `jax.profiler.TraceAnnotation`: with a profiler
+    running it lands on the host plane, on the same clock as the device
+    planes, and otherwise costs a few microseconds. The fleet's spans
+    are `fleet.job`, `fleet.static` and `fleet.report` (`fleet/plan.py`)
+    and, here, `fleet.stream` (first restock to the end of the drain),
+    `fleet.dispatch` (the refill and segment calls), `fleet.sync` (one
+    per blocking device->host read, opened by `fetch`), `fleet.restock`,
+    `fleet.upload`, `fleet.checkpoint` and `fleet.drain`. `sync_wait_s`
+    and `refill_wall_s` total the `fleet.sync` and `fleet.restock` spans
+    (DESIGN.md §9.9)."""
 
     def __init__(self):
         self.host_syncs = 0
         self.sync_wait_s = 0.0
         self.refill_wall_s = 0.0
-        self.idle_s = 0.0
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                if name == "fleet.sync":
+                    self.sync_wait_s += dt
+                elif name == "fleet.restock":
+                    self.refill_wall_s += dt
 
     def fetch(self, x) -> np.ndarray:
-        t0 = time.perf_counter()
-        out = np.asarray(x)
-        self.sync_wait_s += time.perf_counter() - t0
+        with self.span("fleet.sync"):
+            out = np.asarray(x)
         self.host_syncs += 1
         return out
-
-    def busy_frac(self, wall_s: float) -> float:
-        if wall_s <= 0.0:
-            return 1.0
-        return max(0.0, 1.0 - self.idle_s / wall_s)
 
 
 class _SuperstepController:
@@ -1364,7 +1379,6 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         wall_s=wall_s, stepper=stepper, n_devices=n_dev, refill=refill,
         adaptive=adaptive, host_syncs=clock.host_syncs,
         sync_wait_s=clock.sync_wait_s, refill_wall_s=clock.refill_wall_s,
-        device_busy_frac=clock.busy_frac(wall_s),
         seg_schedule=tuple(controller.schedule[:out["n_segments"]]),
         n_shards=int(out.get("n_shards", n_dev)),
         shard_retired=tuple(int(x)
@@ -1438,105 +1452,103 @@ def _stream_host(groups, prefs, counts, ms_of, bank, code_len, mem_len,
                               jnp.asarray(new_prog),
                               jnp.asarray(new_ms)), n_new
 
-    # initial fill (admit into a fresh pool; padding lanes carry
-    # budget 0 and stay parked forever)
-    (first, active0, prog0, ms0), _ = admit(None, np.arange(chunk))
-    state = _fresh_packed(first, active0, prog0, ms0)
-    if mesh is not None:
-        state = jax.tree.map(jax.device_put, state,
-                             dsharding.lane_shardings(mesh, state))
-
     prev_instr = np.zeros(chunk, np.int64)
     lane_steps = 0
     n_segments = 0
-    expected_done = chunk - int((ids >= 0).sum())
 
-    while (ids >= 0).any():
-        seg_steps = controller.next_seg()
-        seg_fn = _packed_segment_runner(stepper, chunk, seg_steps,
-                                        mem_words, n_groups,
-                                        bank_np.shape[1], mesh, subset,
-                                        timing)
-        state = seg_fn(bank, code_len, mem_len, cost, state)
-        n_segments += 1
-        active = ids >= 0
-        act_per_group = np.bincount(lane_group[active],
-                                    minlength=n_groups)
-        g_segments += act_per_group > 0
-
-        # single-scalar sync, as in run_stream: if no lane finished,
-        # every active lane ran exactly seg_steps
-        if int(clock.fetch(_done_count_packed(state))) == expected_done:
-            lane_steps += chunk * seg_steps
-            g_lane_steps += act_per_group * seg_steps
-            prev_instr[active] += seg_steps
-            controller.record(0, seg_steps)
-            continue
-
-        t_harvest = time.perf_counter()
-        wait_before = clock.sync_wait_s
-        halted = clock.fetch(state.lanes.halted)
-        n_instr = clock.fetch(state.lanes.n_instr).astype(np.int64)
-        delta = int((n_instr - prev_instr).max(initial=0))
-        lane_steps += chunk * delta
-        g_lane_steps += act_per_group * delta
-        prev_instr = n_instr
-
-        done = active & (halted | (n_instr >= lane_ms))
-        idx = np.nonzero(done)[0]
-        if idx.size:
-            jidx = jnp.asarray(idx)
-            two = clock.fetch(state.lanes.n_two_stage).astype(np.int64)
-            mix_rows = clock.fetch(state.lanes.mix[jidx]).astype(np.int64)
-            if timing:   # one extra pull, only when the layer is on
-                cyc = clock.fetch(state.lanes.n_cycles).astype(np.int64)
-            # one O(done x mem_words) row gather serves every
-            # group's out-word read (and the keep_state memories) —
-            # not a full O(chunk) column pull per group
-            need_mem = keep_state or any(
-                g.out_addr is not None for g in groups)
-            if need_mem:
-                mem_rows = clock.fetch(state.lanes.mem[jidx])
-            if keep_state:
-                regs_rows = clock.fetch(state.lanes.regs[jidx])
-                pc_rows = clock.fetch(state.lanes.pc)[idx]
-            for g in np.unique(lane_group[idx]):
-                sel = lane_group[idx] == g
-                lg = idx[sel]
-                items = ids[lg]
-                r_instr[g][items] = n_instr[lg]
-                r_two[g][items] = two[lg]
-                if timing:
-                    r_cycles[g][items] = cyc[lg]
-                r_halt[g][items] = halted[lg]
-                r_mix[g] += mix_rows[sel].sum(0)
-                if groups[g].out_addr is not None:
-                    r_out[g][items] = \
-                        mem_rows[sel][:, groups[g].out_addr]
-                if keep_state:
-                    r_mem[g][items] = \
-                        mem_rows[sel][:, :groups[g].mem_words]
-                    r_regs[g][items] = regs_rows[sel]
-                    r_pc[g][items] = pc_rows[sel]
-                    r_mix_items[g][items] = mix_rows[sel]
-
-            # retire done lanes, then backfill from any pending group
-            ids[idx] = -1
-            lane_group[idx] = -1
-            lane_ms[idx] = 0
-            state, _ = admit(state, idx)
-            # refilled lanes restart at n_instr=0; retired-but-empty
-            # lanes keep their frozen device counters
-            prev_instr[idx] = np.where(ids[idx] >= 0, 0,
-                                       prev_instr[idx])
-        controller.record(int(idx.size), seg_steps)
-        # the whole harvest+rebuild runs with the segment finished and
-        # nothing dispatched: device-idle host work, minus the transfer
-        # time already booked as sync wait
-        dt = time.perf_counter() - t_harvest
-        clock.refill_wall_s += dt
-        clock.idle_s += max(0.0, dt - (clock.sync_wait_s - wait_before))
+    with clock.span("fleet.stream"):
+        # initial fill (admit into a fresh pool; padding lanes carry
+        # budget 0 and stay parked forever)
+        with clock.span("fleet.restock"):
+            (first, active0, prog0, ms0), _ = admit(None, np.arange(chunk))
+        state = _fresh_packed(first, active0, prog0, ms0)
+        if mesh is not None:
+            state = jax.tree.map(jax.device_put, state,
+                                 dsharding.lane_shardings(mesh, state))
         expected_done = chunk - int((ids >= 0).sum())
+
+        while (ids >= 0).any():
+            with clock.span("fleet.dispatch"):
+                seg_steps = controller.next_seg()
+                seg_fn = _packed_segment_runner(stepper, chunk, seg_steps,
+                                                mem_words, n_groups,
+                                                bank_np.shape[1], mesh,
+                                                subset, timing)
+                state = seg_fn(bank, code_len, mem_len, cost, state)
+                done_count = _done_count_packed(state)
+            n_segments += 1
+            active = ids >= 0
+            act_per_group = np.bincount(lane_group[active],
+                                        minlength=n_groups)
+            g_segments += act_per_group > 0
+
+            # single-scalar sync, as in run_stream: if no lane finished,
+            # every active lane ran exactly seg_steps
+            if int(clock.fetch(done_count)) == expected_done:
+                lane_steps += chunk * seg_steps
+                g_lane_steps += act_per_group * seg_steps
+                prev_instr[active] += seg_steps
+                controller.record(0, seg_steps)
+                continue
+
+            halted = clock.fetch(state.lanes.halted)
+            n_instr = clock.fetch(state.lanes.n_instr).astype(np.int64)
+            delta = int((n_instr - prev_instr).max(initial=0))
+            lane_steps += chunk * delta
+            g_lane_steps += act_per_group * delta
+            prev_instr = n_instr
+
+            done = active & (halted | (n_instr >= lane_ms))
+            idx = np.nonzero(done)[0]
+            if idx.size:
+                jidx = jnp.asarray(idx)
+                two = clock.fetch(state.lanes.n_two_stage).astype(np.int64)
+                mix_rows = clock.fetch(
+                    state.lanes.mix[jidx]).astype(np.int64)
+                if timing:   # one extra pull, only when the layer is on
+                    cyc = clock.fetch(state.lanes.n_cycles).astype(np.int64)
+                # one O(done x mem_words) row gather serves every
+                # group's out-word read (and the keep_state memories) —
+                # not a full O(chunk) column pull per group
+                need_mem = keep_state or any(
+                    g.out_addr is not None for g in groups)
+                if need_mem:
+                    mem_rows = clock.fetch(state.lanes.mem[jidx])
+                if keep_state:
+                    regs_rows = clock.fetch(state.lanes.regs[jidx])
+                    pc_rows = clock.fetch(state.lanes.pc)[idx]
+                # demux, retire the done lanes, then backfill from any
+                # pending group
+                with clock.span("fleet.restock"):
+                    for g in np.unique(lane_group[idx]):
+                        sel = lane_group[idx] == g
+                        lg = idx[sel]
+                        items = ids[lg]
+                        r_instr[g][items] = n_instr[lg]
+                        r_two[g][items] = two[lg]
+                        if timing:
+                            r_cycles[g][items] = cyc[lg]
+                        r_halt[g][items] = halted[lg]
+                        r_mix[g] += mix_rows[sel].sum(0)
+                        if groups[g].out_addr is not None:
+                            r_out[g][items] = \
+                                mem_rows[sel][:, groups[g].out_addr]
+                        if keep_state:
+                            r_mem[g][items] = \
+                                mem_rows[sel][:, :groups[g].mem_words]
+                            r_regs[g][items] = regs_rows[sel]
+                            r_pc[g][items] = pc_rows[sel]
+                            r_mix_items[g][items] = mix_rows[sel]
+                    ids[idx] = -1
+                    lane_group[idx] = -1
+                    lane_ms[idx] = 0
+                    state, _ = admit(state, idx)
+                # refilled lanes restart at n_instr=0; retired-but-empty
+                # lanes keep their frozen device counters
+                prev_instr[idx] = np.where(ids[idx] >= 0, 0,
+                                           prev_instr[idx])
+            controller.record(int(idx.size), seg_steps)
+            expected_done = chunk - int((ids >= 0).sum())
 
     return {"r_instr": r_instr, "r_two": r_two, "r_halt": r_halt,
             "r_out": r_out, "r_mix": r_mix, "r_mem": r_mem,
@@ -1978,142 +1990,144 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
         dckpt.save(checkpoint_dir, n_segments, tree)
 
     last_saved = n_segments
-    try:
-        restock()
-        while retired < total:
-            if crash_after is not None and n_segments >= crash_after:
-                raise InjectedFault(
-                    f"injected fault after segment {n_segments}")
-            if checkpoint_dir is not None and checkpoint_every > 0 \
-                    and n_segments - last_saved >= checkpoint_every:
-                save_checkpoint()
-                last_saved = n_segments
-            upload()
-            staged_dev_n = jnp.asarray(staged_n, iss.I32)
-            if dmr:
-                (state, item_slot, epoch, retries, quar_d, acc,
-                 stats) = refill_fn(
-                    state, item_slot, epoch, retries, quar_d, snap,
-                    acc, *staged["dev"], staged_dev_n, out_addr_dev)
-                # the refreshed boundary state IS the next rollback
-                # snapshot; holding it here (while the non-donating
-                # segment runs) keeps its buffers alive
-                snap = state.lanes
-            elif faults is not None:
-                state, item_slot, epoch, acc, stats = refill_fn(
-                    state, item_slot, epoch, acc, *staged["dev"],
-                    staged_dev_n, out_addr_dev)
-            else:
-                state, item_slot, acc, stats = refill_fn(
-                    state, item_slot, acc, *staged["dev"],
-                    staged_dev_n, out_addr_dev)
-            seg_steps = controller.next_seg()
-            # positional on purpose: test_shard_local.py wraps this
-            # factory with a *args-only shim to audit the lowered HLO
-            seg_fn = _packed_segment_runner(stepper, chunk, seg_steps,
-                                            mem_words, n_groups,
-                                            bank_np.shape[1], mesh,
-                                            subset, timing, faults,
-                                            not dmr)
-            if faults is not None:
-                state = seg_fn(bank, code_len, mem_len, cost,
-                               lane_key, epoch, state)
-            else:
-                state = seg_fn(bank, code_len, mem_len, cost, state)
-            if hasattr(stats, "copy_to_host_async"):
-                stats.copy_to_host_async()
-            # blocks until refill_i only — seg_i is already running;
-            # one (n_shards, 3+G) read regardless of device count
-            # ((n_shards, 6+G) under DMR: +detected/corrected/q_slot)
-            sv = np.asarray(clock.fetch(stats), np.int64)
-            n_ret = int(sv[:, 0].sum())
-            if dmr:
-                detected += int(sv[:, 3].sum())
-                corrected += int(sv[:, 4].sum())
-                for s in np.nonzero(sv[:, 5] >= 0)[0]:
-                    # quarantined pair: map the acc row back to the
-                    # item and hand it to restock for re-admission
-                    row = int(s) * cap + int(sv[s, 5])
-                    item = int(row_owner[row])
-                    g = int(np.searchsorted(slot_base, item,
-                                            side="right") - 1)
-                    requeue[int(s)].append(
-                        (g, item - int(slot_base[g]), int(sv[s, 5])))
-                    quarantined += 1
-                    n_quar[int(s)] += 1
-                    if n_quar[int(s)] >= spc // 2:
-                        raise RuntimeError(
-                            f"DMR pool starved: all {spc // 2} lane "
-                            f"pair(s) of shard {int(s)} are "
-                            f"quarantined with items still pending — "
-                            f"raise chunk, raise max_retries, or fix "
-                            f"the fault rate")
-                act_s = sv[:, 6:]
-            else:
-                act_s = sv[:, 3:]
-            deltas = sv[:, 2]
-            sh_act = act_s.sum(1) > 0
-            if sh_act.any():
-                n_segments += 1
-                g_segments += act_s.sum(0) > 0
-                g_lane_steps += (act_s * deltas[:, None]).sum(0)
-                stepped = spc * deltas * sh_act
-                lane_steps += int(stepped.sum())
-                shard_steps += stepped
-            controller.record(n_ret, prev_seg)
-            prev_seg = seg_steps
-            retired += n_ret
-            shard_retired += sv[:, 0]
-            t_refill = time.perf_counter()
-            consume(sv[:, 1])
-            restock()
-            dt = time.perf_counter() - t_refill
-            clock.refill_wall_s += dt
-            try:
-                if state.lanes.regs.is_ready():  # segment already done:
-                    clock.idle_s += dt           # restock was idle time
-            except AttributeError:
-                pass
-    finally:
-        for row in prefs:
-            for p in row:
-                p.close()
+    with clock.span("fleet.stream"):
+        try:
+            with clock.span("fleet.restock"):
+                restock()
+            while retired < total:
+                if crash_after is not None and n_segments >= crash_after:
+                    raise InjectedFault(
+                        f"injected fault after segment {n_segments}")
+                if checkpoint_dir is not None and checkpoint_every > 0 \
+                        and n_segments - last_saved >= checkpoint_every:
+                    with clock.span("fleet.checkpoint"):
+                        save_checkpoint()
+                    last_saved = n_segments
+                with clock.span("fleet.upload"):
+                    upload()
+                with clock.span("fleet.dispatch"):
+                    staged_dev_n = jnp.asarray(staged_n, iss.I32)
+                    if dmr:
+                        (state, item_slot, epoch, retries, quar_d, acc,
+                         stats) = refill_fn(
+                            state, item_slot, epoch, retries, quar_d,
+                            snap, acc, *staged["dev"], staged_dev_n,
+                            out_addr_dev)
+                        # the refreshed boundary state IS the next
+                        # rollback snapshot; holding it here (while the
+                        # non-donating segment runs) keeps its buffers
+                        # alive
+                        snap = state.lanes
+                    elif faults is not None:
+                        state, item_slot, epoch, acc, stats = refill_fn(
+                            state, item_slot, epoch, acc, *staged["dev"],
+                            staged_dev_n, out_addr_dev)
+                    else:
+                        state, item_slot, acc, stats = refill_fn(
+                            state, item_slot, acc, *staged["dev"],
+                            staged_dev_n, out_addr_dev)
+                    seg_steps = controller.next_seg()
+                    # positional on purpose: test_shard_local.py wraps
+                    # this factory with a *args-only shim to audit the
+                    # lowered HLO
+                    seg_fn = _packed_segment_runner(
+                        stepper, chunk, seg_steps, mem_words, n_groups,
+                        bank_np.shape[1], mesh, subset, timing, faults,
+                        not dmr)
+                    if faults is not None:
+                        state = seg_fn(bank, code_len, mem_len, cost,
+                                       lane_key, epoch, state)
+                    else:
+                        state = seg_fn(bank, code_len, mem_len, cost,
+                                       state)
+                    if hasattr(stats, "copy_to_host_async"):
+                        stats.copy_to_host_async()
+                # blocks until refill_i only — seg_i is already running;
+                # one (n_shards, 3+G) read regardless of device count
+                # ((n_shards, 6+G) under DMR: +detected/corrected/q_slot)
+                sv = np.asarray(clock.fetch(stats), np.int64)
+                n_ret = int(sv[:, 0].sum())
+                if dmr:
+                    detected += int(sv[:, 3].sum())
+                    corrected += int(sv[:, 4].sum())
+                    for s in np.nonzero(sv[:, 5] >= 0)[0]:
+                        # quarantined pair: map the acc row back to the
+                        # item and hand it to restock for re-admission
+                        row = int(s) * cap + int(sv[s, 5])
+                        item = int(row_owner[row])
+                        g = int(np.searchsorted(slot_base, item,
+                                                side="right") - 1)
+                        requeue[int(s)].append(
+                            (g, item - int(slot_base[g]), int(sv[s, 5])))
+                        quarantined += 1
+                        n_quar[int(s)] += 1
+                        if n_quar[int(s)] >= spc // 2:
+                            raise RuntimeError(
+                                f"DMR pool starved: all {spc // 2} lane "
+                                f"pair(s) of shard {int(s)} are "
+                                f"quarantined with items still pending — "
+                                f"raise chunk, raise max_retries, or fix "
+                                f"the fault rate")
+                    act_s = sv[:, 6:]
+                else:
+                    act_s = sv[:, 3:]
+                deltas = sv[:, 2]
+                sh_act = act_s.sum(1) > 0
+                if sh_act.any():
+                    n_segments += 1
+                    g_segments += act_s.sum(0) > 0
+                    g_lane_steps += (act_s * deltas[:, None]).sum(0)
+                    stepped = spc * deltas * sh_act
+                    lane_steps += int(stepped.sum())
+                    shard_steps += stepped
+                controller.record(n_ret, prev_seg)
+                prev_seg = seg_steps
+                retired += n_ret
+                shard_retired += sv[:, 0]
+                with clock.span("fleet.restock"):
+                    consume(sv[:, 1])
+                    restock()
+        finally:
+            for row in prefs:
+                for p in row:
+                    p.close()
 
-    # ---- drain: ONE demux of the on-device accumulators, merged with
-    # the host base through the item->row table
-    accv = {"n_instr": clock.fetch(acc.n_instr),
-            "n_two": clock.fetch(acc.n_two)}
-    accv["n_cycles"] = clock.fetch(acc.n_cycles) if timing \
-        else np.zeros(n_shards * cap, np.int64)
-    accv["halted"] = clock.fetch(acc.halted)
-    accv["out"] = clock.fetch(acc.out)
-    res_mix_g = mix_base + clock.fetch(acc.mix_g).astype(
-        np.int64).sum(0)
-    if keep_state:
-        accv["mems"] = clock.fetch(acc.mems)
-        accv["regs"] = clock.fetch(acc.regs)
-        accv["pc"] = clock.fetch(acc.pc)
-        accv["mix_items"] = clock.fetch(acc.mix_items)
-    merged = merged_vals(accv)
+        # ---- drain: ONE demux of the on-device accumulators, merged with
+        # the host base through the item->row table
+        with clock.span("fleet.drain"):
+            accv = {"n_instr": clock.fetch(acc.n_instr),
+                    "n_two": clock.fetch(acc.n_two)}
+            accv["n_cycles"] = clock.fetch(acc.n_cycles) if timing \
+                else np.zeros(n_shards * cap, np.int64)
+            accv["halted"] = clock.fetch(acc.halted)
+            accv["out"] = clock.fetch(acc.out)
+            res_mix_g = mix_base + clock.fetch(acc.mix_g).astype(
+                np.int64).sum(0)
+            if keep_state:
+                accv["mems"] = clock.fetch(acc.mems)
+                accv["regs"] = clock.fetch(acc.regs)
+                accv["pc"] = clock.fetch(acc.pc)
+                accv["mix_items"] = clock.fetch(acc.mix_items)
+            merged = merged_vals(accv)
 
-    r_instr, r_two, r_halt, r_out, r_mix = [], [], [], [], []
-    r_cycles = []
-    r_mem = r_regs = r_pc = r_mix_items = None
-    if keep_state:
-        r_mem, r_regs, r_pc, r_mix_items = [], [], [], []
-    for g, grp in enumerate(groups):
-        sl = slice(int(slot_base[g]), int(slot_base[g] + counts[g]))
-        r_instr.append(merged["n_instr"][sl].astype(np.int64))
-        r_two.append(merged["n_two"][sl].astype(np.int64))
-        r_cycles.append(merged["n_cycles"][sl].astype(np.int64))
-        r_halt.append(merged["halted"][sl])
-        r_out.append(merged["out"][sl])
-        r_mix.append(res_mix_g[g])
-        if keep_state:
-            r_mem.append(merged["mems"][sl, :grp.mem_words].copy())
-            r_regs.append(merged["regs"][sl])
-            r_pc.append(merged["pc"][sl])
-            r_mix_items.append(merged["mix_items"][sl])
+            r_instr, r_two, r_halt, r_out, r_mix = [], [], [], [], []
+            r_cycles = []
+            r_mem = r_regs = r_pc = r_mix_items = None
+            if keep_state:
+                r_mem, r_regs, r_pc, r_mix_items = [], [], [], []
+            for g, grp in enumerate(groups):
+                sl = slice(int(slot_base[g]), int(slot_base[g] + counts[g]))
+                r_instr.append(merged["n_instr"][sl].astype(np.int64))
+                r_two.append(merged["n_two"][sl].astype(np.int64))
+                r_cycles.append(merged["n_cycles"][sl].astype(np.int64))
+                r_halt.append(merged["halted"][sl])
+                r_out.append(merged["out"][sl])
+                r_mix.append(res_mix_g[g])
+                if keep_state:
+                    r_mem.append(merged["mems"][sl, :grp.mem_words].copy())
+                    r_regs.append(merged["regs"][sl])
+                    r_pc.append(merged["pc"][sl])
+                    r_mix_items.append(merged["mix_items"][sl])
 
     return {"r_instr": r_instr, "r_two": r_two, "r_halt": r_halt,
             "r_out": r_out, "r_mix": r_mix, "r_mem": r_mem,

@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence, Tuple, Union
 
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.flexibench import base as fb
@@ -236,47 +237,59 @@ def run_plan(plan: FleetPlan, mesh: Optional[Mesh] = None,
     `checkpoint_dir`/`checkpoint_every` make the packed resident stream
     durable (mid-flight checkpoint + bit-exact auto-resume — packed
     plans only).
+
+    The job is a `fleet.job` span on the profiler's host plane, holding
+    the `fleet.static` pass, the engine's `fleet.stream` and the
+    `fleet.report` demux and pricing (engine `_SyncClock`).
     """
     if checkpoint_dir is not None and not (plan.packed and plan.groups):
         raise ValueError("checkpointing requires a packed plan")
-    if plan.packed and plan.groups:
-        lowered, resolved = _packed_groups(plan)
-        results, stats = engine.run_packed(
-            lowered, chunk=plan.chunk, seg_steps=plan.seg_steps,
-            keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
-            prefetch=plan.prefetch, refill=plan.refill,
-            adaptive=plan.adaptive, checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every, faults=plan.faults,
-            redundancy=plan.redundancy, max_retries=plan.max_retries)
-        group_reports = [
-            build_group_report(
-                group=g, workload=w, core=core, result=res,
-                lifetime_s=lifetime_s, execs_per_day=execs_per_day,
-                intensity=plan.intensity, clock_hz=plan.clock_hz,
-                wcet_cycles=wcet_cycles, redundancy=plan.redundancy,
-                fault_rate=0.0 if plan.faults is None
-                else plan.faults.rate)
-            for g, (w, core, lifetime_s, execs_per_day, wcet_cycles), res
-            in zip(plan.groups, resolved, results)]
-        return FleetReport(groups=group_reports, intensity=plan.intensity,
-                           packed=stats)
+    with TraceAnnotation("fleet.job"):
+        if plan.packed and plan.groups:
+            with TraceAnnotation("fleet.static"):
+                lowered, resolved = _packed_groups(plan)
+            results, stats = engine.run_packed(
+                lowered, chunk=plan.chunk, seg_steps=plan.seg_steps,
+                keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
+                prefetch=plan.prefetch, refill=plan.refill,
+                adaptive=plan.adaptive, checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every, faults=plan.faults,
+                redundancy=plan.redundancy, max_retries=plan.max_retries)
+            with TraceAnnotation("fleet.report"):
+                group_reports = [
+                    build_group_report(
+                        group=g, workload=w, core=core, result=res,
+                        lifetime_s=lifetime_s, execs_per_day=execs_per_day,
+                        intensity=plan.intensity, clock_hz=plan.clock_hz,
+                        wcet_cycles=wcet_cycles, redundancy=plan.redundancy,
+                        fault_rate=0.0 if plan.faults is None
+                        else plan.faults.rate)
+                    for g, (w, core, lifetime_s, execs_per_day,
+                            wcet_cycles), res
+                    in zip(plan.groups, resolved, results)]
+            return FleetReport(groups=group_reports,
+                               intensity=plan.intensity, packed=stats)
 
-    group_reports = []
-    for g in plan.groups:
-        w, core, lifetime_s, execs_per_day = g.resolve()
-        max_steps, subset, wcet_cycles = _static_pass(plan, g, w, core)
-        res = engine.run_workload_stream(
-            w, g.n_items, seed=g.seed, chunk=plan.chunk,
-            seg_steps=plan.seg_steps, max_steps=max_steps,
-            keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
-            prefetch=plan.prefetch, refill=plan.refill,
-            adaptive=plan.adaptive, cost=_group_cost(plan, core),
-            subset=subset, faults=plan.faults,
-            redundancy=plan.redundancy, max_retries=plan.max_retries)
-        group_reports.append(build_group_report(
-            group=g, workload=w, core=core, result=res,
-            lifetime_s=lifetime_s, execs_per_day=execs_per_day,
-            intensity=plan.intensity, clock_hz=plan.clock_hz,
-            wcet_cycles=wcet_cycles, redundancy=plan.redundancy,
-            fault_rate=0.0 if plan.faults is None else plan.faults.rate))
-    return FleetReport(groups=group_reports, intensity=plan.intensity)
+        group_reports = []
+        for g in plan.groups:
+            with TraceAnnotation("fleet.static"):
+                w, core, lifetime_s, execs_per_day = g.resolve()
+                max_steps, subset, wcet_cycles = _static_pass(plan, g, w,
+                                                              core)
+            res = engine.run_workload_stream(
+                w, g.n_items, seed=g.seed, chunk=plan.chunk,
+                seg_steps=plan.seg_steps, max_steps=max_steps,
+                keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
+                prefetch=plan.prefetch, refill=plan.refill,
+                adaptive=plan.adaptive, cost=_group_cost(plan, core),
+                subset=subset, faults=plan.faults,
+                redundancy=plan.redundancy, max_retries=plan.max_retries)
+            with TraceAnnotation("fleet.report"):
+                group_reports.append(build_group_report(
+                    group=g, workload=w, core=core, result=res,
+                    lifetime_s=lifetime_s, execs_per_day=execs_per_day,
+                    intensity=plan.intensity, clock_hz=plan.clock_hz,
+                    wcet_cycles=wcet_cycles, redundancy=plan.redundancy,
+                    fault_rate=0.0 if plan.faults is None
+                    else plan.faults.rate))
+        return FleetReport(groups=group_reports, intensity=plan.intensity)

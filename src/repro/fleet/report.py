@@ -247,8 +247,7 @@ class FleetReport:
             lines.append(
                 f"sync stats ({mode}): {p.host_syncs} blocking host "
                 f"syncs ({p.sync_wait_s:.3f}s waited), refill host work "
-                f"{p.refill_wall_s:.3f}s, device busy "
-                f"{100.0 * p.device_busy_frac:.1f}%")
+                f"{p.refill_wall_s:.3f}s")
             if p.redundancy != "none" or p.detected or p.quarantined:
                 lines.append(
                     f"resilience (FlexiFault §9.14, {p.redundancy}): "

@@ -187,7 +187,6 @@ def test_resident_syncs_fewer_than_host_refill():
     assert sd.host_syncs == sd.n_segments + 1 + 5
     for s in (sh, sd):
         assert s.sync_wait_s >= 0.0 and s.refill_wall_s >= 0.0
-        assert 0.0 <= s.device_busy_frac <= 1.0
         assert len(s.seg_schedule) == s.n_segments
 
 
