@@ -17,12 +17,17 @@ spread instead of point estimates.
 Engine shape (the `fleet/engine.py` streaming discipline, applied to
 scenarios instead of items):
 
-- **Streamed tiles, bounded memory.** The flat cell space is walked in
-  fixed tiles; per-tile device work is O(tile x draws x cores) and the
-  host keeps only O(cells) scalar summaries plus two small global
-  accumulators (histogram + Pareto bins) that are *donated* back to the
-  jitted step every tile — arbitrarily large sweeps run in one
-  chunk-sized device allocation.
+- **Streamed tiles, bounded memory, one read-back.** The flat cell
+  space is walked in fixed tiles; per-tile device work is O(tile x
+  draws x cores). Each tile writes its per-cell summaries into a device
+  stats buffer, and two small global accumulators (histogram + Pareto
+  bins) are *donated* back to the jitted step every tile. The host reads
+  nothing until a run of tiles fills the buffer or the sweep ends, then
+  fetches buffer and accumulators in one `jax.device_get`. The buffer
+  holds at most `READBACK_BYTES` of stat rows, so device memory is one
+  tile plus `SweepAcc` plus that budget however large the sweep, and a
+  sweep whose rows fit (every planner what-if of the benchmark) makes a
+  single blocking read.
 - **Counter-based per-cell seeding.** Scenario (cell, draw) derives its
   uniforms from `fold_in(fold_in(key, cell), draw)` — a pure function
   of the *global* indices, so tiles are order-independent and the whole
@@ -78,6 +83,13 @@ TIMING_MODES = ("base", "dynamic", "wcet", "measured")
 DAY_S = 86_400.0
 YEAR_S = 365.0 * DAY_S
 _PCTS = (50, 90, 99)
+# per-cell draw statistics, in the row order of the device stats buffer
+_STAT_FIELDS = ("mean", "p50", "p90", "p99", "min", "max", "mean_emb",
+                "mean_op", "fleet_mean")
+# Byte budget of the device stats buffer (stat fields plus int32 counts
+# per cell, unpadded; a TPU's 8-row tiles about double it). A sweep whose
+# cells fit reads back once; a larger one once per buffer of cells.
+READBACK_BYTES = 64 << 20
 
 
 # --------------------------------------------------------- distributions
@@ -402,12 +414,22 @@ def _lifetimes(kind, p1, p2, cum_prev, u) -> jax.Array:
 
 # ----------------------------------------------------------- sweep step
 @functools.lru_cache(maxsize=8)
-def _sweep_step(spec: SweepSpec, tile_cells: int, path: str,
+def _sweep_step(spec: SweepSpec, tile_cells: int, rows: int, path: str,
                 dtype_str: str, n_hist: int, n_pareto: int,
                 interpret: Optional[bool]):
-    """Compiled streaming step for (spec, tile, path, dtype) — cached
-    like `fleet/engine.py`'s segment runners so repeated what-ifs on the
-    same spec skip retracing. Returns (jitted step, tables)."""
+    """Compiled streaming step for (spec, tile, buffer rows, path, dtype)
+    — cached like `fleet/engine.py`'s segment runners so repeated
+    what-ifs on the same spec skip retracing. Returns (jitted step,
+    jitted init, tables).
+
+    `step(acc, (stats, counts), start)` prices the tile of cells from
+    `start`, folds it into `acc` and writes its per-cell summaries into
+    the donated stats buffer at column `start % rows`: `stats` is
+    `(len(_STAT_FIELDS), rows)` in the dtype, `counts` `(C*R, rows)`
+    int32. Cells run along the minor axis, so a TPU pads the few fields
+    to its 8-row tile and not the cells to its 128 lanes. `init()` makes
+    a fresh `acc` and buffer in one dispatch (eagerly, each of their
+    nine arrays would cost a dispatch of its own)."""
     tables = build_tables(spec, n_hist, n_pareto)
     dtype = jnp.dtype(dtype_str)
     D, F, I, V, W, T, FR = spec.axis_sizes
@@ -426,7 +448,7 @@ def _sweep_step(spec: SweepSpec, tile_cells: int, path: str,
     qidx = tuple(min(draws - 1, max(0, math.ceil(q / 100 * draws) - 1))
                  for q in _PCTS)
 
-    def step(acc: csk.SweepAcc, start):
+    def step(acc: csk.SweepAcc, buf, start):
         cell = start + jnp.arange(tile_cells, dtype=I32)
         valid = cell < n_cells
         c = jnp.where(valid, cell, n_cells - 1)
@@ -458,21 +480,30 @@ def _sweep_step(spec: SweepSpec, tile_cells: int, path: str,
             par_inv=tables.par_inv, path=path, interpret=interpret)
         by_draw = jnp.sort(out.best_total, axis=1)
         mean = out.sum_best / draws
-        stats = {
-            "mean": mean,
-            "p50": by_draw[:, qidx[0]],
-            "p90": by_draw[:, qidx[1]],
-            "p99": by_draw[:, qidx[2]],
-            "min": out.min_best,
-            "max": out.max_best,
-            "mean_emb": out.sum_emb / draws,
-            "mean_op": out.sum_op / draws,
-            "fleet_mean": mean * vol_d[vi],
-            "counts": out.counts,
-        }
-        return acc, stats
+        stats = jnp.stack([
+            mean,                                  # in _STAT_FIELDS order
+            by_draw[:, qidx[0]],
+            by_draw[:, qidx[1]],
+            by_draw[:, qidx[2]],
+            out.min_best,
+            out.max_best,
+            out.sum_emb / draws,
+            out.sum_op / draws,
+            mean * vol_d[vi],
+        ])
+        col = start % rows
+        stats_buf, counts_buf = buf
+        counts = out.counts.T.astype(I32)            # int64 under x64
+        return acc, (
+            lax.dynamic_update_slice_in_dim(stats_buf, stats, col, axis=1),
+            lax.dynamic_update_slice_in_dim(counts_buf, counts, col, axis=1))
 
-    return jax.jit(step, donate_argnums=0), tables
+    def init():
+        return (csk.init_acc(n_hist, n_pareto, dtype),
+                (jnp.zeros((len(_STAT_FIELDS), rows), dtype),
+                 jnp.zeros((spec.n_candidates, rows), I32)))
+
+    return jax.jit(step, donate_argnums=(0, 1)), jax.jit(init), tables
 
 
 # --------------------------------------------------------------- result
@@ -480,11 +511,9 @@ _PAR_FIELDS = ("op", "emb", "life", "cell", "draw", "core")
 
 
 def _acc_to_host(acc: csk.SweepAcc) -> Dict[str, np.ndarray]:
-    return {"op": np.asarray(acc.par_op), "emb": np.asarray(acc.par_emb),
-            "life": np.asarray(acc.par_life),
-            "cell": np.asarray(acc.par_cell),
-            "draw": np.asarray(acc.par_draw),
-            "core": np.asarray(acc.par_core)}
+    """The Pareto arrays of a read-back `SweepAcc` (numpy leaves), keyed
+    by `_PAR_FIELDS` (the order of `par_op` ... `par_core`)."""
+    return dict(zip(_PAR_FIELDS, acc[1:]))
 
 
 def _merge_pareto_host(a: Optional[Dict[str, np.ndarray]],
@@ -525,6 +554,7 @@ class SweepResult:
     n_scenarios: int
     wall_s: float
     scenarios_per_s: float
+    host_reads: int              # blocking device->host reads of the call
 
     @property
     def core_share(self) -> np.ndarray:
@@ -593,16 +623,24 @@ def run_sweep(spec: SweepSpec, *, path: str = "jnp",
     """Stream the whole scenario space through the fused evaluate-and-
     reduce step in `tile_cells`-cell tiles.
 
-    Device memory is bounded by one tile regardless of sweep size; the
-    global int32 histogram flushes into a host int64 tally (and the
-    Pareto accumulator merges host-side) every `flush_limit` scenarios,
-    so counts can never wrap. float64 sweeps (the oracle-parity mode)
-    require `jax.enable_x64(True)` around the call.
+    Every tile is dispatched without a read. Per-cell summaries stay in
+    a device stats buffer of `rows = min(n_tiles * tile, cap)` cells,
+    `cap` being the whole tiles that fit `READBACK_BYTES` (at least
+    one); after each run of `rows` cells, and at the end together with
+    the accumulators, one `jax.device_get` brings them back. Device
+    memory is one tile plus `SweepAcc` plus at most `READBACK_BYTES`,
+    whatever the sweep's size, and a sweep that fits makes one blocking
+    read (`SweepResult.host_reads`). The global int32 histogram flushes
+    into a host int64 tally (and the Pareto accumulator merges
+    host-side) every `flush_limit` scenarios, one read each, so counts
+    can never wrap. float64 sweeps (the oracle-parity mode) require
+    `jax.enable_x64(True)` around the call.
 
     The call is a `sweep.whatif` span on the profiler's host plane:
-    `sweep.prepare` (validation, the cached step, host buffers), then
-    per tile `sweep.step` and `sweep.readback` (its ten blocking reads),
-    and `sweep.finish` (histogram and Pareto flushes, the result).
+    `sweep.prepare` (validation, the cached step, host and device
+    buffers), `sweep.step` per tile, `sweep.readback` per run of tiles
+    (its one read), and `sweep.finish` (histogram and Pareto flushes,
+    the result).
     """
     with TraceAnnotation("sweep.whatif"):
         with TraceAnnotation("sweep.prepare"):
@@ -613,50 +651,64 @@ def run_sweep(spec: SweepSpec, *, path: str = "jnp",
                                  "around run_sweep")
             n_cells = spec.n_cells
             tile = max(1, min(tile_cells, n_cells))
-            step, tables = _sweep_step(spec, tile, path, dtype.name, n_hist,
-                                       n_pareto, interpret)
             C = spec.n_candidates
-            fields = ("mean", "p50", "p90", "p99", "min", "max",
-                      "mean_emb", "mean_op", "fleet_mean")
-            host = {f: np.empty(n_cells, dtype) for f in fields}
-            host_counts = np.empty((n_cells, C), np.int32)
+            row_bytes = len(_STAT_FIELDS) * dtype.itemsize + 4 * C
+            cap = max(1, READBACK_BYTES // (row_bytes * tile)) * tile
+            rows = min(-(-n_cells // tile) * tile, cap)
+            step, init, tables = _sweep_step(spec, tile, rows, path,
+                                             dtype.name, n_hist, n_pareto,
+                                             interpret)
+            host = np.empty((len(_STAT_FIELDS), n_cells), dtype)
+            host_counts = np.empty((C, n_cells), np.int32)
             hist64 = np.zeros(n_hist, np.int64)
             par_host: Optional[Dict[str, np.ndarray]] = None
             since_flush = 0
+            host_reads = 0
 
             t0 = time.perf_counter()
-            acc = csk.init_acc(n_hist, n_pareto, jnp.dtype(dtype))
+            acc, buf = init()
         for start in range(0, n_cells, tile):
             with TraceAnnotation("sweep.step"):
-                acc, stats = step(acc, np.int32(start))
-            k = min(tile, n_cells - start)
-            with TraceAnnotation("sweep.readback"):
-                for f in fields:
-                    host[f][start:start + k] = np.asarray(stats[f])[:k]
-                host_counts[start:start + k] = \
-                    np.asarray(stats["counts"])[:k]
+                acc, buf = step(acc, buf, np.int32(start))
+            end = min(start + tile, n_cells)
+            last = end == n_cells
+            if last or end % rows == 0:
+                with TraceAnnotation("sweep.readback"):
+                    if last:
+                        (stats, counts), acc_host = jax.device_get((buf, acc))
+                    else:
+                        stats, counts = jax.device_get(buf)
+                    run0 = start - start % rows
+                    host[:, run0:end] = stats[:, :end - run0]
+                    host_counts[:, run0:end] = counts[:, :end - run0]
+                host_reads += 1
             since_flush += tile * spec.draws
-            if since_flush >= flush_limit:
+            # the last tile's accumulators come back with the final read
+            if since_flush >= flush_limit and not last:
                 with TraceAnnotation("sweep.finish"):
-                    hist64 += np.asarray(acc.hist, np.int64)
+                    flushed = jax.device_get(acc)
+                    hist64 += flushed.hist
                     par_host = _merge_pareto_host(par_host,
-                                                  _acc_to_host(acc))
+                                                  _acc_to_host(flushed))
                     acc = csk.init_acc(n_hist, n_pareto, jnp.dtype(dtype))
+                host_reads += 1
                 since_flush = 0
         with TraceAnnotation("sweep.finish"):
-            hist64 += np.asarray(acc.hist, np.int64)
-            par_host = _merge_pareto_host(par_host, _acc_to_host(acc))
+            hist64 += acc_host.hist
+            par_host = _merge_pareto_host(par_host, _acc_to_host(acc_host))
             wall = time.perf_counter() - t0
 
             shape = spec.axis_sizes
             return SweepResult(
                 spec=spec, path=path,
-                **{f: host[f].reshape(shape) for f in fields},
-                counts=host_counts.reshape(shape + (C,)),
+                **{f: host[i].reshape(shape)
+                   for i, f in enumerate(_STAT_FIELDS)},
+                counts=host_counts.T.reshape(shape + (C,)),
                 hist=hist64, hist_edges=tables.hist_edges(n_hist),
                 pareto=par_host, n_cells=n_cells,
                 n_scenarios=spec.n_scenarios, wall_s=wall,
-                scenarios_per_s=spec.n_scenarios / max(wall, 1e-12))
+                scenarios_per_s=spec.n_scenarios / max(wall, 1e-12),
+                host_reads=host_reads)
 
 
 # ------------------------------------------------- workload spec helper

@@ -104,7 +104,8 @@ def test_fleet_checkpoint_is_a_span_of_the_stream(tmp_path):
     assert len(named(spans, "fleet.sync")) == rep.packed.host_syncs
 
 
-def test_sweep_spans_one_step_and_one_readback_per_tile(tmp_path):
+def test_sweep_spans_one_step_per_tile_and_one_readback_per_whatif(
+        tmp_path):
     prof = DeviceProfile(n_one_stage=600, n_two_stage=400, vm_kb=0.4,
                          nvm_kb=1.0)
     spec = SweepSpec(
@@ -120,10 +121,11 @@ def test_sweep_spans_one_step_and_one_readback_per_tile(tmp_path):
     whatif = named(spans, "sweep.whatif")
     assert len(whatif) == 1
     steps, reads = named(spans, "sweep.step"), named(spans, "sweep.readback")
-    assert len(steps) == len(reads) == n_tiles
+    assert len(steps) == n_tiles
+    assert len(reads) == res.host_reads == 1
     assert len(named(spans, "sweep.prepare")) == 1
     assert len(named(spans, "sweep.finish")) == 1
     assert inside(steps + reads + named(spans, "sweep.prepare")
                   + named(spans, "sweep.finish"), whatif)
-    # tile i's read-back follows its step
-    assert all(s0 <= r0 for (s0, _), (r0, _) in zip(steps, reads))
+    # the one read-back follows the last tile's step
+    assert reads[0][0] >= max(e for _, e in steps)

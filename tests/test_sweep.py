@@ -6,6 +6,9 @@ The contracts pinned here, all as exact array equality:
 - same seed + different tile sizes -> bit-identical reductions (the
   counter-based per-cell seeding plus associative accumulators);
 - Pallas vs jnp paths bit-exact, at any row-tile size;
+- a sweep read back in several runs of tiles (a small device stats
+  buffer) equals the same sweep read back once, and `host_reads` counts
+  one read per run of tiles plus one per flush;
 - on point-mass lifetime distributions the device sweep equals the
   numpy `selection.total_grid` / `selection_map` oracles bit-for-bit
   (float64 under `enable_x64`), and Monte Carlo percentiles collapse
@@ -26,6 +29,7 @@ from repro.core.planner import plan_grid
 from repro.core.selection import (crossover_lifetime_s,
                                   crossover_lifetimes, selection_map,
                                   total_grid)
+from repro.core import sweep
 from repro.core.sweep import (LifetimeDist, SweepSpec, run_sweep,
                               serving_plan_jnp)
 from repro.flexibits.cycles import CORES
@@ -90,6 +94,47 @@ def test_same_seed_reproduces_different_seed_differs():
     c = run_sweep(dataclasses.replace(spec, seed=spec.seed + 1),
                   path="jnp", tile_cells=16)
     assert not np.array_equal(a.mean, c.mean)
+
+
+# ----------------------------------------------------------- read-back
+def _cap_tiles(monkeypatch, spec, tile, n):
+    """Set the stats-buffer budget to just under n + 1 tiles' worth of
+    float32 rows, so it rounds down to n whole tiles."""
+    row_bytes = len(sweep._STAT_FIELDS) * 4 + 4 * spec.n_candidates
+    monkeypatch.setattr(sweep, "READBACK_BYTES",
+                        row_bytes * tile * (n + 1) - 1)
+
+
+@pytest.mark.parametrize("tile,tiles_per_read", [(7, 1), (7, 3), (48, 1)])
+def test_capped_readback_bit_identical(monkeypatch, tile, tiles_per_read):
+    """108 cells: neither tile size divides them, so the last run of
+    tiles is partial."""
+    spec = dataclasses.replace(_mixture_spec(),
+                               intensities=(0.028, 0.2, 0.367),
+                               volumes=(1.0, 1e3, 1e9))
+    once = run_sweep(spec, path="jnp", tile_cells=tile)
+    _cap_tiles(monkeypatch, spec, tile, tiles_per_read)
+    capped = run_sweep(spec, path="jnp", tile_cells=tile)
+    n_tiles = -(-spec.n_cells // tile)
+    assert once.host_reads == 1
+    assert capped.host_reads == -(-n_tiles // tiles_per_read) >= 3
+    _assert_results_equal(once, capped)
+
+
+@pytest.mark.parametrize("path", ["jnp", "pallas"])
+def test_host_reads_count_runs_of_tiles_and_flushes(monkeypatch, path):
+    spec = _mixture_spec()                       # 48 cells: 3 tiles of 16
+    fits = run_sweep(spec, path=path, tile_cells=16)
+    assert fits.host_reads == 1
+    # a flush after every tile but the last, whose accumulators come
+    # back with the final read
+    flushed = run_sweep(spec, path=path, tile_cells=16, flush_limit=1)
+    assert flushed.host_reads == 1 + 2
+    _cap_tiles(monkeypatch, spec, 16, 1)
+    both = run_sweep(spec, path=path, tile_cells=16, flush_limit=1)
+    assert both.host_reads == 3 + 2
+    _assert_results_equal(fits, flushed)
+    _assert_results_equal(fits, both)
 
 
 # --------------------------------------------------- pallas A/B parity
