@@ -132,7 +132,7 @@ def total_kg(core: Core, prof: DeviceProfile, *, lifetime_s: float,
 # pair doubles the core + VM SRAM (each copy keeps private architectural
 # state) but shares the LPROM code store; TMR triples them. Operationally
 # DMR runs 2 copies per attempt and re-executes on a digest mismatch
-# (the fleet engine's segment-granular rollback), TMR runs 3 copies and
+# (the fleet engine's compare-granular rollback), TMR runs 3 copies and
 # votes with no retry. The unprotected mode pays differently: its faults
 # escape silently (SDC), so delivering the same number of *trusted*
 # results takes 1/(1-p) device-executions — a derating multiplier on

@@ -81,6 +81,13 @@ REDUNDANCY = ("none", "dmr")   # executed redundancy modes (§9.14; the
 _RESIDENT_MIX_LIMIT = 2**31 - 1
 _RESIDENT_KEEP_STATE_WORDS = 1 << 27   # ~512 MB of int32 device rows
 
+# DMR pair compares inside a segment (§9.14): under a transient schedule
+# a pair is compared often enough to expect at most COMPARE_FAULTS
+# faults between compares, but never more often than every
+# MIN_COMPARE_STEPS steps (a compare reads the whole lane pool)
+COMPARE_FAULTS = 1 / 512
+MIN_COMPARE_STEPS = 32
+
 # source protocol: source(start, count) -> (count, mem_words) int32
 Source = Callable[[int, int], np.ndarray]
 
@@ -489,6 +496,7 @@ class PackedStats:
     detected: int = 0             # DMR digest mismatches observed
     corrected: int = 0            # pair rollbacks that re-executed
     quarantined: int = 0          # pairs permanently retired from pool
+    discarded: int = 0            # lane-steps mismatching pairs threw away
     sdc: int = 0
 
 
@@ -652,12 +660,30 @@ def _packed_state_specs(mesh: Mesh, mem_words: int):
     return dsharding.lane_specs(mesh, abstract)
 
 
+def compare_steps(faults: Optional[flexifault.FaultSpec],
+                  seg_steps: int) -> int:
+    """Steps between DMR pair compares in a segment of `seg_steps`.
+
+    Under a transient schedule, `seg_steps` halved until a pair (two
+    lanes at `faults.rate` a step each) expects at most COMPARE_FAULTS
+    faults between compares, stopping at MIN_COMPARE_STEPS; a rollback
+    then discards at most that many steps, and three mismatches in a
+    row (a quarantine at the default `max_retries`) stay out of reach
+    of transients. Otherwise, and for stuck/dead defects, which recur
+    on every retry, once a segment, at its boundary."""
+    k = seg_steps
+    if faults is not None and faults.mode == "transient":
+        while k > MIN_COMPARE_STEPS and 2 * k * faults.rate > COMPARE_FAULTS:
+            k = max(MIN_COMPARE_STEPS, -(-k // 2))
+    return k
+
+
 @functools.lru_cache(maxsize=None)
 def _packed_segment_runner(stepper: str, chunk: int, seg_steps: int,
                            mem_words: int, n_progs: int, bank_width: int,
                            mesh: Optional[Mesh], subset, timing: bool,
                            faults: Optional[flexifault.FaultSpec] = None,
-                           donate_state: bool = True):
+                           dmr: bool = False, max_retries: int = 0):
     """Compiled packed segment runner, cached per engine configuration.
 
     The bank, per-program code lengths, per-program memory bounds, and
@@ -672,20 +698,34 @@ def _packed_segment_runner(stepper: str, chunk: int, seg_steps: int,
     pre-FlexiFault signature and graph; with a schedule on, the runner
     takes the per-lane `lane_key`/`epoch` arrays as two extra traced
     inputs ahead of the donated state.
+
+    With `dmr` (§9.14) the runner takes `(lane_key, epoch, retries)`
+    ahead of the state and returns `(state, snap, epoch, retries,
+    counts)`: it steps the pool in runs of `compare_steps(faults,
+    seg_steps)` and, after each run but the last, compares every lane
+    pair's digest. A pair that disagrees rolls back to `snap`, its
+    state at the previous compare, with a bumped epoch (fresh draws),
+    and counts one retry; one that disagrees after `max_retries`
+    retries in a row is left as it is, halted, for the boundary op to
+    quarantine. Pairs that agree reset their retries and become the
+    new `snap`. The last run is compared at the boundary by
+    `refill_dmr`, against the returned `snap`; `counts` is the
+    shard's `[rollbacks, discarded lane-steps]` of the runs compared
+    here.
     """
     def seg_body(bank, code_len, mem_len, cost, state,
-                 lane_key=None, epoch=None):
+                 lane_key=None, epoch=None, steps=seg_steps):
         cr = cost if timing else None
         if stepper == "switch":
             if faults is None:
                 lanes = jax.vmap(
                     lambda p, m, l: iss.run_segment_banked(
-                        bank, code_len, p, m, l, seg_steps, mem_len, cr)
+                        bank, code_len, p, m, l, steps, mem_len, cr)
                 )(state.prog_id, state.max_steps, state.lanes)
             else:
                 lanes = jax.vmap(
                     lambda p, m, k, e, l: iss.run_segment_banked(
-                        bank, code_len, p, m, l, seg_steps, mem_len, cr,
+                        bank, code_len, p, m, l, steps, mem_len, cr,
                         faults=faults, lane_key=k, epoch=e)
                 )(state.prog_id, state.max_steps, lane_key, epoch,
                   state.lanes)
@@ -693,16 +733,75 @@ def _packed_segment_runner(stepper: str, chunk: int, seg_steps: int,
                                    max_steps=state.max_steps)
         if stepper == "pallas":
             return iss_stepper.iss_segment_banked(
-                bank, code_len, state, seg_steps=seg_steps, subset=subset,
+                bank, code_len, state, seg_steps=steps, subset=subset,
                 mem_len=mem_len, cost=cr, faults=faults,
                 lane_key=lane_key, epoch=epoch)
         return iss.run_segment_lanes_banked(bank, code_len, state,
-                                            seg_steps, subset, mem_len,
+                                            steps, subset, mem_len,
                                             cr, faults=faults,
                                             lane_key=lane_key,
                                             epoch=epoch)
 
-    if faults is None:
+    def pair_compare(state, snap, epoch, retries, frozen, counts):
+        """One compare of every lane pair inside the segment."""
+        lanes = state.lanes
+        d = flexifault.arch_digest(lanes.regs, lanes.pc, lanes.mem,
+                                   lanes.halted, lanes.n_instr,
+                                   lanes.n_two_stage, lanes.n_cycles,
+                                   lanes.mix).reshape(-1, 2)
+        mismatch = (d[:, 0] != d[:, 1]) & ~frozen
+        freeze = mismatch & (retries >= max_retries)
+        rollback = mismatch & ~freeze
+        rb_l = jnp.repeat(rollback, 2)
+        discarded = jnp.sum(jnp.where(rb_l, lanes.n_instr - snap.n_instr,
+                                      0))
+
+        def rb(a, b):
+            m = rb_l.reshape(rb_l.shape + (1,) * (b.ndim - 1))
+            return jnp.where(m, a, b)
+        lanes = jax.tree.map(rb, snap, lanes)
+        frozen = frozen | freeze
+        fz_l = jnp.repeat(frozen, 2)
+        lanes = lanes._replace(halted=lanes.halted | fz_l)
+
+        def keep(a, b):
+            m = fz_l.reshape(fz_l.shape + (1,) * (b.ndim - 1))
+            return jnp.where(m, a, b)
+        snap = jax.tree.map(keep, snap, lanes)
+        one = jnp.asarray(1, iss.I32)
+        epoch = jnp.where(rb_l, epoch + one, epoch)
+        retries = jnp.where(rollback, retries + one,
+                            jnp.where(frozen, retries,
+                                      jnp.zeros_like(retries)))
+        counts = counts + jnp.stack([rollback.sum().astype(iss.I32),
+                                     discarded.astype(iss.I32)])
+        return (state._replace(lanes=lanes), snap, epoch, retries, frozen,
+                counts)
+
+    k = compare_steps(faults, seg_steps)
+    n_cmp = (seg_steps - 1) // k          # compares inside the segment
+    lane = None if mesh is None else P(tuple(mesh.axis_names))
+    if dmr:
+        def seg(bank, code_len, mem_len, cost, lane_key, epoch, retries,
+                state):
+            def run(_, carry):
+                st, snap, ep, rt, frozen, counts = carry
+                st = seg_body(bank, code_len, mem_len, cost, st,
+                              lane_key=lane_key, epoch=ep, steps=k)
+                return pair_compare(st, snap, ep, rt, frozen, counts)
+            carry = (state, state.lanes, epoch, retries,
+                     jnp.zeros_like(retries, bool),
+                     jnp.zeros(2, iss.I32))
+            if n_cmp:
+                carry = jax.lax.fori_loop(0, n_cmp, run, carry)
+            state, snap, epoch, retries, _, counts = carry
+            state = seg_body(bank, code_len, mem_len, cost, state,
+                             lane_key=lane_key, epoch=epoch,
+                             steps=seg_steps - n_cmp * k)
+            return state, snap, epoch, retries, counts[None]
+        donate = (5, 6, 7)
+        extra_specs = (lane, lane, lane)
+    elif faults is None:
         def seg(bank, code_len, mem_len, cost, state):
             return seg_body(bank, code_len, mem_len, cost, state)
         donate = (4,)
@@ -712,21 +811,18 @@ def _packed_segment_runner(stepper: str, chunk: int, seg_steps: int,
             return seg_body(bank, code_len, mem_len, cost, state,
                             lane_key=lane_key, epoch=epoch)
         donate = (6,)
-        extra_specs = None  # filled below (needs the mesh axes)
-    if not donate_state:
-        # DMR holds the boundary state as its rollback snapshot while
-        # the segment runs — the input pool must survive the call
-        donate = ()
+        extra_specs = (lane, lane)
 
     if mesh is None:
         return jax.jit(seg, donate_argnums=donate)
     specs = _packed_state_specs(mesh, mem_words)
     bspecs = dsharding.bank_specs(mesh, (0, 0, 0, 0))
-    if faults is not None:
-        lane = P(tuple(mesh.axis_names))
-        extra_specs = (lane, lane)
+    out_specs = specs
+    if dmr:
+        out_specs = (specs, specs.lanes, lane, lane,
+                     P(tuple(mesh.axis_names), None))
     fn = shard_map(seg, mesh=mesh, in_specs=(*bspecs, *extra_specs, specs),
-                   out_specs=specs, check_vma=False)
+                   out_specs=out_specs, check_vma=False)
     return jax.jit(fn, donate_argnums=donate)
 
 
@@ -979,38 +1075,49 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
                               epoch)
         return new_state, new_slot, new_epoch, acc, stats
 
-    def refill_dmr(state, item_slot, epoch, retries, quar, snap, acc,
-                   staged_mems, staged_prog, staged_ms, staged_slot,
-                   n_staged, out_addr):
+    def refill_dmr(state, item_slot, epoch, retries, quar, snap,
+                   seg_counts, acc, staged_mems, staged_prog, staged_ms,
+                   staged_slot, n_staged, out_addr):
         """DMR shadow-lane retire/refill (§9.14).
 
         Lanes pair up as (2p primary, 2p+1 shadow); both run the SAME
         item image but draw independent fault schedules (different
         physical lane keys). At every refill boundary the pair's
         architectural digests are compared: a mismatch means at least
-        one lane was hit since the last boundary, so the pair rolls
-        back to `snap` (its state at the previous boundary — the exact
-        segment re-executes) with a bumped epoch (fresh draws; a
-        transient won't recur, a stuck-at/dead defect will). A pair
-        that mismatches `max_retries` times in a row is quarantined —
-        parked forever, its item handed back to the host for
-        re-admission on healthy lanes — at most one pair per shard per
-        boundary, so the host's re-admission bookkeeping is one scalar
-        per shard. Pairs whose digests agree retire/refill exactly as
-        the base loop, at pair granularity (the shadow carries item
-        row -1 and never scatters). The next boundary's snapshot is the
-        op's OUTPUT state (for rolled-back pairs that IS the old snap)
-        — the host keeps that reference while the segment executes,
-        which is why the DMR segment runner does not donate its state.
+        one lane was hit since the segment's last compare, so the pair
+        rolls back to `snap` (its state at that compare, returned by
+        the segment runner — those steps re-execute) with a bumped
+        epoch (fresh draws; a transient won't recur, a stuck-at/dead
+        defect will). A pair
+        that mismatches `max_retries + 1` times in a row is quarantined:
+        it is rolled back to the snapshot, its last agreed state, and
+        parked forever, still holding its item; the first free pair of
+        this or a later boundary takes that state over and resumes the
+        item where the pair agreed last, ahead of any staged item.
+        Pairs whose digests agree retire/refill exactly as the base
+        loop, at pair granularity (the shadow carries item row -1 and
+        never scatters).
+        The stats block adds, after the base loop's three columns,
+        [mismatches, rollbacks, pairs quarantined, discarded] ahead of
+        the per-group counts; `discarded` sums n_instr - snap.n_instr
+        over both lanes of every mismatching pair, rolled back or
+        quarantined: the lane-steps thrown away. The segment's own
+        compares (`seg_counts`: [rollbacks, discarded]) are added in.
         """
         lanes = state.lanes
         one = jnp.asarray(1, iss.I32)
-        active = item_slot >= 0        # primaries only (shadows: -1)
+        n_pairs = quar.shape[0]
+        slot_p = item_slot.reshape(-1, 2)[:, 0]
+        # primaries of running pairs (shadows: -1; a quarantined pair's
+        # primary keeps its row while it holds the item)
+        active = (item_slot >= 0) & ~jnp.repeat(quar, 2)
 
         # ---- pair views: chunk % (2 * n_shards) == 0 (validated in
         # run_packed), so a pair never straddles a shard boundary
         d = flexifault.arch_digest(lanes.regs, lanes.pc, lanes.mem,
-                                   lanes.halted, lanes.n_instr)
+                                   lanes.halted, lanes.n_instr,
+                                   lanes.n_two_stage, lanes.n_cycles,
+                                   lanes.mix)
         d2 = d.reshape(-1, 2)
         pair_active = active.reshape(-1, 2)[:, 0]
         mismatch = pair_active & (d2[:, 0] != d2[:, 1])
@@ -1018,11 +1125,8 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
         pair_retire = (pair_active & done_l.reshape(-1, 2)[:, 0]
                        & ~mismatch)
 
-        wants_q = mismatch & (retries >= max_retries)
-        new_q = wants_q & (jnp.cumsum(wants_q.astype(iss.I32)) == 1)
+        new_q = mismatch & (retries >= max_retries)
         rollback = mismatch & ~new_q
-        q_slot = jnp.max(jnp.where(
-            new_q, item_slot.reshape(-1, 2)[:, 0], -1))
 
         # ---- accounting of the segment that just ran
         delta = jnp.max(lanes.n_instr - acc.prev_instr, initial=0)
@@ -1034,10 +1138,12 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
             & jnp.repeat(pair_retire, 2)
         acc = scatter_retired(state, item_slot, acc, out_addr, retired)
 
-        # ---- roll mismatching pairs back to the last good boundary,
-        # park the quarantined pair
-        rb_l = jnp.repeat(rollback, 2)
+        # ---- roll mismatching pairs back to the last agreed boundary;
+        # park the quarantined ones there
+        rb_l = jnp.repeat(mismatch, 2)
         q_l = jnp.repeat(new_q, 2)
+        discarded = jnp.sum(jnp.where(rb_l, lanes.n_instr - snap.n_instr,
+                                      0))
 
         def rb(a, b):
             m = rb_l.reshape(rb_l.shape + (1,) * (b.ndim - 1))
@@ -1046,13 +1152,35 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
         lanes2 = jax.tree.map(rb, snap, lanes)
         lanes2 = lanes2._replace(
             halted=jnp.where(q_l, True, lanes2.halted))
-        state = iss.PackedState(lanes=lanes2, prog_id=state.prog_id,
-                                max_steps=state.max_steps)
 
-        # ---- refill freed pairs; both lanes get the item image, only
-        # the primary carries the accumulator row
-        free_p = (pair_retire | ~pair_active) & ~(quar | new_q)
-        take_p, src_p = iss.refill_take(free_p, n_staged[0])
+        # ---- free pairs resume held items first (a held item's state is
+        # its pair's primary lane), then take staged items
+        quar2 = quar | new_q
+        held = quar2 & (slot_p >= 0)
+        free_p = (pair_retire | ~pair_active) & ~quar2
+        f_rank = jnp.cumsum(free_p.astype(iss.I32)) - 1
+        resume_p = free_p & (f_rank < held.sum())
+        held_idx = jnp.nonzero(held, size=n_pairs, fill_value=0)[0]
+        from_l = 2 * jnp.repeat(
+            held_idx[jnp.clip(f_rank, 0, n_pairs - 1)], 2)
+        resume_l = jnp.repeat(resume_p, 2)
+        released = held & (jnp.cumsum(held.astype(iss.I32)) - 1
+                           < free_p.sum())
+
+        def resume(x):
+            m = resume_l.reshape(resume_l.shape + (1,) * (x.ndim - 1))
+            return jnp.where(m, x[from_l], x)
+
+        lanes3 = jax.tree.map(resume, lanes2)
+        lanes3 = lanes3._replace(
+            halted=jnp.where(resume_l, False, lanes3.halted))
+        state = iss.PackedState(lanes=lanes3,
+                                prog_id=resume(state.prog_id),
+                                max_steps=resume(state.max_steps))
+
+        # ---- refill the other free pairs; both lanes get the item
+        # image, only the primary carries the accumulator row
+        take_p, src_p = iss.refill_take(free_p & ~resume_p, n_staged[0])
         take_l = jnp.repeat(take_p, 2)
         src_l = jnp.repeat(src_p, 2)
         new_state = iss.refill_lanes(state, take_l, src_l,
@@ -1061,8 +1189,11 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
         is_primary = (jnp.arange(item_slot.shape[0]) % 2) == 0
         new_slot = jnp.where(
             take_l & is_primary, staged_slot[0][src_l],
-            jnp.where(retired | q_l, -1, item_slot))
-        new_epoch = jnp.where(take_l | rb_l, epoch + one, epoch)
+            jnp.where(resume_l & is_primary, item_slot[from_l],
+                      jnp.where(retired | jnp.repeat(released, 2), -1,
+                                item_slot)))
+        new_epoch = jnp.where(take_l | rb_l | resume_l, epoch + one,
+                              epoch)
         # consecutive-mismatch counter: any clean boundary resets it
         # (a long-lived item accrues many independent transients over
         # its lifetime; only an unrecoverable streak should quarantine)
@@ -1075,17 +1206,19 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
             jnp.stack([pair_retire.sum().astype(iss.I32),
                        take_p.sum().astype(iss.I32),
                        delta.astype(iss.I32),
-                       mismatch.sum().astype(iss.I32),
-                       rollback.sum().astype(iss.I32),
-                       q_slot.astype(iss.I32)]), act_g])[None]
-        return (new_state, new_slot, new_epoch, new_retries,
-                quar | new_q, acc, stats)
+                       mismatch.sum().astype(iss.I32) + seg_counts[0, 0],
+                       rollback.sum().astype(iss.I32) + seg_counts[0, 0],
+                       new_q.sum().astype(iss.I32),
+                       discarded.astype(iss.I32) + seg_counts[0, 1]]),
+            act_g])[None]
+        return (new_state, new_slot, new_epoch, new_retries, quar2, acc,
+                stats)
 
     if dmr:
         # snap (arg 5) is NOT donated: the new-state output already
         # reuses the state input's buffers, so snap's would go unused
         # (it is freed by refcount when the host drops the reference)
-        fn, donate = refill_dmr, (0, 1, 2, 3, 4, 6)
+        fn, donate = refill_dmr, (0, 1, 2, 3, 4, 7)
     elif faults_on:
         fn, donate = refill_faults, (0, 1, 2, 3)
     else:
@@ -1101,7 +1234,7 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
     if dmr:
         snap_specs = state_specs.lanes
         carry_in = (state_specs, lane, lane, lane, lane, snap_specs,
-                    acc_specs)
+                    P(axes, None), acc_specs)
         carry_out = (state_specs, lane, lane, lane, lane, acc_specs)
     elif faults_on:
         carry_in = (state_specs, lane, lane, acc_specs)
@@ -1196,11 +1329,14 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     fault transform under its own `fold_in`-derived key, bit-identically
     across all three steppers. `redundancy="dmr"` pairs lanes as
     primary+shadow running the same item under independent schedules,
-    compares architectural digests at every segment boundary, rolls
-    mismatching pairs back to the boundary's snapshot (re-executing the
-    segment under fresh draws), and after `max_retries` consecutive
-    mismatches quarantines the pair — parking the defective lanes and
-    re-admitting the item on healthy ones. Both require the resident
+    compares architectural digests every `compare_steps(faults,
+    seg_steps)` steps (inside the segment runner, and at the segment
+    boundary), rolls mismatching pairs back to the previous compare's
+    snapshot (re-executing those steps under fresh draws), and after
+    `max_retries` consecutive rollbacks quarantines the pair at its
+    next mismatch — parking the defective lanes and resuming the item
+    from the pair's last agreed state on the next free pair. Both
+    require the resident
     loop (`refill="device"`) and are incompatible with `checkpoint_dir`
     (the rollback snapshots are not part of the durable snapshot
     schema); `faults=None` with `redundancy="none"` is bit-exact with
@@ -1388,7 +1524,8 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         redundancy=redundancy,
         detected=int(out.get("detected", 0)),
         corrected=int(out.get("corrected", 0)),
-        quarantined=int(out.get("quarantined", 0)))
+        quarantined=int(out.get("quarantined", 0)),
+        discarded=int(out.get("discarded", 0)))
     return results, stats
 
 
@@ -1649,7 +1786,7 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
     lane_steps = 0
     n_segments = 0
     prev_seg = 0
-    detected = corrected = quarantined = 0        # §9.14 counters
+    detected = corrected = quarantined = discarded = 0   # §9.14 counters
     n_quar = np.zeros(n_shards, np.int64)         # quarantined pairs
 
     # ---- resume? (canonical checkpoint — independent of the mesh and
@@ -1748,25 +1885,8 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
         stage_sh = dsharding.stage_shardings(
             mesh, (st_mems, st_prog, st_ms, st_slot))
 
-    # quarantined pairs hand their item back here; restock re-stages it
-    # (same accumulator row — the healthy pair that picks it up scatters
-    # into the row the item always owned) ahead of fresh admissions
-    requeue = [[] for _ in range(n_shards)]
-
     def restock():
         changed = False
-        for s in range(n_shards):
-            while requeue[s] and int(staged_n[s]) < spc:
-                g, local, slot = requeue[s].pop(0)
-                off = int(staged_n[s])
-                st_mems[s, off] = 0
-                st_mems[s, off, :groups[g].mem_words] = \
-                    np.asarray(groups[g].source(local, 1), np.int32)[0]
-                st_prog[s, off] = g
-                st_ms[s, off] = ms_of[g]
-                st_slot[s, off] = slot
-                staged_n[s] = off + 1
-                changed = True
         for s in range(n_shards):
             free = spc - int(staged_n[s])
             remaining = pend_n[:, s] - staged_cursor[:, s]
@@ -1861,16 +1981,23 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
         prog_id=jnp.asarray(prog_l), max_steps=jnp.asarray(ms_l))
     item_slot = jnp.asarray(slot_l, iss.I32)
     # resilience state (§9.14): per-lane fault keys/epochs, per-pair
-    # retry counters + quarantine flags, and the rollback snapshot
-    lane_key = None
+    # retry counters + quarantine flags, the rollback snapshot and the
+    # segment's own compare counts
+    lane_key = seg_faults = None
     if faults is not None:
         lane_key = jnp.asarray(flexifault.lane_keys(faults.seed, chunk))
+        # the seed enters only through the lane keys: one compiled
+        # segment serves every seed of a schedule
+        seg_faults = dataclasses.replace(faults, seed=0)
+    elif dmr:
+        lane_key = jnp.zeros(chunk, jnp.uint32)   # unread without faults
     epoch = jnp.zeros(chunk, iss.I32) if (faults is not None or dmr) \
         else None
     retries = jnp.zeros(chunk // 2, iss.I32) if dmr else None
     quar_d = jnp.zeros(chunk // 2, bool) if dmr else None
     snap = jax.tree.map(lambda x: jnp.array(x, copy=True),
                         state.lanes) if dmr else None
+    seg_counts = jnp.zeros((n_shards, 2), iss.I32) if dmr else None
     acc = ResidentAcc(
         n_instr=jnp.zeros(n_shards * cap, iss.I32),
         n_two=jnp.zeros(n_shards * cap, iss.I32),
@@ -1902,6 +2029,9 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
         epoch = _lane_put(epoch)
         retries = _lane_put(retries)
         quar_d = _lane_put(quar_d)
+        if seg_counts is not None:
+            seg_counts = jax.device_put(
+                seg_counts, dsharding.lane_shardings(mesh, seg_counts))
         if snap is not None:
             snap = jax.tree.map(jax.device_put, snap,
                                 dsharding.lane_shardings(mesh, snap))
@@ -2011,13 +2141,8 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
                         (state, item_slot, epoch, retries, quar_d, acc,
                          stats) = refill_fn(
                             state, item_slot, epoch, retries, quar_d,
-                            snap, acc, *staged["dev"], staged_dev_n,
-                            out_addr_dev)
-                        # the refreshed boundary state IS the next
-                        # rollback snapshot; holding it here (while the
-                        # non-donating segment runs) keeps its buffers
-                        # alive
-                        snap = state.lanes
+                            snap, seg_counts, acc, *staged["dev"],
+                            staged_dev_n, out_addr_dev)
                     elif faults is not None:
                         state, item_slot, epoch, acc, stats = refill_fn(
                             state, item_slot, epoch, acc, *staged["dev"],
@@ -2032,9 +2157,13 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
                     # lowered HLO
                     seg_fn = _packed_segment_runner(
                         stepper, chunk, seg_steps, mem_words, n_groups,
-                        bank_np.shape[1], mesh, subset, timing, faults,
-                        not dmr)
-                    if faults is not None:
+                        bank_np.shape[1], mesh, subset, timing,
+                        seg_faults, dmr, max_retries if dmr else 0)
+                    if dmr:
+                        state, snap, epoch, retries, seg_counts = seg_fn(
+                            bank, code_len, mem_len, cost, lane_key,
+                            epoch, retries, state)
+                    elif faults is not None:
                         state = seg_fn(bank, code_len, mem_len, cost,
                                        lane_key, epoch, state)
                     else:
@@ -2044,31 +2173,23 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
                         stats.copy_to_host_async()
                 # blocks until refill_i only — seg_i is already running;
                 # one (n_shards, 3+G) read regardless of device count
-                # ((n_shards, 6+G) under DMR: +detected/corrected/q_slot)
+                # ((n_shards, 7+G) under DMR: +detected/corrected/
+                # quarantined/discarded)
                 sv = np.asarray(clock.fetch(stats), np.int64)
                 n_ret = int(sv[:, 0].sum())
                 if dmr:
                     detected += int(sv[:, 3].sum())
                     corrected += int(sv[:, 4].sum())
-                    for s in np.nonzero(sv[:, 5] >= 0)[0]:
-                        # quarantined pair: map the acc row back to the
-                        # item and hand it to restock for re-admission
-                        row = int(s) * cap + int(sv[s, 5])
-                        item = int(row_owner[row])
-                        g = int(np.searchsorted(slot_base, item,
-                                                side="right") - 1)
-                        requeue[int(s)].append(
-                            (g, item - int(slot_base[g]), int(sv[s, 5])))
-                        quarantined += 1
-                        n_quar[int(s)] += 1
-                        if n_quar[int(s)] >= spc // 2:
-                            raise RuntimeError(
-                                f"DMR pool starved: all {spc // 2} lane "
-                                f"pair(s) of shard {int(s)} are "
-                                f"quarantined with items still pending — "
-                                f"raise chunk, raise max_retries, or fix "
-                                f"the fault rate")
-                    act_s = sv[:, 6:]
+                    quarantined += int(sv[:, 5].sum())
+                    discarded += int(sv[:, 6].sum())
+                    n_quar += sv[:, 5]
+                    if (n_quar >= spc // 2).any():
+                        raise RuntimeError(
+                            f"DMR pool starved: all {spc // 2} lane "
+                            f"pairs of a shard are quarantined with "
+                            f"items still pending — raise chunk, raise "
+                            f"max_retries, or fix the fault rate")
+                    act_s = sv[:, 7:]
                 else:
                     act_s = sv[:, 3:]
                 deltas = sv[:, 2]
@@ -2139,7 +2260,7 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
             "shard_retired": shard_retired.tolist(),
             "shard_lane_steps": shard_steps.tolist(),
             "detected": detected, "corrected": corrected,
-            "quarantined": quarantined}
+            "quarantined": quarantined, "discarded": discarded}
 
 
 def run_workload_stream(w: Workload, n_items: int, *, seed: int = 0,

@@ -138,9 +138,9 @@ class FleetPlan:
     `faults`/`redundancy`/`max_retries` turn on the FlexiFault
     resilience layer (DESIGN.md §9.14): a deterministic counter-based
     fault schedule injected into every lane, and — with
-    `redundancy="dmr"` — shadow-lane detection with segment-granular
-    re-execution and quarantine. Resilient plans require the resident
-    refill loop; `faults=None` with `redundancy="none"` (the default)
+    `redundancy="dmr"` — shadow-lane detection with rollback to the
+    last pair compare (`engine.compare_steps`) and quarantine.
+    Resilient plans require the resident refill loop; `faults=None` with `redundancy="none"` (the default)
     keeps the fault-free graphs bit-exact. The report prices each group
     under the plan's redundancy mode (`carbon.redundant_*`), so DMR
     plans show the spare-area + re-execution carbon they'd pay in

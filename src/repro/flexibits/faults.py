@@ -264,21 +264,31 @@ def apply_faults(spec: Optional[FaultSpec], lane_key, epoch, state,
     return state._replace(regs=regs, pc=pc, mem=mem)
 
 
-def arch_digest(regs, pc, mem, halted, n_instr):
-    """Per-lane 32-bit digest of the architectural state.
+def arch_digest(regs, pc, mem, halted, n_instr, n_two_stage, n_cycles,
+                mix):
+    """Per-lane 32-bit digest of the architectural state and the tallies
+    an item reports.
 
-    The DMR boundary compare (fleet/engine.py): two lanes that executed
+    The DMR pair compare (fleet/engine.py): two lanes that executed
     the same item fault-free have equal digests; any surviving state
-    corruption shows up as an inequality. Position-mixed so permuted
-    corruption cannot cancel; uint32 sums wrap, which is fine — the
-    digest is a determinism check, not cryptography.
+    corruption shows up as an inequality. The two-stage, cycle and
+    instruction-mix tallies are folded in too: a fault that only
+    changes what an item reports (a flipped shift amount, overwritten
+    before anything reads it, still costs its serial-shift ticks) must
+    roll back like one that changes the state. Position-mixed so
+    permuted corruption cannot cancel; uint32 sums wrap, which is fine
+    — the digest is a determinism check, not cryptography.
     """
     rpos = mix32(_u(jnp.arange(16, dtype=I32)) + 1)
     mpos = mix32(_u(jnp.arange(mem.shape[-1], dtype=I32)) + 17)
+    xpos = mix32(_u(jnp.arange(mix.shape[-1], dtype=I32)) + 0x3C6EF372)
     d = jnp.sum(mix32(_u(regs) ^ rpos), axis=-1)
     d = d + jnp.sum(mix32(_u(mem) ^ mpos), axis=-1)
+    d = d + jnp.sum(mix32(_u(mix) ^ xpos), axis=-1)
     d = d + mix32(_u(pc) ^ _c(0x7FB5D329))
     d = d + mix32(_u(n_instr) ^ _c(0x2B7E1516))
+    d = d + mix32(_u(n_two_stage) ^ _c(0x243F6A88))
+    d = d + mix32(_u(n_cycles) ^ _c(0x13198A2E))
     return d + halted.astype(U32)
 
 

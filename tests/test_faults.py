@@ -5,6 +5,11 @@ engine, DMR detect/rollback/quarantine recovery end-to-end, the
 consecutive-retry quarantine semantics, golden-vs-faulty rate
 measurement, redundancy-aware planner reproduction at rate 0, and the
 FleetPlan wiring + resilience pricing."""
+import hashlib
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -254,6 +259,344 @@ def test_dmr_long_items_accrue_transients_without_quarantine():
     for f in _RESULT_FIELDS:
         np.testing.assert_array_equal(getattr(gold[0], f),
                                       getattr(dm[0], f), err_msg=f)
+
+
+def _timed_group(code, mems):
+    from repro.flexibits.cycles import CORES, cost_row
+    return engine.PackedGroup(code=code, source=engine.array_source(mems),
+                              n_items=len(mems), max_steps=400,
+                              mem_words=mems.shape[1], out_addr=1,
+                              cost=cost_row(CORES["HERV"], dynamic=True))
+
+
+_TALLY_FIELDS = _RESULT_FIELDS + ("n_two_stage", "n_cycles", "mix",
+                                  "mix_items")
+
+
+@pytest.mark.parametrize("tally", ["n_two_stage", "n_cycles", "mix"])
+@pytest.mark.parametrize("stepper", ["branchless", "pallas", "switch"])
+def test_dmr_rolls_back_a_tally_only_corruption(monkeypatch, stepper,
+                                                tally):
+    """A corruption that leaves regs, pc, memory and n_instr alone and
+    changes only what the item reports (its two-stage, cycle or mix
+    tally), planted in the primary lane of one pair after the first
+    segment, is caught by the pair compare and rolled back: the drain
+    equals the fault-free one."""
+    code, mems = _fleet(40)
+    gold, _ = engine.run_packed([_timed_group(code, mems)], chunk=16,
+                                seg_steps=64, keep_state=True)
+    real = engine._packed_segment_runner
+    planted = []
+
+    def runner(*args):
+        seg = real(*args)
+
+        def plant(*a):
+            state, *rest = seg(*a)         # DMR: (state, snap, ...)
+            if planted:
+                return (state, *rest)
+            planted.append(True)
+            lanes = state.lanes
+            v = getattr(lanes, tally)
+            v = v.at[0, 0].add(1) if v.ndim == 2 else v.at[0].add(1)
+            return (state._replace(lanes=lanes._replace(**{tally: v})),
+                    *rest)
+        return plant
+
+    monkeypatch.setattr(engine, "_packed_segment_runner", runner)
+    dm, ds = engine.run_packed([_timed_group(code, mems)], chunk=32,
+                               seg_steps=64, keep_state=True,
+                               redundancy="dmr", stepper=stepper)
+    assert planted
+    assert ds.detected >= 1 and ds.corrected >= 1
+    for f in _TALLY_FIELDS:
+        np.testing.assert_array_equal(getattr(gold[0], f),
+                                      getattr(dm[0], f), err_msg=f)
+
+
+def _boundary_discards(monkeypatch):
+    """Record, at each DMR boundary, n_instr - snapshot n_instr summed
+    over both lanes of every pair the boundary rolled back (its retry
+    count went up by one) or quarantined (its quarantine flag went
+    up), read from the op's own inputs and outputs."""
+    real = engine._resident_refill_runner
+    want = []
+
+    def runner(*args):
+        refill = real(*args)
+
+        def record(state, item_slot, epoch, retries, quar, snap, *rest):
+            n = np.asarray(state.lanes.n_instr, np.int64)
+            s = np.asarray(snap.n_instr, np.int64)
+            r0, q0 = np.asarray(retries), np.asarray(quar)
+            out = refill(state, item_slot, epoch, retries, quar, snap,
+                         *rest)
+            lost = np.repeat((np.asarray(out[3]) == r0 + 1)
+                             | (np.asarray(out[4]) & ~q0), 2)
+            want.append(int((n - s)[lost].sum()))
+            return out
+        return record
+
+    monkeypatch.setattr(engine, "_resident_refill_runner", runner)
+    return want
+
+
+@pytest.mark.parametrize("spec,max_retries", [
+    (faults.FaultSpec(rate=0.0008, seed=5, targets=("regs", "mem", "pc")),
+     6),
+    (faults.FaultSpec(rate=0.3, seed=5, mode="dead"), 1),
+], ids=["transient", "dead"])
+def test_dmr_counts_the_work_its_rollbacks_discard(monkeypatch, spec,
+                                                   max_retries):
+    """`PackedStats.discarded` is 0 where nothing mismatches, and under
+    faults it equals n_instr - snapshot n_instr summed over both lanes
+    of every mismatching pair: at each boundary, read from the refill's
+    own inputs and outputs (a pair rolled back iff its retry count went
+    up by one, and was quarantined iff its quarantine flag went up;
+    dead lanes recur on retry, so their pairs quarantine), plus what
+    the segment runner reports of its own compares (transients only:
+    dead lanes are compared at the boundary alone)."""
+    code, mems = _fleet(40)
+    _, clean = engine.run_packed([_group(code, mems)], chunk=32,
+                                 seg_steps=64, redundancy="dmr")
+    assert clean.corrected == 0 and clean.discarded == 0
+
+    want = _boundary_discards(monkeypatch)
+    seen = _seg_counts(monkeypatch)
+    _, ds = engine.run_packed([_group(code, mems)], chunk=32,
+                              seg_steps=64, faults=spec, redundancy="dmr",
+                              max_retries=max_retries)
+    inside = int(np.sum(seen, axis=0)[1])
+    assert ds.corrected > 0
+    assert (ds.quarantined > 0) == (spec.mode == "dead")
+    assert (inside > 0) == (spec.mode == "transient")
+    assert ds.discarded == sum(want) + inside > 0
+
+
+def test_dmr_quarantined_item_resumes_from_the_last_agreed_state(
+        monkeypatch):
+    """A quarantined pair parks at its snapshot, the state both lanes
+    last agreed on, still holding its item; the free pair that takes
+    the item over starts from that state on both lanes, not from the
+    item's first instruction, and the drain stays golden. With
+    `max_retries` 0 every mismatch quarantines, so items that ran clean
+    segments first are parked part-way."""
+    prog = skew_program()
+    mems = skew_fleet(prog, 16, short_iters=64, long_iters=600,
+                      long_frac=0.5, seed=7)
+
+    def group():
+        return engine.PackedGroup(code=prog.code,
+                                  source=engine.array_source(mems),
+                                  n_items=16, max_steps=100_000,
+                                  mem_words=32, out_addr=1)
+
+    gold, _ = engine.run_packed([group()], chunk=16, seg_steps=64,
+                                keep_state=True)
+    real = engine._resident_refill_runner
+    held, resumed = {}, []
+    fields = ("regs", "pc", "mem", "n_instr", "n_two_stage", "n_cycles",
+              "mix")
+
+    def runner(*args):
+        refill = real(*args)
+
+        def record(state, item_slot, epoch, retries, quar, snap, *rest):
+            slot0, q0 = np.asarray(item_slot), np.asarray(quar)
+            snap_h = {f: np.asarray(getattr(snap, f)) for f in fields}
+            out = refill(state, item_slot, epoch, retries, quar, snap,
+                         *rest)
+            lanes, slot = out[0].lanes, np.asarray(out[1])
+            for p in np.nonzero(np.asarray(out[4]) & ~q0)[0]:
+                held[int(slot0[2 * p])] = {f: v[2 * p]
+                                           for f, v in snap_h.items()}
+            for lane in range(0, slot.size, 2):
+                row = int(slot[lane])
+                if row in held and int(slot0[lane]) != row:
+                    for f in fields:
+                        v = np.asarray(getattr(lanes, f))
+                        np.testing.assert_array_equal(v[lane], held[row][f])
+                        np.testing.assert_array_equal(v[lane + 1],
+                                                      held[row][f])
+                    assert not np.asarray(lanes.halted)[lane:lane + 2].any()
+                    resumed.append(int(held.pop(row)["n_instr"]))
+            return out
+        return record
+
+    monkeypatch.setattr(engine, "_resident_refill_runner", runner)
+    mild = faults.FaultSpec(rate=0.0008, seed=5,
+                            targets=("regs", "mem", "pc"))
+    dq, dqs = engine.run_packed([group()], chunk=32, seg_steps=64,
+                                keep_state=True, faults=mild,
+                                redundancy="dmr", max_retries=0)
+    assert dqs.quarantined == len(resumed) > 0 and not held
+    assert max(resumed) > 0
+    for f in _RESULT_FIELDS:
+        np.testing.assert_array_equal(getattr(gold[0], f),
+                                      getattr(dq[0], f), err_msg=f)
+
+
+def _seg_counts(monkeypatch, frozen=None):
+    """Record the [rollbacks, discarded] each DMR segment run reports
+    of its own compares, and into `frozen` the pairs it returns halted
+    on both lanes but disagreeing."""
+    real = engine._packed_segment_runner
+    seen = []
+
+    def runner(*args):
+        seg = real(*args)
+
+        def record(*a):
+            out = seg(*a)
+            seen.append(np.asarray(out[4]).sum(0))
+            if frozen is not None:
+                lanes = out[0].lanes
+                halted = np.asarray(lanes.halted).reshape(-1, 2).all(1)
+                differ = np.zeros_like(halted)
+                for f in ("regs", "pc", "mem", "n_instr"):
+                    v = np.asarray(getattr(lanes, f))
+                    v = v.reshape(halted.size, 2, -1)
+                    differ |= (v[:, 0] != v[:, 1]).any(1)
+                frozen.append(int((halted & differ).sum()))
+            return out
+        return record
+
+    monkeypatch.setattr(engine, "_packed_segment_runner", runner)
+    return seen
+
+
+def test_compare_steps_follow_the_transient_rate():
+    """Pairs compare often enough to expect at most COMPARE_FAULTS faults
+    between compares, never more often than MIN_COMPARE_STEPS; without
+    transients, once a segment."""
+    assert engine.compare_steps(faults.FaultSpec(rate=1.6e-5), 4096) == 32
+    assert engine.compare_steps(faults.FaultSpec(rate=1e-6), 4096) == 512
+    assert engine.compare_steps(faults.FaultSpec(rate=0.0008), 4096) == 32
+    assert engine.compare_steps(faults.FaultSpec(rate=0.0008), 100) == 32
+    assert engine.compare_steps(faults.FaultSpec(rate=1e-9), 4096) == 4096
+    assert engine.compare_steps(None, 4096) == 4096
+    dead = faults.FaultSpec(rate=0.3, mode="dead")
+    assert engine.compare_steps(dead, 4096) == 4096
+
+
+@pytest.mark.parametrize("stepper", ["branchless", "pallas", "switch"])
+def test_dmr_compares_inside_the_segment(monkeypatch, stepper):
+    """With segments longer than the compare interval, pairs are compared
+    and rolled back inside the segment: the drain equals the fault-free
+    one, the segment's own rollbacks are counted as detected and
+    corrected, and `discarded` adds the steps they threw away to the
+    boundary's."""
+    code, mems = _fleet(40)
+    gold, _ = engine.run_packed([_group(code, mems)], chunk=16,
+                                seg_steps=256, keep_state=True)
+    seen = _seg_counts(monkeypatch)
+    want = _boundary_discards(monkeypatch)
+    mild = faults.FaultSpec(rate=0.0008, seed=5,
+                            targets=("regs", "mem", "pc"))
+    assert engine.compare_steps(mild, 256) == 32
+    dm, ds = engine.run_packed([_group(code, mems)], chunk=32,
+                               seg_steps=256, keep_state=True,
+                               faults=mild, redundancy="dmr",
+                               max_retries=6, stepper=stepper)
+    for f in _RESULT_FIELDS:
+        np.testing.assert_array_equal(getattr(gold[0], f),
+                                      getattr(dm[0], f), err_msg=f)
+    inside = np.sum(seen, axis=0)
+    assert inside[0] > 0 and inside[1] > 0
+    assert ds.corrected >= inside[0] and ds.detected >= ds.corrected
+    assert ds.discarded == sum(want) + inside[1]
+
+
+def test_dmr_pair_frozen_inside_the_segment_is_quarantined(monkeypatch):
+    """A pair that disagrees once more after `max_retries` retries inside
+    a segment stops there, halted, and the boundary quarantines it and
+    resumes its item on a free pair from the state both lanes last
+    agreed on: the drain stays golden."""
+    prog = skew_program()
+    mems = skew_fleet(prog, 16, short_iters=64, long_iters=600,
+                      long_frac=0.5, seed=7)
+
+    def group():
+        return engine.PackedGroup(code=prog.code,
+                                  source=engine.array_source(mems),
+                                  n_items=16, max_steps=100_000,
+                                  mem_words=32, out_addr=1)
+
+    gold, _ = engine.run_packed([group()], chunk=16, seg_steps=256,
+                                keep_state=True)
+    frozen = []
+    seen = _seg_counts(monkeypatch, frozen)
+    mild = faults.FaultSpec(rate=0.0008, seed=5,
+                            targets=("regs", "mem", "pc"))
+    dq, dqs = engine.run_packed([group()], chunk=32, seg_steps=256,
+                                keep_state=True, faults=mild,
+                                redundancy="dmr", max_retries=0)
+    for f in _RESULT_FIELDS:
+        np.testing.assert_array_equal(getattr(gold[0], f),
+                                      getattr(dq[0], f), err_msg=f)
+    # max_retries 0: no rollback anywhere, every mismatch quarantines
+    assert dqs.quarantined == dqs.detected > 0 and dqs.corrected == 0
+    assert np.sum(seen, axis=0)[0] == 0
+    assert sum(frozen) > 0
+
+
+_SEED_RUN = """
+import sys
+sys.path.insert(0, {tests!r})
+from test_faults import _seed_run
+print(_seed_run(int(sys.argv[1]))[0])
+"""
+
+
+def _seed_run(seed):
+    """A faulty packed run under schedule seed `seed`: (a digest of its
+    results, the segment runners it used)."""
+    code, mems = _fleet(40)
+    spec = faults.FaultSpec(rate=0.02, seed=seed,
+                            targets=("regs", "mem", "pc"))
+    real = engine._packed_segment_runner
+    used = set()
+
+    def runner(*args):
+        fn = real(*args)
+        used.add(fn)
+        return fn
+
+    engine._packed_segment_runner = runner
+    try:
+        res, _ = engine.run_packed([_group(code, mems)], chunk=16,
+                                   seg_steps=64, keep_state=True,
+                                   faults=spec)
+    finally:
+        engine._packed_segment_runner = real
+    h = hashlib.sha256()
+    for f in _RESULT_FIELDS:
+        h.update(np.ascontiguousarray(getattr(res[0], f)).tobytes())
+    return h.hexdigest(), used
+
+
+def test_fault_seed_does_not_key_the_compiled_runner():
+    """The schedule's seed enters only through the host-side lane keys,
+    so schedules that differ only in seed share one compiled segment
+    runner, and each run still equals a fresh process's run of its
+    seed bit for bit."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    root = os.path.dirname(here)
+    got = {}
+    for seed in (11, 12):
+        got[seed] = _seed_run(seed)
+    assert got[11][1] == got[12][1] and len(got[11][1]) == 1
+    assert got[11][0] != got[12][0], "the seed changed nothing"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(root, "src"), root,
+                    os.environ.get("PYTHONPATH", "")]))
+    for seed in (11, 12):
+        p = subprocess.run(
+            [sys.executable, "-c", _SEED_RUN.format(tests=here), str(seed)],
+            cwd=root, env=env, capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        assert p.stdout.split()[-1] == got[seed][0], seed
 
 
 def test_resilience_requires_resident_loop():
