@@ -8,7 +8,7 @@ Phases, all in this one process (a chip belongs to one process):
   a  the mixed Table-2 fleet — all 11 FlexiBench workloads in one packed
      `FleetPlan`, each on the core `selection.optimal_core` picks for
      its Table-2 lifetime and task frequency, dynamic timing, WCET step
-     budgets — through `run_plan` with the default stepper. Every item's
+     budgets — through `run_plan` with the branchless stepper. Every item's
      output must equal `Workload.ref`; the first and last item of each
      group must match the PyISS oracle's instruction, two-stage, cycle
      and mix tallies; the run must stay on the resident runtime.
@@ -176,7 +176,7 @@ def check_fleet(plan, report):
 def fleet_line(tag, plan, report, warm, run):
     st = report.packed
     return (f"phase {tag}: {device_tag(st.n_devices)} "
-            f"stepper={plan.stepper} "
+            f"stepper={st.stepper} "
             f"groups={len(plan.groups)} items={report.n_items} "
             f"({plan.groups[0].n_items}/group) "
             f"instructions_retired={report.busy_steps} "

@@ -26,8 +26,10 @@ def main():
                     help="items per group")
     ap.add_argument("--chunk", type=int, default=128)
     ap.add_argument("--seg-steps", type=int, default=1024)
-    ap.add_argument("--stepper", choices=STEPPERS, default="branchless",
-                    help="segment interpreter (DESIGN.md §9.5/§9.7)")
+    ap.add_argument("--stepper", choices=STEPPERS, default=None,
+                    help="segment interpreter (DESIGN.md §9.5/§9.7); "
+                         "default: the engine's choice, pallas on a TPU "
+                         "and branchless elsewhere")
     ap.add_argument("--packed", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="run all groups in one packed multi-program "

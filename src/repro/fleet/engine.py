@@ -352,7 +352,7 @@ def run_stream(code: np.ndarray, source: Source, *, n_items: int,
                seg_steps: int = 4096, out_addr: Optional[int] = None,
                keep_state: bool = False,
                mesh: Optional[Mesh] = None,
-               stepper: str = "branchless",
+               stepper: Optional[str] = "branchless",
                subset: Optional[frozenset] = None,
                prefetch: bool = True, refill: str = "device",
                adaptive: bool = False,
@@ -370,7 +370,8 @@ def run_stream(code: np.ndarray, source: Source, *, n_items: int,
     masked-select stepper, DESIGN.md §9.5), "pallas" (fused-segment
     kernel — the whole segment of a lane tile runs inside one kernel
     invocation with state resident, §9.7), or "switch" (the legacy
-    vmapped lax.switch interpreter). `subset` optionally pins the static
+    vmapped lax.switch interpreter); None, a `FleetPlan`'s default,
+    leaves the choice to `run_packed`. `subset` optionally pins the static
     opcode subset for the branchless/pallas steppers; by default it is
     derived from the program text (`iss.opcode_subset`), letting the
     compiler drop opcode classes the workload can never retire. With a
@@ -1262,9 +1263,24 @@ def _pool_lanes(chunk: int, stepper: str, n_dev: int, dmr: bool) -> int:
     return -(-chunk // round_to) * round_to
 
 
+def _choose_stepper(stepper: Optional[str],
+                    faults: Optional[flexifault.FaultSpec]) -> str:
+    """The segment stepper a run uses: `stepper` when one is named;
+    otherwise the fused Pallas kernel on a TPU for a fault-free run, and
+    the XLA branchless stepper elsewhere — on other backends the kernel
+    runs interpreted, and its fault transform does not compile for the
+    TPU. The two give bit-identical results, so the choice is speed
+    only."""
+    if stepper is not None:
+        return stepper
+    if faults is None and jax.default_backend() == "tpu":
+        return "pallas"
+    return "branchless"
+
+
 def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
                keep_state: bool = False, mesh: Optional[Mesh] = None,
-               stepper: str = "branchless",
+               stepper: Optional[str] = None,
                subset: Optional[frozenset] = None,
                prefetch: bool = True, refill: str = "device",
                adaptive: bool = False,
@@ -1297,6 +1313,11 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     whole-run wall clock proportionally to retired instructions (the
     sums over groups match the run, up to idle-lane slots, which belong
     to `stats`).
+
+    `stepper` names the segment interpreter (`STEPPERS`); None lets
+    `_choose_stepper` pick it from the backend and the fault schedule,
+    and `stats.stepper` and every `FleetResult.stepper` report the one
+    that ran.
 
     `refill` picks the stream loop (DESIGN.md §9.9): "device" (the
     default) is the *resident* runtime — retire/refill happens in one
@@ -1349,8 +1370,6 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         raise ValueError("seg_steps must be >= 1")
     if chunk < 1:
         raise ValueError("chunk must be >= 1")
-    if stepper not in STEPPERS:
-        raise ValueError(f"stepper must be one of {STEPPERS}")
     if refill not in REFILLS:
         raise ValueError(f"refill must be one of {REFILLS}")
     if redundancy not in REDUNDANCY:
@@ -1359,6 +1378,9 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
                          f"not executed), got {redundancy!r}")
     if faults is not None and faults.off:
         faults = None              # rate 0 IS the fault-free graph
+    stepper = _choose_stepper(stepper, faults)
+    if stepper not in STEPPERS:
+        raise ValueError(f"stepper must be one of {STEPPERS}")
     resilient = faults is not None or redundancy == "dmr"
     if resilient:
         if refill != "device":
@@ -2268,7 +2290,7 @@ def run_workload_stream(w: Workload, n_items: int, *, seed: int = 0,
                         max_steps: Optional[int] = None,
                         keep_state: bool = False,
                         mesh: Optional[Mesh] = None,
-                        stepper: str = "branchless",
+                        stepper: Optional[str] = "branchless",
                         prefetch: bool = True, refill: str = "device",
                         adaptive: bool = False,
                         cost: Optional[np.ndarray] = None,

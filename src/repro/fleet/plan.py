@@ -94,10 +94,13 @@ class FleetGroup:
 class FleetPlan:
     """A full heterogeneous fleet plus engine tuning knobs.
 
-    `stepper` selects the segment interpreter: "branchless" (lane-
+    `stepper` names the segment interpreter: "branchless" (lane-
     parallel stepper with per-workload opcode-subset specialization,
     DESIGN.md §9.5), "pallas" (fused-segment kernel, §9.7), or the
-    legacy "switch" interpreter for A/B runs; `prefetch` enables
+    legacy "switch" interpreter for A/B runs. None (default) is the
+    engine's choice: "pallas" on a TPU when the plan has no fault
+    schedule, "branchless" otherwise (`engine._choose_stepper`); the
+    report's results name the stepper that ran. `prefetch` enables
     double-buffered async host refill (§9.6); `packed` (the default)
     executes ALL groups in one packed multi-program stream — program
     bank + per-lane prog_id, freed lanes backfilled from any pending
@@ -150,7 +153,7 @@ class FleetPlan:
     seg_steps: int = 4096
     intensity: float = 0.367              # kg CO2e/kWh (US grid)
     clock_hz: float = 10_000.0
-    stepper: str = "branchless"
+    stepper: Optional[str] = None         # None: the engine's choice
     prefetch: bool = True
     packed: bool = True
     refill: str = "device"

@@ -16,6 +16,7 @@ import pytest
 from benchmarks.fleet import skew_fleet, skew_program
 from repro.flexibench.base import get
 from repro.flexibits import iss
+from repro.flexibits.faults import FaultSpec
 from repro.fleet import engine
 from repro.fleet.engine import PackedGroup, _apportion, run_packed
 from repro.fleet.plan import FleetGroup, FleetPlan, run_plan
@@ -322,6 +323,122 @@ def test_run_packed_rejects_bad_args():
         run_packed([g], seg_steps=0)
     with pytest.raises(ValueError):
         run_packed([g], stepper="vliw")
+
+
+# ---- the engine's choice of stepper ------------------------------------
+
+_MILD = FaultSpec(rate=0.0008, seed=5, targets=("regs", "mem", "pc"))
+
+
+def _skew_group(n=12):
+    prog = skew_program()
+    return PackedGroup(code=prog.code,
+                       source=engine.array_source(skew_fleet(prog, n,
+                                                             long_iters=300)),
+                       n_items=n, max_steps=100_000, mem_words=32,
+                       out_addr=1)
+
+
+def _spy_runners(monkeypatch, backend):
+    """`jax.default_backend()` reads `backend`, and both runner factories
+    record what the engine asked of them — the segment stepper and the
+    Pallas refill swap — and build the branchless and jnp runners, so no
+    Pallas kernel runs (none could be compiled here for a TPU)."""
+    asked = {"stepper": set(), "pallas_swap": set()}
+    seg, refill = (engine._packed_segment_runner,
+                   engine._resident_refill_runner)
+
+    def seg_spy(stepper, *args):
+        asked["stepper"].add(stepper)
+        return seg("branchless", *args)
+
+    def refill_spy(mesh, mem_words, n_groups, keep_state, use_pallas,
+                   *args):
+        asked["pallas_swap"].add(use_pallas)
+        return refill(mesh, mem_words, n_groups, keep_state, False, *args)
+
+    monkeypatch.setattr(engine.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(engine, "_packed_segment_runner", seg_spy)
+    monkeypatch.setattr(engine, "_resident_refill_runner", refill_spy)
+    return asked
+
+
+def _run_reports(groups, **kw):
+    """Run and return the stepper every report names, checking they
+    agree: `PackedStats.stepper` and each `FleetResult.stepper`."""
+    res, stats = run_packed(groups, chunk=8, seg_steps=64, **kw)
+    assert {r.stepper for r in res} == {stats.stepper}
+    return stats.stepper
+
+
+@pytest.mark.parametrize("with_mesh", [False, True], ids=["single", "mesh"])
+def test_fault_free_default_on_tpu_is_pallas(monkeypatch, with_mesh):
+    """On a TPU a fault-free run takes the fused Pallas stepper, with and
+    without a mesh; the Pallas refill swap goes with it on one device
+    only. A rate-0 schedule is no schedule."""
+    mesh = jax.make_mesh((1,), ("fleet",)) if with_mesh else None
+    asked = _spy_runners(monkeypatch, "tpu")
+    assert _run_reports([_skew_group()], mesh=mesh) == "pallas"
+    assert _run_reports([_skew_group()], mesh=mesh,
+                        faults=FaultSpec(rate=0.0, seed=3)) == "pallas"
+    assert asked == {"stepper": {"pallas"}, "pallas_swap": {not with_mesh}}
+
+
+@pytest.mark.parametrize("redundancy", ["none", "dmr"])
+def test_faulted_default_on_tpu_is_branchless(monkeypatch, redundancy):
+    """A fault schedule, with or without DMR, keeps a TPU run on the
+    branchless stepper: the Pallas fault transform does not compile
+    there."""
+    asked = _spy_runners(monkeypatch, "tpu")
+    assert _run_reports([_skew_group()], faults=_MILD,
+                        redundancy=redundancy, max_retries=6) \
+        == "branchless"
+    assert asked == {"stepper": {"branchless"}, "pallas_swap": {False}}
+
+
+@pytest.mark.parametrize("faulted", [False, True], ids=["clean", "faults"])
+@pytest.mark.parametrize("redundancy", ["none", "dmr"])
+def test_default_off_tpu_is_branchless(faulted, redundancy):
+    """On the CPU every default is the branchless stepper: the kernel
+    would run interpreted."""
+    assert _run_reports([_skew_group()],
+                        faults=_MILD if faulted else None,
+                        redundancy=redundancy, max_retries=6) \
+        == "branchless"
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("faulted", [False, True], ids=["clean", "faults"])
+@pytest.mark.parametrize("stepper", engine.STEPPERS)
+def test_explicit_stepper_wins(monkeypatch, backend, faulted, stepper):
+    """A named stepper runs whatever the backend and the schedule, but
+    for the one combination the engine refuses: faults on the Pallas
+    stepper on a TPU."""
+    asked = _spy_runners(monkeypatch, backend)
+    kw = dict(stepper=stepper, faults=_MILD if faulted else None)
+    if faulted and stepper == "pallas" and backend == "tpu":
+        with pytest.raises(ValueError, match="stepper='branchless'"):
+            _run_reports([_skew_group()], **kw)
+        return
+    assert _run_reports([_skew_group()], **kw) == stepper
+    assert asked["stepper"] == {stepper}
+
+
+@pytest.mark.parametrize("packed", [True, False],
+                         ids=["packed", "sequential"])
+def test_plan_reports_the_stepper_that_ran(monkeypatch, packed):
+    """`FleetPlan.stepper` None hands the choice to the engine on both
+    runtimes, and the report's results name the stepper that ran."""
+    _spy_runners(monkeypatch, "tpu")
+    groups = (FleetGroup(workload="WQ", core="QERV", n_items=8, seed=1),)
+    for faults, want in ((None, "pallas"), (_MILD, "branchless")):
+        plan = FleetPlan(groups=groups, chunk=8, seg_steps=128,
+                         packed=packed, faults=faults)
+        rep = run_plan(plan)
+        assert plan.stepper is None
+        assert {g.result.stepper for g in rep.groups} == {want}
+        if packed:
+            assert rep.packed.stepper == want
 
 
 @pytest.mark.slow

@@ -1,10 +1,13 @@
-"""Ahead-of-time compiles of the main path's Pallas kernels for one TPU
-v5e chip, described rather than attached: the fused ISS segment and the
+"""Ahead-of-time compiles of the main path's Pallas kernels for a TPU
+v5e, described rather than attached: the fused ISS segment and the
 resident refill swap at the mixed Table-2 fleet's real widths, the
-segment at an engine-padded lane count, and the carbon-sweep tile at
-the planner's tile size. Each compiled program must hold the Mosaic
-kernel (`tpu_custom_call`). Nothing runs; the TPU compiler refuses what
-interpret mode accepts (unsupported layouts, dtypes, fast-memory use).
+segment at engine-padded lane counts, at the food-spoilage patch's
+widths and at a four-chip shard's 64 lanes, the engine's segment runner
+sharded over the four chips of a v5e:2x2 host, and the carbon-sweep
+tile at the planner's tile size. Each compiled program must hold the
+Mosaic kernel (`tpu_custom_call`). Nothing runs; the TPU compiler
+refuses what interpret mode accepts (unsupported layouts, dtypes,
+fast-memory use).
 
 The topology is described inside a fixture, only once a test of this
 file runs: describing it loads the TPU library, which one process at a
@@ -16,10 +19,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.fleet import engine
-from repro.flexibench.base import all_workloads
+from repro.flexibench.base import all_workloads, get
 from repro.flexibits import iss
 from repro.flexibits.cycles import N_COST
 from repro.kernels import carbon_sweep as csk
@@ -27,14 +31,18 @@ from repro.kernels.iss_stepper import iss_refill, iss_segment_banked
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # no TPU compiler or library lock held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -57,6 +65,13 @@ def fleet_widths():
     ws = all_workloads()
     return (len(ws), max(len(w.program.code) for w in ws),
             max(w.total_mem_words for w in ws))
+
+
+@pytest.fixture(scope="module")
+def fs_widths():
+    """The food-spoilage patch's bank: one row of FS code, FS memory."""
+    w = get("FS")
+    return 1, len(w.program.code), w.total_mem_words
 
 
 def _abstract(sharding, shape, dtype=jnp.int32):
@@ -123,6 +138,51 @@ def test_segment_compiles_at_engine_padded_lanes(one_chip,
     assert n_lanes % 128 == 0 or n_lanes <= 128
     _assert_kernel(_segment, *_segment_args(one_chip, fleet_widths,
                                             n_lanes))
+
+
+def test_segment_compiles_at_fs_widths(one_chip, no_persistent_cache,
+                                      fs_widths):
+    """The engine's 256-lane pool over a 1-row, 52-word bank and 128-word
+    memory, timing on."""
+    _assert_kernel(_segment, *_segment_args(one_chip, fs_widths, 256))
+
+
+def test_segment_compiles_at_four_chip_shard(one_chip, no_persistent_cache,
+                                             fleet_widths):
+    """A four-chip shard of the 256-lane pool: 64 lanes, one full-width
+    block."""
+    n_lanes = engine._pool_lanes(256, "pallas", 4, False) // 4
+    assert n_lanes == 64
+    _assert_kernel(_segment, *_segment_args(one_chip, fleet_widths,
+                                            n_lanes))
+
+
+def test_sharded_segment_runner_compiles_on_four_chips(
+        topo, no_persistent_cache, fleet_widths, monkeypatch):
+    """The engine's Pallas segment runner under `shard_map` over a
+    ("fleet",) mesh of the v5e:2x2 host's four chips, at the Table-2
+    widths: each chip runs the kernel on its own 64 lanes, and the
+    program holds no collective. The backend reads "tpu" while it is
+    traced, so the kernel takes its compiled path, as on the chip."""
+    if len(topo.devices) != 4:
+        pytest.skip(f"v5e:2x2 described {len(topo.devices)} devices")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices), ("fleet",))
+    n_progs, width, mem_words = fleet_widths
+    n_lanes = engine._pool_lanes(256, "pallas", 4, False)
+    seg = engine._packed_segment_runner("pallas", n_lanes, 4096, mem_words,
+                                        n_progs, width, mesh, None, True)
+    bank, code_len, mem_len, cost, state = _segment_args(
+        NamedSharding(mesh, P()), fleet_widths, n_lanes)
+    state = jax.tree.map(
+        lambda a: _abstract(NamedSharding(mesh, P("fleet")), a.shape,
+                            a.dtype), state)
+    text = seg.lower(bank, code_len, mem_len, cost, state).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text
+    for op in ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter"):
+        assert op not in text, op
 
 
 @pytest.mark.parametrize("draws", [64, 128])
