@@ -1,30 +1,15 @@
 """Streaming fleet engine benchmarks (DESIGN.md §9).
 
-Eight studies on a skewed halt-time distribution (the paper's regime:
+Seven studies on a skewed halt-time distribution (the paper's regime:
 most items run short data-dependent paths, a tail runs long ones):
 
 - streaming vs monolithic: total simulated lane-steps; the monolithic
   vmap(while_loop) occupies every lane until the slowest item halts,
   the streaming engine compacts halted items out between segments, so
   it should retire >=2X fewer — bit-exact final memories.
-- stepper A/B (§9.5/§9.7): wall-clock per retired instruction, three
-  ways — lane-parallel branchless stepper, fused-segment pallas kernel
-  (interpret fallback), legacy vmapped lax.switch — on a >=64-lane
-  chunk, bit-exact across all three.
 - fusion proof (§9.7): structural HLO op counts; the fused-segment
   module's top level must hold >=10x fewer ops than the branchless
   step body x seg_steps it replaces.
-- packed vs sequential (§9.8): wall-clock of the packed multi-program
-  runtime (whole heterogeneous plan in one stream, freed lanes
-  backfilled from any pending group) vs draining the same groups
-  sequentially, on 16x-skewed group sizes — bit-exact per group, and
-  packed must not be slower.
-- resident vs host refill (§9.9): the device-resident runtime
-  (on-device retire/refill, one async stats read per segment, adaptive
-  supersteps) against the PR-4 host-refill loop on the same 16x-skewed
-  plan — bit-exact, strictly fewer blocking host syncs, and wall-clock
-  no worse (those two are the gates; the committed run records a
-  >=1.2x win).
 - planner sweep (§9.13): the device-resident Monte Carlo carbon-planner
   sweep — scenarios/second of the fused jitted evaluate-and-reduce over
   the (distribution x frequency x intensity x volume x workload x
@@ -131,71 +116,6 @@ def fleet_streaming_vs_monolithic(n_items: int = 1024, chunk: int = 128,
     return rows, derived
 
 
-AB_STEPPERS = ("switch", "branchless", "pallas")
-
-
-def fleet_stepper_ab(n_items: int = 512, chunk: int = 128,
-                     seg_steps: int = 256, max_steps: int = 100_000):
-    """Three-way stepper A/B: switch vs branchless vs fused-pallas.
-
-    Same fleet, same chunk (>=64 lanes), same segmentation — only the
-    segment interpreter changes. Metric: wall-clock ns per retired
-    instruction (lower is better), best of `reps` timed runs so a noisy
-    shared CI runner can't flip the gate; outputs must agree bit-exactly
-    across all three. The wall-clock gate applies to branchless vs
-    switch only: the pallas stepper runs through the interpret=True CPU
-    fallback here (DESIGN.md §9.7), which measures the fused kernel's
-    semantics and module structure, not its accelerator wall-clock.
-    """
-    assert chunk >= 64, "A/B must run on a >=64-lane chunk"
-    reps = 3
-    prog = skew_program()
-    mems = skew_fleet(prog, n_items)
-    kw = dict(n_items=n_items, mem_words=32, max_steps=max_steps,
-              chunk=chunk, seg_steps=seg_steps, out_addr=1)
-    stats = {}
-    ref_out = None
-    for stepper in AB_STEPPERS:
-        run_stream(prog.code, array_source(mems), stepper=stepper,
-                   **kw)                          # compile warm-up
-        res = None
-        for _ in range(reps):
-            r = run_stream(prog.code, array_source(mems),
-                           stepper=stepper, **kw)
-            if res is None or r.wall_s < res.wall_s:
-                res = r
-        if ref_out is None:
-            ref_out = res.out
-        else:
-            np.testing.assert_array_equal(res.out, ref_out)
-        stats[stepper] = {
-            "wall_s": res.wall_s,
-            "ns_per_retired_instr":
-                res.wall_s * 1e9 / max(res.busy_steps, 1),
-            "items_per_s": res.items_per_s,
-            "n_segments": res.n_segments,
-        }
-    speedup = (stats["switch"]["ns_per_retired_instr"]
-               / stats["branchless"]["ns_per_retired_instr"])
-    rows = [
-        ("fleet/ab_ns_per_instr",) + tuple(
-            round(stats[s]["ns_per_retired_instr"], 1)
-            for s in AB_STEPPERS),
-        ("fleet/ab_items_per_s",) + tuple(
-            round(stats[s]["items_per_s"], 1) for s in AB_STEPPERS),
-    ]
-    derived = {
-        "stepper_speedup": speedup,
-        "pallas_speedup": (stats["switch"]["ns_per_retired_instr"]
-                           / stats["pallas"]["ns_per_retired_instr"]),
-        **stats,
-        "chunk": chunk,
-        "bit_exact": True,
-        "target": "branchless < switch ns/retired-instr on >=64 lanes",
-    }
-    return rows, derived
-
-
 def fleet_fusion_proof(chunk: int = 128, seg_steps: int = 512,
                        max_steps: int = 100_000):
     """HLO op-count proof of the fused-segment kernel (DESIGN.md §9.7).
@@ -214,16 +134,14 @@ def fleet_fusion_proof(chunk: int = 128, seg_steps: int = 512,
     import jax
     import jax.numpy as jnp
 
-    from repro.fleet import engine
     from repro.kernels.iss_stepper import iss_segment
     from repro.launch.hlo_analysis import op_counts
 
     prog = skew_program()
     subset = iss.opcode_subset(prog.code)
     code = jnp.asarray(prog.code.view(np.int32))
-    state = engine._fresh_chunk(
-        np.tile(prog.initial_memory(32), (chunk, 1)),
-        np.ones(chunk, bool))
+    state = jax.vmap(iss.init_state)(
+        jnp.asarray(np.tile(prog.initial_memory(32), (chunk, 1))))
 
     def lower(fn):
         return op_counts(jax.jit(fn).lower(code, state)
@@ -260,171 +178,6 @@ def fleet_fusion_proof(chunk: int = 128, seg_steps: int = 512,
         "top_level_ratio": ratio,
         "target": ">=10x fewer top-level ops than branchless step-body "
                   "x seg_steps",
-    }
-    return rows, derived
-
-
-def fleet_packed_vs_sequential(chunk: int = 128, seg_steps: int = 256,
-                               max_steps: int = 100_000):
-    """Packed multi-program runtime vs sequential group drain (§9.8).
-
-    A skewed plan — group sizes spanning 16x, each group with its own
-    within-group halt-time skew — run twice through the engine: once
-    group-by-group (`run_stream` per group, the pre-§9.8 baseline) and
-    once as ONE packed stream (`run_packed`). Sequentially, every group
-    pays its own tail (the last segments where a few long items hold
-    the whole pool) and its own host<->device cadence; packed, freed
-    lanes are immediately backfilled with items from any pending group.
-    Gate: packed wall-clock <= sequential on this plan, with per-group
-    tallies bit-exact between the two modes. Timed best-of-`reps` after
-    a warm-up run of each mode, so the comparison is steady-state
-    execution, not compile time (which also favors packed: one compiled
-    runner for the bank vs one per group).
-    """
-    from repro.fleet import engine
-
-    prog = skew_program()
-    reps = 3
-    # 16x size skew; per-group halt-time skew via long_frac/long_iters
-    sizes = (8 * chunk, chunk, chunk // 2, chunk // 2)
-    gspecs = []
-    for gi, n in enumerate(sizes):
-        mems = skew_fleet(prog, n, short_iters=48,
-                          long_iters=2048 + 512 * gi,
-                          long_frac=0.08 + 0.04 * gi, seed=17 + gi)
-        gspecs.append(engine.PackedGroup(
-            code=prog.code, source=array_source(mems), n_items=n,
-            max_steps=max_steps, mem_words=32, out_addr=1))
-
-    kw = dict(chunk=chunk, seg_steps=seg_steps)
-
-    def run_sequential():
-        t0 = time.perf_counter()
-        outs = [run_stream(g.code, g.source, n_items=g.n_items,
-                           mem_words=g.mem_words, max_steps=g.max_steps,
-                           out_addr=g.out_addr, **kw) for g in gspecs]
-        return outs, time.perf_counter() - t0
-
-    def run_packed_mode():
-        t0 = time.perf_counter()
-        outs, stats = engine.run_packed(gspecs, **kw)
-        return outs, time.perf_counter() - t0, stats
-
-    run_sequential()                         # warm-up (compile)
-    run_packed_mode()
-    seq_res, seq_wall = None, float("inf")
-    pk_res, pk_wall, pk_stats = None, float("inf"), None
-    for _ in range(reps):
-        r, w = run_sequential()
-        if w < seq_wall:
-            seq_res, seq_wall = r, w
-        r, w, st = run_packed_mode()
-        if w < pk_wall:
-            pk_res, pk_wall, pk_stats = r, w, st
-
-    for a, b in zip(seq_res, pk_res):        # bit-exact demux per group
-        np.testing.assert_array_equal(a.n_instr, b.n_instr)
-        np.testing.assert_array_equal(a.out, b.out)
-        np.testing.assert_array_equal(a.mix, b.mix)
-
-    seq_segments = sum(r.n_segments for r in seq_res)
-    seq_lane_steps = sum(r.lane_steps for r in seq_res)
-    speedup = seq_wall / max(pk_wall, 1e-12)
-    rows = [
-        ("fleet/packed_wall_s", round(pk_wall, 3), round(seq_wall, 3)),
-        ("fleet/packed_segments", pk_stats.n_segments, seq_segments),
-        ("fleet/packed_lane_steps", pk_stats.lane_steps, seq_lane_steps),
-    ]
-    derived = {
-        "group_sizes": list(sizes),
-        "packed_wall_s": pk_wall,
-        "sequential_wall_s": seq_wall,
-        "packed_speedup": speedup,
-        "packed_segments": pk_stats.n_segments,
-        "sequential_segments": seq_segments,
-        "packed_lane_steps": pk_stats.lane_steps,
-        "sequential_lane_steps": seq_lane_steps,
-        "bit_exact": True,
-        "target": "packed wall-clock <= sequential on skewed group sizes",
-    }
-    return rows, derived
-
-
-def fleet_resident_vs_host(chunk: int = 256, seg_steps: int = 512,
-                           max_steps: int = 100_000):
-    """Resident runtime vs host-refill baseline (DESIGN.md §9.9).
-
-    The same 16x-skewed group-size plan as the §9.8 study, with a
-    churnier halt distribution (short items halt in ~50 steps against a
-    512-step segment bound), run through `run_packed` twice: once with
-    the PR-4 host-refill loop at fixed supersteps — a blocking
-    done-count read per segment plus O(done)-row harvest pulls, host
-    demux/rebuild, and a device_put on every finishing segment — and
-    once device-resident with adaptive supersteps: retire/refill as one
-    donated on-device op against an asynchronously staged batch, ONE
-    small stats read per segment overlapped with the next segment's
-    execution, and the superstep controller shrinking segments while
-    churn is high. Gates: bit-exact per-group results, strictly fewer
-    blocking host syncs, resident wall-clock <= host-refill wall-clock
-    (best of `reps` each, after warm-up).
-    """
-    from repro.fleet import engine
-
-    prog = skew_program()
-    reps = 3
-    sizes = (8 * chunk, chunk, chunk // 2, chunk // 2)
-    gspecs = []
-    for gi, n in enumerate(sizes):
-        mems = skew_fleet(prog, n, short_iters=24,
-                          long_iters=4096 + 512 * gi,
-                          long_frac=0.06 + 0.04 * gi, seed=17 + gi)
-        gspecs.append(engine.PackedGroup(
-            code=prog.code, source=array_source(mems), n_items=n,
-            max_steps=max_steps, mem_words=32, out_addr=1))
-
-    def run(refill, adaptive):
-        best = None
-        for i in range(reps + 1):             # first rep is the warm-up
-            t0 = time.perf_counter()
-            outs, stats = engine.run_packed(
-                gspecs, chunk=chunk, seg_steps=seg_steps, refill=refill,
-                adaptive=adaptive)
-            wall = time.perf_counter() - t0
-            if i > 0 and (best is None or wall < best[0]):
-                best = (wall, outs, stats)
-        return best
-
-    h_wall, h_res, h_stats = run("host", False)
-    d_wall, d_res, d_stats = run("device", True)
-    for a, b in zip(h_res, d_res):           # bit-exact demux per group
-        np.testing.assert_array_equal(a.n_instr, b.n_instr)
-        np.testing.assert_array_equal(a.out, b.out)
-        np.testing.assert_array_equal(a.mix, b.mix)
-
-    speedup = h_wall / max(d_wall, 1e-12)
-    rows = [
-        ("fleet/resident_wall_s", round(d_wall, 3), round(h_wall, 3)),
-        ("fleet/resident_syncs", d_stats.host_syncs, h_stats.host_syncs),
-        ("fleet/resident_lane_steps", d_stats.lane_steps,
-         h_stats.lane_steps),
-    ]
-    derived = {
-        "group_sizes": list(sizes),
-        "resident_wall_s": d_wall,
-        "host_refill_wall_s": h_wall,
-        "resident_speedup": speedup,
-        "resident_syncs": d_stats.host_syncs,
-        "host_refill_syncs": h_stats.host_syncs,
-        "resident_segments": d_stats.n_segments,
-        "host_refill_segments": h_stats.n_segments,
-        "resident_lane_steps": d_stats.lane_steps,
-        "host_refill_lane_steps": h_stats.lane_steps,
-        "resident_sync_wait_s": d_stats.sync_wait_s,
-        "host_refill_sync_wait_s": h_stats.sync_wait_s,
-        "adaptive_rungs": sorted(set(d_stats.seg_schedule)),
-        "bit_exact": True,
-        "target": "resident wall <= host-refill wall, strictly fewer "
-                  "blocking host syncs",
     }
     return rows, derived
 
@@ -957,17 +710,6 @@ def main():
           f"(lane busy {derived['streaming_busy_pct']:.1f}%, "
           f"{derived['n_segments']} segments, bit-exact memories)")
 
-    ab_rows, ab = fleet_stepper_ab(n_items=args.items,
-                                   chunk=max(args.chunk, 64),
-                                   seg_steps=args.seg_steps)
-    bench["stepper_ab"] = ab
-    print(f"\n{'metric':<22} " + " ".join(f"{s:>14}" for s in AB_STEPPERS))
-    for name, *vals in ab_rows:
-        print(f"{name:<22} " + " ".join(f"{v:>14}" for v in vals))
-    print(f"branchless speedup: {ab['stepper_speedup']:.2f}x, "
-          f"pallas(interpret) {ab['pallas_speedup']:.2f}x "
-          f"per retired instruction (bit-exact three-way)")
-
     fp_rows, fp = fleet_fusion_proof(chunk=max(args.chunk, 64),
                                      seg_steps=args.seg_steps)
     bench["fusion_proof"] = fp
@@ -977,26 +719,6 @@ def main():
     print(f"fused-segment module: {fp['pallas']['entry_ops']} top-level "
           f"ops vs {fp['branchless']['dispatched_ops_per_segment']} "
           f"step-dispatched ops ({fp['top_level_ratio']:.0f}x)")
-
-    pk_rows, pk = fleet_packed_vs_sequential(chunk=max(args.chunk, 64),
-                                             seg_steps=args.seg_steps)
-    bench["packed_vs_sequential"] = pk
-    print(f"\n{'metric':<24} {'packed':>14} {'sequential':>14}")
-    for name, p, s in pk_rows:
-        print(f"{name:<24} {p:>14} {s:>14}")
-    print(f"packed runtime: {pk['packed_speedup']:.2f}x wall-clock vs "
-          f"sequential group drain on group sizes {pk['group_sizes']} "
-          f"(bit-exact per-group demux)")
-
-    rh_rows, rh = fleet_resident_vs_host(chunk=max(args.chunk, 256))
-    bench["resident_vs_host_refill"] = rh
-    print(f"\n{'metric':<26} {'resident':>14} {'host-refill':>14}")
-    for name, d, h in rh_rows:
-        print(f"{name:<26} {d:>14} {h:>14}")
-    print(f"resident runtime: {rh['resident_speedup']:.2f}x wall-clock, "
-          f"{rh['resident_syncs']} vs {rh['host_refill_syncs']} blocking "
-          f"host syncs (adaptive rungs {rh['adaptive_rungs']}, "
-          f"bit-exact)")
 
     to_rows, to = fleet_timing_overhead(chunk=max(args.chunk, 64),
                                         seg_steps=args.seg_steps)
@@ -1065,24 +787,9 @@ def main():
     if derived["cycles_saved_ratio"] < 2.0 and args.items >= 4 * args.chunk:
         failures.append(f"streaming target NOT met: "
                         f"{derived['cycles_saved_ratio']:.2f}x < 2X")
-    if ab["stepper_speedup"] <= 1.0:
-        failures.append(f"stepper A/B target NOT met: "
-                        f"{ab['stepper_speedup']:.2f}x <= 1X")
     if fp["top_level_ratio"] < 10.0:
         failures.append(f"fusion proof target NOT met: "
                         f"{fp['top_level_ratio']:.1f}x < 10x")
-    if pk["packed_wall_s"] > pk["sequential_wall_s"]:
-        failures.append(f"packed runtime target NOT met: "
-                        f"{pk['packed_wall_s']:.3f}s packed > "
-                        f"{pk['sequential_wall_s']:.3f}s sequential")
-    if rh["resident_wall_s"] > rh["host_refill_wall_s"]:
-        failures.append(f"resident runtime target NOT met: "
-                        f"{rh['resident_wall_s']:.3f}s resident > "
-                        f"{rh['host_refill_wall_s']:.3f}s host-refill")
-    if rh["resident_syncs"] >= rh["host_refill_syncs"]:
-        failures.append(f"resident sync target NOT met: "
-                        f"{rh['resident_syncs']} syncs >= "
-                        f"{rh['host_refill_syncs']} host-refill syncs")
     if to["overhead_ratio"] > 1.5:
         failures.append(f"timing overhead target NOT met: "
                         f"{to['overhead_ratio']:.3f}x > 1.5x")

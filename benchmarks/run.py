@@ -27,7 +27,6 @@ BENCHES = [
     ("planner_grid", "benchmarks.serving"),
     ("roofline_table", "benchmarks.rooflines"),
     ("fleet_streaming_vs_monolithic", "benchmarks.fleet"),
-    ("fleet_stepper_ab", "benchmarks.fleet"),
 ]
 
 

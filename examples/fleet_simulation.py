@@ -15,7 +15,7 @@ import argparse
 import numpy as np
 
 from repro import compile_cache
-from repro.fleet import REFILLS, STEPPERS, FleetGroup, FleetPlan, run_plan
+from repro.fleet import STEPPERS, FleetGroup, FleetPlan, run_plan
 from repro.launch.mesh import make_host_mesh
 
 
@@ -30,16 +30,6 @@ def main():
                     help="segment interpreter (DESIGN.md §9.5/§9.7); "
                          "default: the engine's choice, pallas on a TPU "
                          "and branchless elsewhere")
-    ap.add_argument("--packed", action=argparse.BooleanOptionalAction,
-                    default=True,
-                    help="run all groups in one packed multi-program "
-                         "stream (DESIGN.md §9.8); --no-packed drains "
-                         "groups sequentially (the A/B baseline)")
-    ap.add_argument("--refill", choices=REFILLS, default="device",
-                    help="stream loop (DESIGN.md §9.9): 'device' = "
-                         "resident runtime (on-device retire/refill, "
-                         "async sync), 'host' = PR-4 host-refill A/B "
-                         "baseline")
     ap.add_argument("--adaptive", action=argparse.BooleanOptionalAction,
                     default=False,
                     help="adaptive supersteps: pick each segment's step "
@@ -55,20 +45,18 @@ def main():
         FleetGroup(workload="WQ", core="QERV", n_items=args.items, seed=1),
         FleetGroup(workload="SI", core="HERV", n_items=args.items, seed=2),
     ), chunk=args.chunk, seg_steps=args.seg_steps, stepper=args.stepper,
-        packed=args.packed, refill=args.refill, adaptive=args.adaptive)
+        adaptive=args.adaptive)
 
     mesh = make_host_mesh()
     report = run_plan(plan, mesh=mesh)
 
-    mode = "packed" if args.packed else "sequential"
     print(f"[fleet] {report.n_items} items on mesh {dict(mesh.shape)} "
-          f"({mode} runtime, {args.refill} refill"
+          f"(packed resident runtime"
           f"{', adaptive supersteps' if args.adaptive else ''})")
-    if report.packed is not None:
-        p = report.packed
-        print(f"[fleet] sync: {p.host_syncs} blocking host syncs over "
-              f"{p.n_segments} segments ({p.sync_wait_s * 1e3:.1f} ms "
-              f"waited), refill host work {p.refill_wall_s * 1e3:.1f} ms")
+    p = report.packed
+    print(f"[fleet] sync: {p.host_syncs} blocking host syncs over "
+          f"{p.n_segments} segments ({p.sync_wait_s * 1e3:.1f} ms "
+          f"waited), refill host work {p.refill_wall_s * 1e3:.1f} ms")
     mc = report.groups[0].result
     print(f"[fleet] MC malodor score histogram: "
           f"{np.bincount(mc.out, minlength=5)}")

@@ -6,26 +6,24 @@ chunked, segment-early-exit, heterogeneity-aware engine:
 - `engine.run_stream`   — chunked streaming executor (host memory O(chunk))
 - `engine.run_packed`   — packed multi-program runtime: every group of a
                           heterogeneous plan in ONE stream (program bank,
-                          per-lane prog_id, admission scheduler, §9.8);
-                          device-resident by default (`refill="device"`,
-                          on-device retire/refill + async sync, optional
-                          adaptive supersteps, §9.9)
+                          per-lane prog_id, admission scheduler, §9.8),
+                          device-resident (on-device retire/refill +
+                          async sync, optional adaptive supersteps, §9.9)
 - `plan.FleetPlan`      — heterogeneous (workload, core) sub-fleets;
-                          `run_plan` routes through the packed runtime by
-                          default (`packed=False` = sequential baseline)
+                          `run_plan` runs them through the packed runtime
 - `report.FleetReport`  — per-group cycle/energy tallies priced through
-                          core/carbon.py and core/planner.py, with packed
-                          whole-run stats when the plan ran packed
+                          core/carbon.py and core/planner.py, with the
+                          packed stream's whole-run stats
 """
-from repro.fleet.engine import (REFILLS, STEPPERS, FleetResult,
-                                PackedGroup, PackedStats, array_source,
-                                run_packed, run_stream,
-                                run_workload_stream, workload_source)
+from repro.fleet.engine import (STEPPERS, FleetResult, PackedGroup,
+                                PackedStats, array_source, run_packed,
+                                run_stream, run_workload_stream,
+                                workload_source)
 from repro.fleet.plan import FleetGroup, FleetPlan, run_plan
 from repro.fleet.report import FleetReport, GroupReport
 
 __all__ = [
-    "REFILLS", "STEPPERS", "FleetResult", "PackedGroup", "PackedStats",
+    "STEPPERS", "FleetResult", "PackedGroup", "PackedStats",
     "array_source", "run_packed", "run_stream", "run_workload_stream",
     "workload_source",
     "FleetGroup", "FleetPlan", "run_plan", "FleetReport", "GroupReport",

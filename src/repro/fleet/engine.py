@@ -25,14 +25,12 @@ This engine fixes both (DESIGN.md §9):
   group, so one group's halt-time tail hides behind the others' backlog
   and the whole plan runs as one stream.
 
-- **Resident runtime** (`refill="device"`, the default; DESIGN.md §9.9).
-  Retire/refill runs as one donated on-device op against an
-  asynchronously staged batch, the per-segment host sync collapses to
-  one small stats read overlapped with the next segment's execution,
-  and an optional superstep controller (`adaptive=True`) adapts each
-  segment's step bound to the observed halt cadence. The PR-4
-  host-refill loop survives as `refill="host"` for A/B runs — results
-  are bit-exact either way.
+- **Resident runtime** (DESIGN.md §9.9). Retire/refill runs as one
+  donated on-device op against an asynchronously staged batch, the
+  per-segment host sync collapses to one small stats read overlapped
+  with the next segment's execution, and an optional superstep
+  controller (`adaptive=True`) adapts each segment's step bound to the
+  observed halt cadence.
 
 - **Shard-local multi-device streaming** (DESIGN.md §9.12). Under a
   mesh, every device shard owns its lanes, its slice of the staged
@@ -70,14 +68,14 @@ from repro.flexibits import iss
 from repro.flexibits.cycles import N_COST
 from repro.kernels import iss_stepper
 
-STEPPERS = ("branchless", "pallas", "switch")
-REFILLS = ("device", "host")   # resident on-device refill (§9.9) vs A/B
+STEPPERS = ("branchless", "pallas")
 REDUNDANCY = ("none", "dmr")   # executed redundancy modes (§9.14; the
                                # carbon planner additionally PRICES tmr)
 
-# resident-runtime safety bounds (see run_packed): past either, the
-# engine falls back to the host-refill loop rather than risking int32
-# mix-counter overflow or an O(fleet) keep_state device allocation
+# resident-runtime bounds: the on-device per-group mix rows are int32
+# (a plan that could pass this in one row flushes them to the host's
+# int64 totals as it runs, see `_stream_resident`), and keep_state's
+# O(fleet) device rows are capped (`run_packed` refuses a plan past it)
 _RESIDENT_MIX_LIMIT = 2**31 - 1
 _RESIDENT_KEEP_STATE_WORDS = 1 << 27   # ~512 MB of int32 device rows
 
@@ -317,44 +315,14 @@ class FleetResult:
         return self.n_items / self.wall_s if self.wall_s > 0 else float("inf")
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _refill(state: iss.ISSState, replace, new_mems) -> iss.ISSState:
-    """Reset `replace` lanes to a fresh item (mem from new_mems)."""
-    rep1 = replace[:, None]
-    return iss.ISSState(
-        regs=jnp.where(rep1, 0, state.regs),
-        pc=jnp.where(replace, 0, state.pc),
-        mem=jnp.where(rep1, new_mems, state.mem),
-        halted=jnp.where(replace, False, state.halted),
-        n_instr=jnp.where(replace, 0, state.n_instr),
-        n_two_stage=jnp.where(replace, 0, state.n_two_stage),
-        mix=jnp.where(rep1, 0, state.mix),
-        n_cycles=jnp.where(replace, 0, state.n_cycles),
-    )
-
-
-def _fresh_chunk(mems: np.ndarray, active: np.ndarray) -> iss.ISSState:
-    n, _ = mems.shape
-    return iss.ISSState(
-        regs=jnp.zeros((n, 16), iss.I32),
-        pc=jnp.zeros((n,), iss.I32),
-        mem=jnp.asarray(mems, iss.I32),
-        halted=jnp.asarray(~active),   # padding lanes never step
-        n_instr=jnp.zeros((n,), iss.I32),
-        n_two_stage=jnp.zeros((n,), iss.I32),
-        mix=jnp.zeros((n, len(iss.MIX_CLASSES)), iss.I32),
-        n_cycles=jnp.zeros((n,), iss.I32),
-    )
-
-
 def run_stream(code: np.ndarray, source: Source, *, n_items: int,
                mem_words: int, max_steps: int, chunk: int = 256,
                seg_steps: int = 4096, out_addr: Optional[int] = None,
                keep_state: bool = False,
                mesh: Optional[Mesh] = None,
-               stepper: Optional[str] = "branchless",
+               stepper: Optional[str] = None,
                subset: Optional[frozenset] = None,
-               prefetch: bool = True, refill: str = "device",
+               prefetch: bool = True,
                adaptive: bool = False,
                cost: Optional[np.ndarray] = None,
                faults: Optional[flexifault.FaultSpec] = None,
@@ -367,11 +335,10 @@ def run_stream(code: np.ndarray, source: Source, *, n_items: int,
     host memory, so only use it for parity checks or small fleets.
 
     `stepper` picks the segment interpreter: "branchless" (lane-parallel
-    masked-select stepper, DESIGN.md §9.5), "pallas" (fused-segment
+    masked-select stepper, DESIGN.md §9.5) or "pallas" (fused-segment
     kernel — the whole segment of a lane tile runs inside one kernel
-    invocation with state resident, §9.7), or "switch" (the legacy
-    vmapped lax.switch interpreter); None, a `FleetPlan`'s default,
-    leaves the choice to `run_packed`. `subset` optionally pins the static
+    invocation with state resident, §9.7); None (the default) leaves
+    the choice to `run_packed`. `subset` optionally pins the static
     opcode subset for the branchless/pallas steppers; by default it is
     derived from the program text (`iss.opcode_subset`), letting the
     compiler drop opcode classes the workload can never retire. With a
@@ -385,11 +352,9 @@ def run_stream(code: np.ndarray, source: Source, *, n_items: int,
     loop serves both, so the sync/harvest/refill subtleties exist in
     exactly one place — with the run's whole-pool accounting (lane-step
     slots including padding lanes, segment count, measured wall clock)
-    folded back into the returned `FleetResult`. `refill`/`adaptive`
-    pick the resident runtime and superstep controller exactly as in
-    `run_packed` (DESIGN.md §9.9); with the default resident loop the
-    per-segment host sync is one small async stats read, with
-    `refill="host"` it is the PR-4 blocking done-count scalar.
+    folded back into the returned `FleetResult`. `adaptive` turns on
+    the superstep controller exactly as in `run_packed` (DESIGN.md
+    §9.9); the per-segment host sync is one small async stats read.
 
     `cost` optionally turns on the per-lane timing layer (DESIGN.md
     §9.10): an (N_COST,) int32 cycle-cost row (`cycles.cost_row`) priced
@@ -402,8 +367,8 @@ def run_stream(code: np.ndarray, source: Source, *, n_items: int,
                      out_addr=out_addr, cost=cost)],
         chunk=chunk, seg_steps=seg_steps, keep_state=keep_state,
         mesh=mesh, stepper=stepper, subset=subset, prefetch=prefetch,
-        refill=refill, adaptive=adaptive, faults=faults,
-        redundancy=redundancy, max_retries=max_retries)
+        adaptive=adaptive, faults=faults, redundancy=redundancy,
+        max_retries=max_retries)
     return dataclasses.replace(
         results[0], lane_steps=stats.lane_steps,
         n_segments=stats.n_segments, chunk=stats.chunk,
@@ -456,8 +421,7 @@ class PackedStats:
     cadence a first-class output: `host_syncs` counts every blocking
     device->host read the run performed (the `fleet.sync` spans),
     `sync_wait_s` the host time spent inside them, and `refill_wall_s`
-    the host time spent in the `fleet.restock` spans that retire and
-    stage items. How long the device itself sat idle is read from a
+    the host time spent in the `fleet.restock` spans that stage items. How long the device itself sat idle is read from a
     profiler trace, where these spans share the device planes' clock.
     `seg_schedule` records the seg_steps actually used per segment —
     constant for a fixed run, the controller's trace for an adaptive
@@ -465,7 +429,7 @@ class PackedStats:
 
     The shard-local fields (DESIGN.md §9.12) attribute the run to the
     mesh: `n_shards` is the lane-pool shard count (1 single-device),
-    and for the resident loop `shard_retired`/`shard_lane_steps` break
+    and `shard_retired`/`shard_lane_steps` break
     items retired and lane-step slots down per shard, so a scaling
     regression is attributable from the stats alone."""
     n_groups: int
@@ -478,7 +442,6 @@ class PackedStats:
     wall_s: float
     stepper: str
     n_devices: int
-    refill: str = "host"          # "device" (resident, §9.9) or "host"
     adaptive: bool = False
     host_syncs: int = 0           # blocking device->host reads
     sync_wait_s: float = 0.0      # host time blocked in those reads
@@ -618,46 +581,12 @@ def _apportion(slots: int, remaining) -> np.ndarray:
     return take
 
 
-def _fresh_packed(mems: np.ndarray, active: np.ndarray,
-                  prog_id: np.ndarray,
-                  max_steps: np.ndarray) -> iss.PackedState:
-    return iss.PackedState(
-        lanes=_fresh_chunk(mems, active),
-        prog_id=jnp.asarray(prog_id, iss.I32),
-        max_steps=jnp.asarray(max_steps, iss.I32))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _refill_packed(state: iss.PackedState, replace, new_mems, new_prog,
-                   new_ms) -> iss.PackedState:
-    """Reset `replace` lanes to a fresh item of (possibly) another group:
-    new memory image, bank row, and step budget."""
-    return iss.PackedState(
-        lanes=_refill(state.lanes, replace, new_mems),
-        prog_id=jnp.where(replace, new_prog, state.prog_id),
-        max_steps=jnp.where(replace, new_ms, state.max_steps))
-
-
-@jax.jit
-def _done_count_packed(state: iss.PackedState):
-    """Scalar count of done lanes (halted or own step budget exhausted;
-    padding lanes carry budget 0 and count as done).
-
-    The engine's per-segment host sync: comparing this single int32
-    against the host-known value tells whether any lane finished this
-    segment — only then is the O(chunk) harvest pulled."""
-    return (state.lanes.halted
-            | (state.lanes.n_instr >= state.max_steps)).sum()
-
-
 def _packed_state_specs(mesh: Mesh, mem_words: int):
-    """Shard specs for a packed lane pool, derived from the real state
-    constructor (via eval_shape) so the new lane fields (prog_id,
-    max_steps) can never drift from what run_packed actually passes."""
-    abstract = jax.eval_shape(
-        lambda: _fresh_packed(np.zeros((1, mem_words), np.int32),
-                              np.ones(1, bool), np.zeros(1, np.int32),
-                              np.ones(1, np.int32)))
+    """Shard specs for a packed lane pool (`lane_specs` maps each leaf
+    of the abstract `iss.PackedState` by its rank)."""
+    abstract = jax.eval_shape(lambda: iss.PackedState(
+        lanes=jax.vmap(iss.init_state)(jnp.zeros((1, mem_words), iss.I32)),
+        prog_id=jnp.zeros(1, iss.I32), max_steps=jnp.zeros(1, iss.I32)))
     return dsharding.lane_specs(mesh, abstract)
 
 
@@ -717,21 +646,6 @@ def _packed_segment_runner(stepper: str, chunk: int, seg_steps: int,
     def seg_body(bank, code_len, mem_len, cost, state,
                  lane_key=None, epoch=None, steps=seg_steps):
         cr = cost if timing else None
-        if stepper == "switch":
-            if faults is None:
-                lanes = jax.vmap(
-                    lambda p, m, l: iss.run_segment_banked(
-                        bank, code_len, p, m, l, steps, mem_len, cr)
-                )(state.prog_id, state.max_steps, state.lanes)
-            else:
-                lanes = jax.vmap(
-                    lambda p, m, k, e, l: iss.run_segment_banked(
-                        bank, code_len, p, m, l, steps, mem_len, cr,
-                        faults=faults, lane_key=k, epoch=e)
-                )(state.prog_id, state.max_steps, lane_key, epoch,
-                  state.lanes)
-            return iss.PackedState(lanes=lanes, prog_id=state.prog_id,
-                                   max_steps=state.max_steps)
         if stepper == "pallas":
             return iss_stepper.iss_segment_banked(
                 bank, code_len, state, seg_steps=steps, subset=subset,
@@ -836,19 +750,16 @@ class ResidentAcc(NamedTuple):
     it admitted (the host keeps the item->row table, `rowmap`), so the
     retire scatter never crosses a shard boundary. Rows are scattered
     once when the item's lane retires and fetched once at drain —
-    per-item scalar results stay O(fleet) exactly as the host
-    collectors did, they just live on the device until the stream ends.
+    per-item scalar results stay O(fleet), on the device until the
+    stream ends.
     Single-device, `cap == total_items` and the row table is the
-    identity — the old layout, unchanged. Per-GROUP mix totals
-    accumulate in int32 per shard (summed over shards on the host at
-    drain; sound below 2^31 retired instructions per group per mix
-    class; past that bound — or past the keep_state device-row budget —
-    `run_packed` falls back to the host loop, whose collectors are
-    int64 in host RAM). `prev_instr` is the per-lane retired-count
-    snapshot at the last refill — the device-side form of the host
-    path's `prev_instr` array, from which each segment's max step delta
-    is measured. The keep_state leaves are None unless full final state
-    was requested.
+    identity. Per-GROUP mix totals accumulate in int32 per shard and
+    are summed over shards into the host's int64 totals at drain, or
+    earlier, by a flush, in a plan whose rows could pass 2^31 - 1
+    (`_stream_resident`). `prev_instr` is the per-lane retired-count
+    snapshot at the last refill, from which each segment's max step
+    delta is measured. The keep_state leaves are None unless full final
+    state was requested.
     """
     n_instr: jax.Array             # (n_shards*cap,) i32
     n_two: jax.Array               # (n_shards*cap,) i32
@@ -976,8 +887,8 @@ def _resident_refill_runner(mesh: Optional[Mesh], mem_words: int,
                             dmr: bool = False, max_retries: int = 0):
     """Compiled retire+refill op, shard-local end to end (§9.9/§9.12).
 
-    One donated op replaces the host path's demux->rebuild->device_put
-    cycle: finished lanes are detected against their own budgets
+    One donated op retires and refills on the device: finished lanes
+    are detected against their own budgets
     (`iss.retire_mask`), their tallies scattered into the `ResidentAcc`
     rows of the items they carried (dropped-out-of-range scatter — only
     retiring lanes write), and fresh items swapped in from the staged
@@ -1282,7 +1193,7 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
                keep_state: bool = False, mesh: Optional[Mesh] = None,
                stepper: Optional[str] = None,
                subset: Optional[frozenset] = None,
-               prefetch: bool = True, refill: str = "device",
+               prefetch: bool = True,
                adaptive: bool = False,
                checkpoint_dir: Optional[str] = None,
                checkpoint_every: int = 0,
@@ -1294,7 +1205,7 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     Returns `(results, stats)`: `results[g]` is a per-group `FleetResult`
     bit-exact with what `run_stream` would produce for group g alone —
     identical per-item instruction/timing/mix tallies and final state
-    (`tests/test_packed.py` pins this three ways) — and `stats` is the
+    (`tests/test_packed.py` pins it) — and `stats` is the
     whole-run `PackedStats`.
 
     The program bank holds one padded row per group; every stepper
@@ -1319,15 +1230,11 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     and `stats.stepper` and every `FleetResult.stepper` report the one
     that ran.
 
-    `refill` picks the stream loop (DESIGN.md §9.9): "device" (the
-    default) is the *resident* runtime — retire/refill happens in one
-    donated on-device op against a staged batch that was uploaded
-    asynchronously while the previous segment ran, and the only
-    per-segment host read is one small stats vector fetched while the
-    NEXT segment executes — while "host" keeps the PR-4 loop (blocking
-    done-count read, host demux/rebuild, device_put) as the A/B
-    baseline. Per-group results are bit-exact either way
-    (tests/test_resident.py pins full-state parity). `adaptive` turns
+    The stream loop is the *resident* runtime (DESIGN.md §9.9):
+    retire/refill happens in one donated on-device op against a staged
+    batch that was uploaded asynchronously while the previous segment
+    ran, and the only per-segment host read is one small stats vector
+    fetched while the NEXT segment executes. `adaptive` turns
     on the superstep controller (§9.9): each segment's step bound is
     picked from a bounded power-of-two ladder under `seg_steps` by the
     observed halt cadence — deterministic for a given plan, bit-exact
@@ -1348,7 +1255,7 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     `faults` (a `flexibits.faults.FaultSpec`, DESIGN.md §9.14) turns on
     deterministic fault injection: every lane applies the post-commit
     fault transform under its own `fold_in`-derived key, bit-identically
-    across all three steppers. `redundancy="dmr"` pairs lanes as
+    across the steppers. `redundancy="dmr"` pairs lanes as
     primary+shadow running the same item under independent schedules,
     compares architectural digests every `compare_steps(faults,
     seg_steps)` steps (inside the segment runner, and at the segment
@@ -1356,12 +1263,16 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     snapshot (re-executing those steps under fresh draws), and after
     `max_retries` consecutive rollbacks quarantines the pair at its
     next mismatch — parking the defective lanes and resuming the item
-    from the pair's last agreed state on the next free pair. Both
-    require the resident
-    loop (`refill="device"`) and are incompatible with `checkpoint_dir`
-    (the rollback snapshots are not part of the durable snapshot
-    schema); `faults=None` with `redundancy="none"` is bit-exact with
-    the pre-FlexiFault engine (pinned by tests/test_faults.py).
+    from the pair's last agreed state on the next free pair. Both are
+    incompatible with `checkpoint_dir` (the rollback snapshots are not
+    part of the durable snapshot schema); `faults=None` with
+    `redundancy="none"` is bit-exact with the pre-FlexiFault engine
+    (pinned by tests/test_faults.py).
+
+    `keep_state` past `_RESIDENT_KEEP_STATE_WORDS` words of device rows
+    is refused; a plan whose per-group mix rows could pass int32 runs
+    with on-demand flushes, as long as one refill cannot (see
+    `_stream_resident`).
     """
     groups = list(groups)
     if not groups:
@@ -1370,8 +1281,6 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         raise ValueError("seg_steps must be >= 1")
     if chunk < 1:
         raise ValueError("chunk must be >= 1")
-    if refill not in REFILLS:
-        raise ValueError(f"refill must be one of {REFILLS}")
     if redundancy not in REDUNDANCY:
         raise ValueError(f"redundancy must be one of {REDUNDANCY} "
                          f"(tmr is priced by the carbon planner but "
@@ -1380,19 +1289,14 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         faults = None              # rate 0 IS the fault-free graph
     stepper = _choose_stepper(stepper, faults)
     if stepper not in STEPPERS:
-        raise ValueError(f"stepper must be one of {STEPPERS}")
-    resilient = faults is not None or redundancy == "dmr"
-    if resilient:
-        if refill != "device":
-            raise ValueError(
-                "fault injection / DMR needs the resident loop: the "
-                "fault epoch and rollback snapshots live on device "
-                "(pass refill='device')")
-        if checkpoint_dir is not None:
-            raise ValueError(
-                "fault injection / DMR is incompatible with "
-                "checkpoint_dir: epoch/retry/snapshot state is not "
-                "part of the durable checkpoint schema")
+        raise ValueError(f"stepper must be one of {STEPPERS}, got "
+                         f"{stepper!r}")
+    if (faults is not None or redundancy == "dmr") \
+            and checkpoint_dir is not None:
+        raise ValueError(
+            "fault injection / DMR is incompatible with checkpoint_dir: "
+            "epoch/retry/snapshot state is not part of the durable "
+            "checkpoint schema")
     if (faults is not None and stepper == "pallas"
             and jax.default_backend() == "tpu"):
         raise ValueError(
@@ -1403,36 +1307,16 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     n_groups = len(groups)
     counts = np.array([g.n_items for g in groups], np.int64)
     total_items = int(counts.sum())
-    if refill == "device" and groups:
-        # resident-safety fallback: the on-device per-group mix
-        # counters are int32 (a group's per-class retired count is
-        # bounded by n_items x max_steps), and keep_state scatters full
-        # final state into O(fleet) device rows — past either bound the
-        # host loop (int64 collectors, host-RAM state) is the correct
-        # runtime, so fall back rather than overflow/OOM silently; the
-        # returned PackedStats.refill reports what actually ran.
-        mix_bound = max(int(g.n_items) * int(g.max_steps)
-                        for g in groups)
-        ks_words = 0
-        if keep_state:
-            ks_words = total_items * (
-                max(g.mem_words for g in groups) + 16 + 1
-                + len(iss.MIX_CLASSES))
-        if mix_bound > _RESIDENT_MIX_LIMIT \
-                or ks_words > _RESIDENT_KEEP_STATE_WORDS:
-            if resilient:
-                raise ValueError(
-                    "plan exceeds the resident-runtime safety bounds "
-                    "(int32 mix counters / keep_state device rows) and "
-                    "fault injection / DMR cannot fall back to the "
-                    "host-refill loop — shrink the plan or drop the "
-                    "fault/redundancy knobs")
-            refill = "host"
-    if checkpoint_dir is not None and refill != "device":
-        raise ValueError(
-            "checkpoint_dir requires the resident loop: refill='device' "
-            "within the resident safety bounds (the host-refill loop "
-            "keeps no durable on-device state)")
+    if keep_state:
+        # keep_state scatters full final state into O(fleet) device rows
+        ks_words = total_items * (max(g.mem_words for g in groups) + 16
+                                  + 1 + len(iss.MIX_CLASSES))
+        if ks_words > _RESIDENT_KEEP_STATE_WORDS:
+            raise ValueError(
+                f"keep_state would hold {ks_words:,} words of device rows, "
+                f"past the bound of {_RESIDENT_KEEP_STATE_WORDS:,} "
+                f"(_RESIDENT_KEEP_STATE_WORDS): keep_state is for parity "
+                f"checks and small fleets")
     if total_items == 0:
         empty = [FleetResult(
             n_items=0, n_instr=np.zeros(0, np.int64),
@@ -1446,8 +1330,7 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         return empty, PackedStats(
             n_groups=n_groups, n_progs=n_groups, bank_width=0,
             lane_steps=0, n_segments=0, chunk=0, seg_steps=seg_steps,
-            wall_s=0.0, stepper=stepper, n_devices=1, refill=refill,
-            adaptive=adaptive)
+            wall_s=0.0, stepper=stepper, n_devices=1, adaptive=adaptive)
     mem_words = max(g.mem_words for g in groups)
     bank_np, code_len_np = iss.pack_programs([g.code for g in groups])
     if subset is None:
@@ -1483,31 +1366,15 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
     clock = _SyncClock()
     controller = _SuperstepController(seg_steps, chunk, adaptive)
     t0 = time.perf_counter()
-    if refill == "device":
-        # the resident loop owns per-(group, shard) prefetchers — the
-        # item->shard partition decides what each one reads (§9.12)
-        out = _stream_resident(
-            groups, prefetch, counts, ms_of, bank, code_len, mem_len,
-            cost, timing, bank_np, chunk, keep_state, mesh, stepper,
-            subset, mem_words, controller, clock,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every,
-            faults=faults, redundancy=redundancy,
-            max_retries=max_retries,
-            crash_after=_crash_after_segments)
-    else:
-        prefs = [_Prefetcher(g.source, g.n_items,
-                             block=max(1, min(chunk, g.n_items)),
-                             background=prefetch)
-                 for g in groups]
-        try:
-            out = _stream_host(groups, prefs, counts, ms_of, bank,
-                               code_len, mem_len, cost, timing, bank_np,
-                               chunk, keep_state, mesh, stepper, subset,
-                               mem_words, controller, clock)
-        finally:
-            for p in prefs:
-                p.close()
+    # the resident loop owns per-(group, shard) prefetchers — the
+    # item->shard partition decides what each one reads (§9.12)
+    out = _stream_resident(
+        groups, prefetch, counts, ms_of, bank, code_len, mem_len, cost,
+        timing, bank_np, chunk, keep_state, mesh, stepper, subset,
+        mem_words, controller, clock, checkpoint_dir=checkpoint_dir,
+        checkpoint_every=checkpoint_every, faults=faults,
+        redundancy=redundancy, max_retries=max_retries,
+        crash_after=_crash_after_segments)
 
     wall_s = time.perf_counter() - t0
     busy = np.array([r.sum() for r in out["r_instr"]], np.float64)
@@ -1534,187 +1401,17 @@ def run_packed(groups, *, chunk: int = 256, seg_steps: int = 4096,
         n_groups=n_groups, n_progs=bank_np.shape[0],
         bank_width=bank_np.shape[1], lane_steps=out["lane_steps"],
         n_segments=out["n_segments"], chunk=chunk, seg_steps=seg_steps,
-        wall_s=wall_s, stepper=stepper, n_devices=n_dev, refill=refill,
+        wall_s=wall_s, stepper=stepper, n_devices=n_dev,
         adaptive=adaptive, host_syncs=clock.host_syncs,
         sync_wait_s=clock.sync_wait_s, refill_wall_s=clock.refill_wall_s,
         seg_schedule=tuple(controller.schedule[:out["n_segments"]]),
-        n_shards=int(out.get("n_shards", n_dev)),
-        shard_retired=tuple(int(x)
-                            for x in out.get("shard_retired", ())),
-        shard_lane_steps=tuple(int(x)
-                               for x in out.get("shard_lane_steps", ())),
-        redundancy=redundancy,
-        detected=int(out.get("detected", 0)),
-        corrected=int(out.get("corrected", 0)),
-        quarantined=int(out.get("quarantined", 0)),
-        discarded=int(out.get("discarded", 0)))
+        n_shards=out["n_shards"],
+        shard_retired=tuple(int(x) for x in out["shard_retired"]),
+        shard_lane_steps=tuple(int(x) for x in out["shard_lane_steps"]),
+        redundancy=redundancy, detected=out["detected"],
+        corrected=out["corrected"], quarantined=out["quarantined"],
+        discarded=out["discarded"])
     return results, stats
-
-
-def _stream_host(groups, prefs, counts, ms_of, bank, code_len, mem_len,
-                 cost, timing, bank_np, chunk, keep_state, mesh, stepper,
-                 subset, mem_words, controller: _SuperstepController,
-                 clock: _SyncClock):
-    """The PR-4 host-refill stream loop (the `refill="host"` A/B path):
-    blocking single-scalar done-count sync per segment, host-side
-    demux + refill rebuild + device_put on finishing segments."""
-    n_groups = len(groups)
-    r_instr = [np.zeros(n, np.int64) for n in counts]
-    r_two = [np.zeros(n, np.int64) for n in counts]
-    r_cycles = [np.zeros(n, np.int64) for n in counts]
-    r_halt = [np.zeros(n, bool) for n in counts]
-    r_out = [np.zeros(n, np.int32) for n in counts]
-    r_mix = [np.zeros(len(iss.MIX_CLASSES), np.int64) for _ in groups]
-    g_lane_steps = np.zeros(n_groups, np.int64)
-    g_segments = np.zeros(n_groups, np.int64)
-    r_mem = r_regs = r_pc = r_mix_items = None
-    if keep_state:
-        r_mem = [np.zeros((n, g.mem_words), np.int32)
-                 for n, g in zip(counts, groups)]
-        r_regs = [np.zeros((n, 16), np.int32) for n in counts]
-        r_pc = [np.zeros(n, np.int32) for n in counts]
-        r_mix_items = [np.zeros((n, len(iss.MIX_CLASSES)), np.int32)
-                       for n in counts]
-
-    cursor = np.zeros(n_groups, np.int64)   # next item per group
-    ids = np.full(chunk, -1, np.int64)      # item index within group
-    lane_group = np.full(chunk, -1, np.int64)
-    lane_ms = np.zeros(chunk, np.int64)     # host copy of budgets
-
-    def admit(state, free_lanes):
-        """Backfill `free_lanes` with items from any pending group."""
-        take = _apportion(len(free_lanes), counts - cursor)
-        n_new = int(take.sum())
-        if n_new == 0:
-            return state, 0
-        new_mems = np.zeros((chunk, mem_words), np.int32)
-        new_prog = np.zeros(chunk, np.int32)
-        new_ms = np.zeros(chunk, np.int32)
-        replace = np.zeros(chunk, bool)
-        off = 0
-        for g in np.nonzero(take)[0]:
-            k = int(take[g])
-            lanes = free_lanes[off:off + k]
-            off += k
-            new_mems[lanes, :groups[g].mem_words] = prefs[g].take(k)
-            new_prog[lanes] = g
-            new_ms[lanes] = ms_of[g]
-            replace[lanes] = True
-            ids[lanes] = np.arange(cursor[g], cursor[g] + k)
-            lane_group[lanes] = g
-            lane_ms[lanes] = ms_of[g]
-            cursor[g] += k
-        if state is None:
-            return (new_mems, replace, new_prog, new_ms), n_new
-        return _refill_packed(state, jnp.asarray(replace),
-                              jnp.asarray(new_mems),
-                              jnp.asarray(new_prog),
-                              jnp.asarray(new_ms)), n_new
-
-    prev_instr = np.zeros(chunk, np.int64)
-    lane_steps = 0
-    n_segments = 0
-
-    with clock.span("fleet.stream"):
-        # initial fill (admit into a fresh pool; padding lanes carry
-        # budget 0 and stay parked forever)
-        with clock.span("fleet.restock"):
-            (first, active0, prog0, ms0), _ = admit(None, np.arange(chunk))
-        state = _fresh_packed(first, active0, prog0, ms0)
-        if mesh is not None:
-            state = jax.tree.map(jax.device_put, state,
-                                 dsharding.lane_shardings(mesh, state))
-        expected_done = chunk - int((ids >= 0).sum())
-
-        while (ids >= 0).any():
-            with clock.span("fleet.dispatch"):
-                seg_steps = controller.next_seg()
-                seg_fn = _packed_segment_runner(stepper, chunk, seg_steps,
-                                                mem_words, n_groups,
-                                                bank_np.shape[1], mesh,
-                                                subset, timing)
-                state = seg_fn(bank, code_len, mem_len, cost, state)
-                done_count = _done_count_packed(state)
-            n_segments += 1
-            active = ids >= 0
-            act_per_group = np.bincount(lane_group[active],
-                                        minlength=n_groups)
-            g_segments += act_per_group > 0
-
-            # single-scalar sync, as in run_stream: if no lane finished,
-            # every active lane ran exactly seg_steps
-            if int(clock.fetch(done_count)) == expected_done:
-                lane_steps += chunk * seg_steps
-                g_lane_steps += act_per_group * seg_steps
-                prev_instr[active] += seg_steps
-                controller.record(0, seg_steps)
-                continue
-
-            halted = clock.fetch(state.lanes.halted)
-            n_instr = clock.fetch(state.lanes.n_instr).astype(np.int64)
-            delta = int((n_instr - prev_instr).max(initial=0))
-            lane_steps += chunk * delta
-            g_lane_steps += act_per_group * delta
-            prev_instr = n_instr
-
-            done = active & (halted | (n_instr >= lane_ms))
-            idx = np.nonzero(done)[0]
-            if idx.size:
-                jidx = jnp.asarray(idx)
-                two = clock.fetch(state.lanes.n_two_stage).astype(np.int64)
-                mix_rows = clock.fetch(
-                    state.lanes.mix[jidx]).astype(np.int64)
-                if timing:   # one extra pull, only when the layer is on
-                    cyc = clock.fetch(state.lanes.n_cycles).astype(np.int64)
-                # one O(done x mem_words) row gather serves every
-                # group's out-word read (and the keep_state memories) —
-                # not a full O(chunk) column pull per group
-                need_mem = keep_state or any(
-                    g.out_addr is not None for g in groups)
-                if need_mem:
-                    mem_rows = clock.fetch(state.lanes.mem[jidx])
-                if keep_state:
-                    regs_rows = clock.fetch(state.lanes.regs[jidx])
-                    pc_rows = clock.fetch(state.lanes.pc)[idx]
-                # demux, retire the done lanes, then backfill from any
-                # pending group
-                with clock.span("fleet.restock"):
-                    for g in np.unique(lane_group[idx]):
-                        sel = lane_group[idx] == g
-                        lg = idx[sel]
-                        items = ids[lg]
-                        r_instr[g][items] = n_instr[lg]
-                        r_two[g][items] = two[lg]
-                        if timing:
-                            r_cycles[g][items] = cyc[lg]
-                        r_halt[g][items] = halted[lg]
-                        r_mix[g] += mix_rows[sel].sum(0)
-                        if groups[g].out_addr is not None:
-                            r_out[g][items] = \
-                                mem_rows[sel][:, groups[g].out_addr]
-                        if keep_state:
-                            r_mem[g][items] = \
-                                mem_rows[sel][:, :groups[g].mem_words]
-                            r_regs[g][items] = regs_rows[sel]
-                            r_pc[g][items] = pc_rows[sel]
-                            r_mix_items[g][items] = mix_rows[sel]
-                    ids[idx] = -1
-                    lane_group[idx] = -1
-                    lane_ms[idx] = 0
-                    state, _ = admit(state, idx)
-                # refilled lanes restart at n_instr=0; retired-but-empty
-                # lanes keep their frozen device counters
-                prev_instr[idx] = np.where(ids[idx] >= 0, 0,
-                                           prev_instr[idx])
-            controller.record(int(idx.size), seg_steps)
-            expected_done = chunk - int((ids >= 0).sum())
-
-    return {"r_instr": r_instr, "r_two": r_two, "r_halt": r_halt,
-            "r_out": r_out, "r_mix": r_mix, "r_mem": r_mem,
-            "r_regs": r_regs, "r_pc": r_pc, "r_mix_items": r_mix_items,
-            "r_cycles": r_cycles,
-            "g_lane_steps": g_lane_steps, "g_segments": g_segments,
-            "lane_steps": lane_steps, "n_segments": n_segments}
 
 
 _CKPT_VALS = ("n_instr", "n_two", "n_cycles", "halted", "out")
@@ -1744,8 +1441,7 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
                      checkpoint_every: int = 0, faults=None,
                      redundancy: str = "none", max_retries: int = 2,
                      crash_after=None):
-    """The resident stream loop (DESIGN.md §9.9, shard-local §9.12,
-    `refill="device"`).
+    """The resident stream loop (DESIGN.md §9.9, shard-local §9.12).
 
     Pipeline per iteration, in device-queue order:
 
@@ -1785,6 +1481,23 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
     if mesh is not None:
         n_shards = int(np.prod(list(mesh.shape.values())))
     spc = chunk // n_shards          # lanes (and staged rows) per shard
+    # int32 mix rows: a plan where some group's n_items x max_steps can
+    # pass _RESIDENT_MIX_LIMIT flushes `acc.mix_g` into the int64
+    # `mix_base` as it runs. No row may pass 2^31 - 1 between two
+    # flushes: after one, a row gains at most spc x max(ms_of) from the
+    # work that items in flight then had done, plus the lane-steps run
+    # since, so the loop flushes once those, with the next segment's
+    # chunk x seg_steps, could pass the limit. `mix_room` is that
+    # budget for the lane-steps, None where no flush is ever needed.
+    mix_room = None
+    if int((counts * ms_of).max()) > _RESIDENT_MIX_LIMIT:
+        mix_room = _RESIDENT_MIX_LIMIT - spc * int(ms_of.max())
+        if mix_room < 0:
+            raise ValueError(
+                f"{spc} lanes a shard x a {int(ms_of.max()):,}-step "
+                f"budget could pass the int32 mix rows' bound of "
+                f"{_RESIDENT_MIX_LIMIT:,} (_RESIDENT_MIX_LIMIT) in one "
+                f"refill: lower chunk or max_steps")
 
     # ---- host-side merged results: items finished before a resume
     # live here and never get device rows again
@@ -2142,6 +1855,7 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
         dckpt.save(checkpoint_dir, n_segments, tree)
 
     last_saved = n_segments
+    flushed_at = lane_steps
     with clock.span("fleet.stream"):
         try:
             with clock.span("fleet.restock"):
@@ -2227,6 +1941,15 @@ def _stream_resident(groups, prefetch, counts, ms_of, bank, code_len,
                 prev_seg = seg_steps
                 retired += n_ret
                 shard_retired += sv[:, 0]
+                if mix_room is not None and (
+                        lane_steps - flushed_at + chunk * seg_steps
+                        > mix_room):
+                    mix_base += clock.fetch(acc.mix_g).astype(
+                        np.int64).sum(0)
+                    acc = acc._replace(mix_g=jax.device_put(
+                        np.zeros(acc.mix_g.shape, np.int32),
+                        acc.mix_g.sharding))
+                    flushed_at = lane_steps
                 with clock.span("fleet.restock"):
                     consume(sv[:, 1])
                     restock()
@@ -2290,8 +2013,8 @@ def run_workload_stream(w: Workload, n_items: int, *, seed: int = 0,
                         max_steps: Optional[int] = None,
                         keep_state: bool = False,
                         mesh: Optional[Mesh] = None,
-                        stepper: Optional[str] = "branchless",
-                        prefetch: bool = True, refill: str = "device",
+                        stepper: Optional[str] = None,
+                        prefetch: bool = True,
                         adaptive: bool = False,
                         cost: Optional[np.ndarray] = None,
                         subset: Optional[frozenset] = None,
@@ -2311,6 +2034,6 @@ def run_workload_stream(w: Workload, n_items: int, *, seed: int = 0,
         max_steps=w.max_steps if max_steps is None else max_steps,
         chunk=chunk, subset=subset,
         seg_steps=seg_steps, out_addr=w.out_addr, keep_state=keep_state,
-        mesh=mesh, stepper=stepper, prefetch=prefetch, refill=refill,
+        mesh=mesh, stepper=stepper, prefetch=prefetch,
         adaptive=adaptive, cost=cost, faults=faults,
         redundancy=redundancy, max_retries=max_retries)

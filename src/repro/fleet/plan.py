@@ -94,28 +94,19 @@ class FleetGroup:
 class FleetPlan:
     """A full heterogeneous fleet plus engine tuning knobs.
 
-    `stepper` names the segment interpreter: "branchless" (lane-
-    parallel stepper with per-workload opcode-subset specialization,
-    DESIGN.md §9.5), "pallas" (fused-segment kernel, §9.7), or the
-    legacy "switch" interpreter for A/B runs. None (default) is the
-    engine's choice: "pallas" on a TPU when the plan has no fault
-    schedule, "branchless" otherwise (`engine._choose_stepper`); the
-    report's results name the stepper that ran. `prefetch` enables
-    double-buffered async host refill (§9.6); `packed` (the default)
-    executes ALL groups in one packed multi-program stream — program
-    bank + per-lane prog_id, freed lanes backfilled from any pending
-    group (§9.8) — instead of draining groups sequentially. Per-group
-    results are bit-exact either way (pinned by tests/test_packed.py);
-    `packed=False` keeps the sequential path as the A/B baseline.
-
-    `refill` picks the stream loop (§9.9): "device" (default) is the
-    resident runtime — on-device retire/refill, one small async stats
-    read per segment — and "host" the PR-4 blocking host-refill loop,
-    kept for A/B runs; results are bit-exact either way
-    (tests/test_resident.py). `adaptive` turns on the superstep
-    controller: each segment's step bound is picked from a bounded
-    ladder under `seg_steps` by the observed halt cadence
-    (deterministic for a given plan, bit-exact with fixed
+    Every group runs in ONE packed multi-program stream — program bank
+    + per-lane prog_id, freed lanes backfilled from any pending group
+    (DESIGN.md §9.8) — on the resident runtime (§9.9). `stepper` names
+    the segment interpreter: "branchless" (lane-parallel stepper with
+    per-workload opcode-subset specialization, §9.5) or "pallas"
+    (fused-segment kernel, §9.7). None (default) is the engine's
+    choice: "pallas" on a TPU when the plan has no fault schedule,
+    "branchless" otherwise (`engine._choose_stepper`); the report's
+    results name the stepper that ran. `prefetch` enables
+    double-buffered async host refill (§9.6). `adaptive` turns on the
+    superstep controller (§9.9): each segment's step bound is picked
+    from a bounded ladder under `seg_steps` by the observed halt
+    cadence (deterministic for a given plan, bit-exact with fixed
     segmentation).
 
     `timing` turns on the per-lane cycle layer (DESIGN.md §9.10): each
@@ -143,8 +134,8 @@ class FleetPlan:
     fault schedule injected into every lane, and — with
     `redundancy="dmr"` — shadow-lane detection with rollback to the
     last pair compare (`engine.compare_steps`) and quarantine.
-    Resilient plans require the resident refill loop; `faults=None` with `redundancy="none"` (the default)
-    keeps the fault-free graphs bit-exact. The report prices each group
+    `faults=None` with `redundancy="none"` (the default) keeps the
+    fault-free graphs bit-exact. The report prices each group
     under the plan's redundancy mode (`carbon.redundant_*`), so DMR
     plans show the spare-area + re-execution carbon they'd pay in
     deployment."""
@@ -155,8 +146,6 @@ class FleetPlan:
     clock_hz: float = 10_000.0
     stepper: Optional[str] = None         # None: the engine's choice
     prefetch: bool = True
-    packed: bool = True
-    refill: str = "device"
     adaptive: bool = False
     timing: Optional[str] = None          # None | "base" | "dynamic"
     validate_budgets: bool = True         # FlexiLint min-steps gate
@@ -229,70 +218,42 @@ def run_plan(plan: FleetPlan, mesh: Optional[Mesh] = None,
              checkpoint_every: int = 0) -> FleetReport:
     """Execute the plan and price it through the carbon report.
 
-    With `plan.packed` (the default) every group runs in ONE packed
-    stream (engine.run_packed) and `fleet/report.py` demuxes the
-    per-lane tallies back into per-group `GroupReport`s; with
-    `packed=False` groups drain sequentially through `run_stream`, one
-    stream each — the A/B baseline the packed runtime is benchmarked
-    (and pinned bit-exact) against. Under a mesh the resident stream is
-    shard-local (DESIGN.md §9.12) and the returned
-    `FleetReport.packed` carries per-shard retirement/lane-step stats;
-    `checkpoint_dir`/`checkpoint_every` make the packed resident stream
-    durable (mid-flight checkpoint + bit-exact auto-resume — packed
-    plans only).
+    Every group runs in ONE packed stream (engine.run_packed) and
+    `fleet/report.py` demuxes the per-lane tallies back into per-group
+    `GroupReport`s. Under a mesh the resident stream is shard-local
+    (DESIGN.md §9.12) and the returned `FleetReport.packed` carries
+    per-shard retirement/lane-step stats; `checkpoint_dir`/
+    `checkpoint_every` make the stream durable (mid-flight checkpoint +
+    bit-exact auto-resume). A plan with no groups returns an empty
+    report.
 
     The job is a `fleet.job` span on the profiler's host plane, holding
     the `fleet.static` pass, the engine's `fleet.stream` and the
     `fleet.report` demux and pricing (engine `_SyncClock`).
     """
-    if checkpoint_dir is not None and not (plan.packed and plan.groups):
-        raise ValueError("checkpointing requires a packed plan")
+    if not plan.groups:
+        return FleetReport(groups=[], intensity=plan.intensity)
     with TraceAnnotation("fleet.job"):
-        if plan.packed and plan.groups:
-            with TraceAnnotation("fleet.static"):
-                lowered, resolved = _packed_groups(plan)
-            results, stats = engine.run_packed(
-                lowered, chunk=plan.chunk, seg_steps=plan.seg_steps,
-                keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
-                prefetch=plan.prefetch, refill=plan.refill,
-                adaptive=plan.adaptive, checkpoint_dir=checkpoint_dir,
-                checkpoint_every=checkpoint_every, faults=plan.faults,
-                redundancy=plan.redundancy, max_retries=plan.max_retries)
-            with TraceAnnotation("fleet.report"):
-                group_reports = [
-                    build_group_report(
-                        group=g, workload=w, core=core, result=res,
-                        lifetime_s=lifetime_s, execs_per_day=execs_per_day,
-                        intensity=plan.intensity, clock_hz=plan.clock_hz,
-                        wcet_cycles=wcet_cycles, redundancy=plan.redundancy,
-                        fault_rate=0.0 if plan.faults is None
-                        else plan.faults.rate)
-                    for g, (w, core, lifetime_s, execs_per_day,
-                            wcet_cycles), res
-                    in zip(plan.groups, resolved, results)]
-            return FleetReport(groups=group_reports,
-                               intensity=plan.intensity, packed=stats)
-
-        group_reports = []
-        for g in plan.groups:
-            with TraceAnnotation("fleet.static"):
-                w, core, lifetime_s, execs_per_day = g.resolve()
-                max_steps, subset, wcet_cycles = _static_pass(plan, g, w,
-                                                              core)
-            res = engine.run_workload_stream(
-                w, g.n_items, seed=g.seed, chunk=plan.chunk,
-                seg_steps=plan.seg_steps, max_steps=max_steps,
-                keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
-                prefetch=plan.prefetch, refill=plan.refill,
-                adaptive=plan.adaptive, cost=_group_cost(plan, core),
-                subset=subset, faults=plan.faults,
-                redundancy=plan.redundancy, max_retries=plan.max_retries)
-            with TraceAnnotation("fleet.report"):
-                group_reports.append(build_group_report(
+        with TraceAnnotation("fleet.static"):
+            lowered, resolved = _packed_groups(plan)
+        results, stats = engine.run_packed(
+            lowered, chunk=plan.chunk, seg_steps=plan.seg_steps,
+            keep_state=keep_state, mesh=mesh, stepper=plan.stepper,
+            prefetch=plan.prefetch, adaptive=plan.adaptive,
+            checkpoint_dir=checkpoint_dir,
+            checkpoint_every=checkpoint_every, faults=plan.faults,
+            redundancy=plan.redundancy, max_retries=plan.max_retries)
+        with TraceAnnotation("fleet.report"):
+            group_reports = [
+                build_group_report(
                     group=g, workload=w, core=core, result=res,
                     lifetime_s=lifetime_s, execs_per_day=execs_per_day,
                     intensity=plan.intensity, clock_hz=plan.clock_hz,
                     wcet_cycles=wcet_cycles, redundancy=plan.redundancy,
                     fault_rate=0.0 if plan.faults is None
-                    else plan.faults.rate))
-        return FleetReport(groups=group_reports, intensity=plan.intensity)
+                    else plan.faults.rate)
+                for g, (w, core, lifetime_s, execs_per_day,
+                        wcet_cycles), res
+                in zip(plan.groups, resolved, results)]
+        return FleetReport(groups=group_reports,
+                           intensity=plan.intensity, packed=stats)

@@ -142,12 +142,13 @@ def simulation_footprint_kg(wall_s: float, n_chips: int = 1,
 class FleetReport:
     """Fleet-wide pricing + engine accounting.
 
-    From a packed run (DESIGN.md §9.8) the per-group `GroupReport`s are
-    the *demux* of one multiplexed stream: each group's per-item
-    instruction/timing/mix tallies — and therefore every carbon number —
-    are bit-exact with a sequential per-group run, while `packed` holds
-    the whole-run `PackedStats` (total lane-step slots including idle
-    lanes, segment count, wall clock for the single stream).
+    The per-group `GroupReport`s are the *demux* of one packed stream
+    (DESIGN.md §9.8): each group's per-item instruction/timing/mix
+    tallies — and therefore every carbon number — are bit-exact with
+    the group run as its own plan, while `packed` holds the whole-run
+    `PackedStats` (total lane-step slots including idle lanes, segment
+    count, wall clock for the single stream); it is None only for a
+    plan with no groups.
     """
     groups: List[GroupReport]
     intensity: float
@@ -159,8 +160,8 @@ class FleetReport:
 
     @property
     def lane_steps(self) -> int:
-        """Lane-step slots attributed to groups' active lanes. For a
-        packed run, `packed.lane_steps` additionally counts idle/padding
+        """Lane-step slots attributed to groups' active lanes.
+        `packed.lane_steps` additionally counts idle/padding
         slots, which belong to the shared stream rather than a group."""
         return sum(g.result.lane_steps for g in self.groups)
 
@@ -242,10 +243,9 @@ class FleetReport:
                 f"(bank {p.n_progs}x{p.bank_width} words), "
                 f"{p.n_segments} segments, {p.lane_steps:,} lane-step "
                 f"slots incl. idle, chunk {p.chunk}")
-            mode = f"{p.refill}-refill" \
-                + (", adaptive supersteps" if p.adaptive else "")
+            mode = " (adaptive supersteps)" if p.adaptive else ""
             lines.append(
-                f"sync stats ({mode}): {p.host_syncs} blocking host "
+                f"sync stats{mode}: {p.host_syncs} blocking host "
                 f"syncs ({p.sync_wait_s:.3f}s waited), refill host work "
                 f"{p.refill_wall_s:.3f}s")
             if p.redundancy != "none" or p.detected or p.quarantined:

@@ -19,12 +19,12 @@ scheme into a single kernel per lane tile; any change to the commit
 semantics here must be mirrored there (the instruction-soup tests in
 tests/test_stepper.py pin all three against each other).
 
-All three also run *banked* (DESIGN.md §9.8): lanes fetch from a padded
-multi-program bank through `fetch_banked` (per-program pc clamp), carry
-their program row and step budget in `PackedState`, and retire exactly
-what a single-program pool running their program would — the packed
-fleet runtime multiplexes a whole heterogeneous plan through one lane
-pool on top of this.
+The branchless and Pallas steppers also run *banked* (DESIGN.md §9.8):
+lanes fetch from a padded multi-program bank through `fetch_banked`
+(per-program pc clamp), carry their program row and step budget in
+`PackedState`, and retire exactly what a single-program pool running
+their program would — the packed fleet runtime multiplexes a whole
+heterogeneous plan through one lane pool on top of this.
 
 Cycle accounting implements the paper's bit-serial timing model
 (cycles.py): per retired instruction, one-stage or two-stage cost for the
@@ -315,7 +315,7 @@ def step(code: jax.Array, s: ISSState, *,
         n_cycles=n_cycles,
     )
     if faults is not None:
-        # post-commit fault transform: the switch stepper only runs a
+        # post-commit fault transform: this scalar stepper only runs a
         # step while live, so the gate is just post-commit ~halted
         out = flexifault.apply_faults(faults, lane_key, epoch, out,
                                       mem_len=mem_len)
@@ -946,9 +946,7 @@ def retire_mask(ps: PackedState, item_slot: jax.Array) -> jax.Array:
 def refill_take(free: jax.Array, n_staged: jax.Array):
     """Deterministic staged->lane assignment for an on-device refill.
 
-    Free lanes are ranked in lane order (a cumsum compaction — the
-    device-side analogue of the host path's `np.nonzero(done)` index
-    walk); the first `n_staged` of them take staged rows 0..n_staged-1
+    Free lanes are ranked in lane order (a cumsum compaction); the first `n_staged` of them take staged rows 0..n_staged-1
     in order, so the host — which built the staged batch and will learn
     only the *count* consumed — always knows exactly which item went
     where it matters (into the stream) without reading any lane state.
@@ -969,8 +967,9 @@ def refill_lanes(ps: PackedState, take: jax.Array, src: jax.Array,
     """Swap fresh items into `take` lanes from staged rows `src`.
 
     The jnp form of the resident swap (gather staged rows, masked
-    reset of the architectural state) — used by the branchless and
-    switch steppers; the Pallas stepper's variant
+    reset of the architectural state) — used by the branchless
+    stepper, and by the Pallas one under a mesh or DMR; the Pallas
+    stepper's single-device variant
     (`kernels/iss_stepper.py::iss_refill`) expresses the same gather
     as a one-hot reduction and is bit-identical.
     """
@@ -1020,39 +1019,6 @@ def run_segment(code: jax.Array, s: ISSState, seg_steps: int,
     def body(c):
         k, st = c
         return k + 1, step(code, st, cost=cost)
-
-    _, out = lax.while_loop(cond, body, (jnp.zeros((), I32), s))
-    return out
-
-
-def run_segment_banked(bank: jax.Array, code_len: jax.Array,
-                       prog_id: jax.Array, max_steps: jax.Array,
-                       s: ISSState, seg_steps: int,
-                       mem_len: jax.Array = None,
-                       cost: jax.Array = None, faults=None,
-                       lane_key: jax.Array = None,
-                       epoch: jax.Array = None) -> ISSState:
-    """Banked `run_segment`: the lax.switch interpreter fetching from a
-    program bank (scalar state; the packed engine vmaps it per lane).
-    `max_steps` is a traced scalar — each lane brings its own budget;
-    `mem_len` (per-program word counts) bounds the lane's memory ports
-    at its own program's size; `cost` (per-program rows) prices the
-    lane's retirements on its own program's core; `faults` (with this
-    lane's scalar `lane_key`/`epoch`) turns on the post-commit fault
-    transform.
-    """
-    ml = None if mem_len is None else mem_len[prog_id]
-    cr = None if cost is None else cost[prog_id]
-
-    def cond(c):
-        k, st = c
-        return (~st.halted) & (k < seg_steps) & (st.n_instr < max_steps)
-
-    def body(c):
-        k, st = c
-        instr = fetch_banked(bank, code_len, prog_id, st.pc)
-        return k + 1, step(bank, st, instr=instr, mem_len=ml, cost=cr,
-                           faults=faults, lane_key=lane_key, epoch=epoch)
 
     _, out = lax.while_loop(cond, body, (jnp.zeros((), I32), s))
     return out

@@ -376,9 +376,8 @@ def _refill_kernel(take_ref, src_ref, planes_ref,
     (memory image, program row and step budget packed as one row of
     byte planes) is selected by a one-hot product on the MXU instead of
     a row gather. The take/src assignment itself (`iss.refill_take`, a
-    pool-wide cumsum) is computed outside — ranks cross lane tiles,
-    exactly like the host path's pool-wide free-lane walk. Bit-identical
-    to `iss.refill_lanes`.
+    pool-wide cumsum) is computed outside, because ranks cross lane
+    tiles. Bit-identical to `iss.refill_lanes`.
     """
     take = take_ref[...] != 0
     src = src_ref[...]

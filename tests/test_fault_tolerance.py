@@ -228,14 +228,6 @@ def test_resident_stream_resume_skips_corrupt_newest(tmp_path):
                                           err_msg=f)
 
 
-def test_resident_checkpoint_requires_packed_plan(tmp_path):
-    from repro.fleet.plan import FleetGroup, FleetPlan, run_plan
-    plan = FleetPlan(groups=(FleetGroup(workload="WQ", n_items=4),),
-                     packed=False)
-    with pytest.raises(ValueError, match="packed"):
-        run_plan(plan, checkpoint_dir=str(tmp_path))
-
-
 @pytest.mark.slow
 def test_resident_stream_elastic_resume_across_mesh_shapes(tmp_path):
     """The resident checkpoint is mesh-independent: crash a 4-device

@@ -1,8 +1,9 @@
 """Packed multi-program fleet runtime (DESIGN.md §9.8): banked-fetch
-stepper parity against per-program monolithic runs, three-way
-(switch/branchless/pallas) engine packed-parity with the sequential
-baseline, heterogeneous per-lane step budgets, the proportional
-admission scheduler, and sharded multi-device packed streaming."""
+stepper parity against per-program monolithic runs, packed-parity of
+both steppers with each group run alone, plan reports against one-group
+plans, heterogeneous per-lane step budgets, the proportional admission
+scheduler, the engine's choice of stepper, and sharded multi-device
+packed streaming."""
 import json
 import os
 import subprocess
@@ -99,11 +100,11 @@ def test_fetch_banked_clamps_per_program():
     np.testing.assert_array_equal(np.asarray(got), [11, 11, 22])
 
 
-@pytest.mark.parametrize("mode", ["branchless", "pallas", "switch"])
+@pytest.mark.parametrize("mode", ["branchless", "pallas"])
 def test_banked_segments_match_per_program_monolithic(mode):
     """Interleaved lanes running two different workloads from one bank
     retire exactly what each lane's own single-program `iss.run` does —
-    for all three banked segment steppers."""
+    for both banked segment steppers."""
     workloads = (get("WQ"), get("MC"))
     pids = [i % 2 for i in range(8)]
     ps, refs = _packed_pool(workloads, pids)
@@ -116,14 +117,9 @@ def test_banked_segments_match_per_program_monolithic(mode):
     if mode == "branchless":
         seg = jax.jit(lambda b, c, s: iss.run_segment_lanes_banked(
             b, c, s, 64, sub))
-    elif mode == "pallas":
+    else:
         seg = jax.jit(lambda b, c, s: iss_segment_banked(
             b, c, s, seg_steps=64, subset=sub))
-    else:
-        seg = jax.jit(lambda b, c, s: iss.PackedState(
-            lanes=jax.vmap(lambda p, m, l: iss.run_segment_banked(
-                b, c, p, m, l, 64))(s.prog_id, s.max_steps, s.lanes),
-            prog_id=s.prog_id, max_steps=s.max_steps))
 
     st = ps
     for _ in range(10_000):
@@ -133,11 +129,11 @@ def test_banked_segments_match_per_program_monolithic(mode):
     _assert_lanes_match_refs(st, refs, workloads, pids)
 
 
-@pytest.mark.parametrize("stepper", ["switch", "branchless", "pallas"])
+@pytest.mark.parametrize("stepper", ["branchless", "pallas"])
 def test_packed_engine_bit_exact_with_sequential(stepper):
     """run_packed demuxes per-group results bit-exactly equal to what
     run_stream produces for each group alone — full final state, per-item
-    tallies, and outputs — for all three steppers."""
+    tallies, and outputs — for both steppers."""
     specs = (("WQ", 1, 40), ("MC", 2, 17))
     groups = []
     for key, seed, n in specs:
@@ -169,20 +165,25 @@ def test_packed_engine_bit_exact_with_sequential(stepper):
         np.testing.assert_array_equal(r.out, w.ref(src[:, :w.n_inputs]))
 
 
-def test_packed_plan_report_matches_sequential():
-    """run_plan(packed=True) reports the same per-group carbon numbers
-    (to the bit — same floats) as the sequential baseline, plus packed
-    whole-run stats."""
+@pytest.mark.parametrize("timing", [None, "base", "dynamic"])
+def test_packed_plan_report_matches_sequential(timing):
+    """A two-group plan reports the same per-group carbon numbers (to
+    the bit — same floats) as each group run as its own plan, under
+    every timing mode, plus packed whole-run stats."""
     groups = (
         FleetGroup(workload="WQ", core="SERV", n_items=40, seed=1),
         FleetGroup(workload="MC", core="HERV", n_items=24, seed=2),
     )
-    rep_p = run_plan(FleetPlan(groups=groups, chunk=16, seg_steps=128))
-    rep_s = run_plan(FleetPlan(groups=groups, chunk=16, seg_steps=128,
-                               packed=False))
+    kw = dict(chunk=16, seg_steps=128, timing=timing)
+    rep_p = run_plan(FleetPlan(groups=groups, **kw))
+    alone = [run_plan(FleetPlan(groups=(g,), **kw)).groups[0]
+             for g in groups]
     assert rep_p.packed is not None and rep_p.packed.n_groups == 2
-    assert rep_s.packed is None
-    for a, b in zip(rep_p.groups, rep_s.groups):
+    for a, b in zip(rep_p.groups, alone):
+        if timing is not None:
+            np.testing.assert_array_equal(a.result.n_cycles,
+                                          b.result.n_cycles)
+            assert a.measured_cycles == b.measured_cycles
         np.testing.assert_array_equal(a.result.n_instr, b.result.n_instr)
         np.testing.assert_array_equal(a.result.mix, b.result.mix)
         assert a.profile == b.profile
@@ -265,7 +266,7 @@ def test_packed_scheduler_beats_sequential_drain_on_skew():
                                                seq_lane_steps)
 
 
-@pytest.mark.parametrize("stepper", ["switch", "branchless", "pallas"])
+@pytest.mark.parametrize("stepper", ["branchless", "pallas"])
 def test_packed_preserves_oob_memory_semantics_per_group(stepper):
     """Data-memory out-of-range semantics are per-GROUP, not per-pool:
     a lane of a small-memory group packed next to a larger-memory group
@@ -424,29 +425,65 @@ def test_explicit_stepper_wins(monkeypatch, backend, faulted, stepper):
     assert asked["stepper"] == {stepper}
 
 
-@pytest.mark.parametrize("packed", [True, False],
-                         ids=["packed", "sequential"])
-def test_plan_reports_the_stepper_that_ran(monkeypatch, packed):
-    """`FleetPlan.stepper` None hands the choice to the engine on both
-    runtimes, and the report's results name the stepper that ran."""
+@pytest.mark.parametrize("n_groups", [1, 2],
+                         ids=["one-group", "two-groups"])
+def test_plan_reports_the_stepper_that_ran(monkeypatch, n_groups):
+    """`FleetPlan.stepper` None hands the choice to the engine, and the
+    report's results name the stepper that ran."""
     _spy_runners(monkeypatch, "tpu")
-    groups = (FleetGroup(workload="WQ", core="QERV", n_items=8, seed=1),)
+    groups = (FleetGroup(workload="WQ", core="QERV", n_items=8, seed=1),
+              FleetGroup(workload="MC", core="HERV", n_items=8,
+                         seed=2))[:n_groups]
     for faults, want in ((None, "pallas"), (_MILD, "branchless")):
         plan = FleetPlan(groups=groups, chunk=8, seg_steps=128,
-                         packed=packed, faults=faults)
+                         faults=faults)
         rep = run_plan(plan)
         assert plan.stepper is None
         assert {g.result.stepper for g in rep.groups} == {want}
-        if packed:
-            assert rep.packed.stepper == want
+        assert rep.packed.stepper == want
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("entry", ["run_stream", "run_workload_stream"])
+def test_stream_default_stepper_follows_the_engine_rule(monkeypatch,
+                                                        entry, backend):
+    """`run_stream` and `run_workload_stream` name no stepper by
+    default, so `_choose_stepper` decides for them as for `run_packed`:
+    the fused kernel on a TPU, the branchless stepper elsewhere."""
+    asked = _spy_runners(monkeypatch, backend)
+    if entry == "run_stream":
+        g = _skew_group()
+        r = engine.run_stream(g.code, g.source, n_items=g.n_items,
+                              mem_words=g.mem_words,
+                              max_steps=g.max_steps, chunk=8,
+                              seg_steps=64, out_addr=g.out_addr)
+    else:
+        r = engine.run_workload_stream(get("WQ"), 8, chunk=8,
+                                       seg_steps=128)
+    want = "pallas" if backend == "tpu" else "branchless"
+    assert r.stepper == want and asked["stepper"] == {want}
+
+
+@pytest.mark.parametrize("removed", ["switch", "refill"])
+def test_removed_paths_are_refused(removed):
+    """The segment steppers are the branchless and Pallas ones, and the
+    stream loop is the resident one: a `switch` stepper is refused with
+    the names of the steppers there are, a `refill=` keyword as an
+    unknown argument."""
+    if removed == "switch":
+        with pytest.raises(ValueError, match="'branchless', 'pallas'"):
+            run_packed([_skew_group()], chunk=8, stepper="switch")
+    else:
+        with pytest.raises(TypeError, match="refill"):
+            run_packed([_skew_group()], chunk=8, refill="host")
 
 
 @pytest.mark.slow
 def test_packed_sharded_multi_device_bit_exact():
     """Packed streaming under shard_map on 4 forced host devices stays
-    bit-exact with the sequential per-group baseline, for all three
-    steppers (lane fields prog_id/max_steps shard over the mesh; the
-    bank replicates)."""
+    bit-exact with each group streamed alone, for both steppers (lane
+    fields prog_id/max_steps shard over the mesh; the bank
+    replicates)."""
     script = r"""
 import numpy as np, jax, json
 from benchmarks.fleet import skew_fleet, skew_program
@@ -467,7 +504,7 @@ refs = [engine.run_stream(g.code, g.source, n_items=g.n_items,
                           mem_words=32, max_steps=100_000, chunk=16,
                           seg_steps=64, out_addr=1) for g in groups]
 mesh = jax.make_mesh((len(jax.devices()),), ("fleet",))
-for stepper in ("branchless", "pallas", "switch"):
+for stepper in ("branchless", "pallas"):
     res, stats = run_packed(groups, chunk=16, seg_steps=64, mesh=mesh,
                             stepper=stepper)
     assert stats.n_devices == 4, stats.n_devices

@@ -1,7 +1,10 @@
-"""Resident packed runtime (DESIGN.md §9.9): on-device refill parity
-with the PR-4 host-refill baseline (full state, three steppers), the
-banked Pallas refill swap, adaptive-superstep determinism and
-bit-exactness, sync-stats accounting, and the 4-device shard_map path."""
+"""Resident packed runtime (DESIGN.md §9.9): full-state parity with the
+monolithic per-group `iss.run_fleet` oracle (both steppers, timing and
+adaptive supersteps on and off), the banked Pallas refill swap,
+adaptive-superstep determinism and bit-exactness, sync-stats
+accounting, the on-demand flush of the int32 mix rows, and the
+4-device shard_map path."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -13,11 +16,15 @@ import numpy as np
 import pytest
 
 from benchmarks.fleet import skew_fleet, skew_program
+from repro.flexibench.base import get
 from repro.fleet import engine
 from repro.fleet.engine import (PackedGroup, _SuperstepController,
                                 run_packed)
 from repro.fleet.plan import FleetGroup, FleetPlan, run_plan
+from repro.fleet.report import build_group_report
 from repro.flexibits import iss
+from repro.flexibits.cycles import CORES, cost_row
+from repro.flexibits.faults import FaultSpec
 from repro.kernels.iss_stepper import iss_refill
 
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -26,61 +33,103 @@ _STATE_FIELDS = ("n_instr", "n_two_stage", "halted", "out", "mix",
                  "mems", "regs", "pc", "mix_items")
 
 
-def _skew_groups(n_a=40, n_b=24, max_steps_b=100_000):
+def _skew_mems(n_a=40, n_b=24):
     prog = skew_program()
-    mems_a = skew_fleet(prog, n_a, short_iters=8, long_iters=400,
-                        long_frac=0.2, seed=13)
-    mems_b = skew_fleet(prog, n_b, short_iters=16, long_iters=300,
-                        long_frac=0.3, seed=14)
+    return prog, (
+        skew_fleet(prog, n_a, short_iters=8, long_iters=400,
+                   long_frac=0.2, seed=13),
+        skew_fleet(prog, n_b, short_iters=16, long_iters=300,
+                   long_frac=0.3, seed=14))
+
+
+def _skew_groups(n_a=40, n_b=24, max_steps_b=100_000, costs=(None, None)):
+    prog, (mems_a, mems_b) = _skew_mems(n_a, n_b)
     return [
         PackedGroup(code=prog.code, source=engine.array_source(mems_a),
                     n_items=n_a, max_steps=100_000, mem_words=32,
-                    out_addr=1),
+                    out_addr=1, cost=costs[0]),
         PackedGroup(code=prog.code, source=engine.array_source(mems_b),
                     n_items=n_b, max_steps=max_steps_b, mem_words=32,
-                    out_addr=1),
+                    out_addr=1, cost=costs[1]),
     ]
 
 
-@pytest.mark.parametrize("stepper", ["switch", "branchless", "pallas"])
-def test_resident_bit_exact_with_host_refill(stepper):
-    """Full-state parity: the resident runtime retires, demuxes, and
-    keeps final state bit-exactly equal to the host-refill baseline —
-    including a group whose budget, not halting, ends its items."""
-    groups = _skew_groups(max_steps_b=200)
-    host, _ = run_packed(groups, chunk=16, seg_steps=64, keep_state=True,
-                         refill="host", stepper=stepper)
-    res, stats = run_packed(groups, chunk=16, seg_steps=64,
-                            keep_state=True, refill="device",
-                            stepper=stepper)
-    assert stats.refill == "device"
-    for a, b in zip(host, res):
-        for f in _STATE_FIELDS:
-            np.testing.assert_array_equal(getattr(a, f), getattr(b, f),
+def _oracle(code, mems, max_steps, out_addr, cost=None):
+    """One group's final state from the monolithic `iss.run_fleet`
+    (every item to its halt or budget in one vmapped while_loop), in
+    `FleetResult` field names."""
+    st = iss.run_fleet(jnp.asarray(np.asarray(code).view(np.int32)),
+                       jnp.asarray(mems), max_steps,
+                       None if cost is None else jnp.asarray(cost))
+    mem = np.asarray(st.mem)
+    mix_items = np.asarray(st.mix)
+    return {"n_instr": np.asarray(st.n_instr),
+            "n_two_stage": np.asarray(st.n_two_stage),
+            "halted": np.asarray(st.halted), "out": mem[:, out_addr],
+            "mix": mix_items.astype(np.int64).sum(0), "mems": mem,
+            "regs": np.asarray(st.regs), "pc": np.asarray(st.pc),
+            "mix_items": mix_items, "n_cycles": np.asarray(st.n_cycles)}
+
+
+def _assert_matches_oracle(results, groups, mems, timing=False):
+    for r, g, m in zip(results, groups, mems):
+        want = _oracle(g.code, m, g.max_steps, g.out_addr, g.cost)
+        for f in _STATE_FIELDS + (("n_cycles",) if timing else ()):
+            np.testing.assert_array_equal(getattr(r, f), want[f],
                                           err_msg=f)
-        assert not a.halted[a.n_instr == 200].any()   # budget-exhausted
+
+
+@pytest.mark.parametrize("adaptive", [False, True],
+                         ids=["fixed", "adaptive"])
+@pytest.mark.parametrize("timing", [False, True], ids=["off", "dynamic"])
+@pytest.mark.parametrize("stepper", ["branchless", "pallas"])
+def test_resident_bit_exact_with_host_refill(stepper, timing, adaptive):
+    """Full-state parity: the resident runtime retires, demuxes, and
+    keeps final state (and, with the timing layer on, every cycle tally)
+    bit-exactly equal to each group's monolithic `iss.run_fleet` —
+    including a group whose budget, not halting, ends its items."""
+    costs = (cost_row(CORES["SERV"], dynamic=True),
+             cost_row(CORES["HERV"], dynamic=True)) if timing \
+        else (None, None)
+    groups = _skew_groups(max_steps_b=200, costs=costs)
+    res, stats = run_packed(groups, chunk=16, seg_steps=64,
+                            keep_state=True, stepper=stepper,
+                            adaptive=adaptive)
+    assert stats.stepper == stepper and stats.adaptive == adaptive
+    _assert_matches_oracle(res, groups, _skew_mems()[1], timing)
+    assert not res[1].halted[res[1].n_instr == 200].any()
+    assert (res[1].n_instr == 200).any()              # budget-exhausted
 
 
 def test_resident_plan_report_matches_host_refill():
-    """run_plan floats (carbon, energy, profiles) are identical between
-    the resident and host-refill loops — the demux feeds the same
-    report path bit-for-bit."""
+    """run_plan's report (carbon, energy, profiles) is what the report
+    path makes of each group's monolithic `iss.run_fleet` tallies, to
+    the bit: the demux feeds it the oracle's numbers."""
     groups = (
         FleetGroup(workload="WQ", core="SERV", n_items=40, seed=1),
         FleetGroup(workload="MC", core="HERV", n_items=24, seed=2),
     )
-    rep_d = run_plan(FleetPlan(groups=groups, chunk=16, seg_steps=128))
-    rep_h = run_plan(FleetPlan(groups=groups, chunk=16, seg_steps=128,
-                               refill="host"))
-    assert rep_d.packed.refill == "device"
-    assert rep_h.packed.refill == "host"
-    for a, b in zip(rep_d.groups, rep_h.groups):
-        np.testing.assert_array_equal(a.result.n_instr, b.result.n_instr)
-        np.testing.assert_array_equal(a.result.mix, b.result.mix)
-        assert a.profile == b.profile
-        assert a.energy_j_per_exec == b.energy_j_per_exec
-        assert a.total_kg == b.total_kg
-    assert "sync stats (device-refill)" in rep_d.format()
+    rep = run_plan(FleetPlan(groups=groups, chunk=16, seg_steps=128))
+    for g, gr in zip(groups, rep.groups):
+        w = get(g.workload)
+        mems = engine.workload_source(w, g.seed)(0, g.n_items)
+        want = _oracle(w.program.code, mems, w.max_steps, w.out_addr)
+        for f in ("n_instr", "n_two_stage", "halted", "out", "mix"):
+            np.testing.assert_array_equal(getattr(gr.result, f), want[f],
+                                          err_msg=f)
+        ref = build_group_report(
+            group=g, workload=w, core=CORES[g.core],
+            result=dataclasses.replace(
+                gr.result, n_instr=want["n_instr"].astype(np.int64),
+                n_two_stage=want["n_two_stage"].astype(np.int64),
+                mix=want["mix"]),
+            lifetime_s=gr.lifetime_s, execs_per_day=gr.execs_per_day,
+            intensity=rep.intensity, clock_hz=10_000.0,
+            wcet_cycles=gr.wcet_cycles)
+        assert gr.profile == ref.profile
+        assert gr.energy_j_per_exec == ref.energy_j_per_exec
+        assert gr.total_kg == ref.total_kg
+    assert "sync stats" in rep.format()
 
 
 @pytest.mark.parametrize("stepper", ["branchless", "pallas"])
@@ -173,76 +222,135 @@ def test_pallas_refill_swap_matches_jnp_swap():
 
 
 def test_resident_syncs_fewer_than_host_refill():
-    """On a churny skewed fleet the resident loop performs strictly
-    fewer blocking host syncs (one small stats read per segment + one
-    drain) than the host-refill loop (done-count scalar per segment +
-    O(done) harvest pulls per finishing segment), and its sync stats
-    are populated sanely."""
-    _, sh = run_packed(_skew_groups(), chunk=16, seg_steps=64,
-                       refill="host")
-    _, sd = run_packed(_skew_groups(), chunk=16, seg_steps=64,
-                       refill="device")
-    assert sd.host_syncs < sh.host_syncs, (sd.host_syncs, sh.host_syncs)
-    # one stats read per iteration (segments + trailing) + 5 drain pulls
+    """On a churny skewed fleet the resident loop makes one small stats
+    read per loop iteration (each segment plus the trailing one) and
+    five drain reads, and nothing else; its sync stats are populated
+    sanely."""
+    _, sd = run_packed(_skew_groups(), chunk=16, seg_steps=64)
     assert sd.host_syncs == sd.n_segments + 1 + 5
-    for s in (sh, sd):
-        assert s.sync_wait_s >= 0.0 and s.refill_wall_s >= 0.0
-        assert len(s.seg_schedule) == s.n_segments
+    assert sd.sync_wait_s >= 0.0 and sd.refill_wall_s >= 0.0
+    assert len(sd.seg_schedule) == sd.n_segments
 
 
-def test_run_packed_rejects_bad_refill():
-    groups = _skew_groups()
-    with pytest.raises(ValueError):
-        run_packed(groups, refill="telepathy")
+def _flat_group(n, max_steps, mems=None):
+    prog = skew_program()
+    if mems is None:
+        mems = skew_fleet(prog, n, short_iters=8, long_iters=400,
+                          long_frac=0.2, seed=13)
+    return PackedGroup(code=prog.code, source=engine.array_source(mems),
+                       n_items=n, max_steps=max_steps, mem_words=32,
+                       out_addr=1)
 
 
 def test_resident_falls_back_to_host_past_safety_bounds():
-    """Past the int32 mix-counter bound (a group that COULD retire 2^31
-    instructions) or the keep_state device-row budget, the engine runs
-    the host loop instead of overflowing/allocating silently — and says
-    so in PackedStats.refill."""
-    prog = skew_program()
-    mems = skew_fleet(prog, 4, short_iters=4, long_iters=8,
-                      long_frac=0.5, seed=1)
-    big_budget = PackedGroup(code=prog.code,
-                             source=engine.array_source(mems), n_items=4,
-                             max_steps=2**30, mem_words=32, out_addr=1)
-    res, stats = run_packed([big_budget], chunk=4, seg_steps=32)
-    assert stats.refill == "host"
+    """A plan whose n_items x max_steps passes 2^31 - 1 (a group that
+    COULD retire more instructions than an int32 mix row holds), but
+    whose lanes-a-shard x max_steps does not, runs on the resident loop
+    and matches the same fleet under a small budget."""
+    n, chunk, big = 16, 4, 2**28
+    assert n * big > engine._RESIDENT_MIX_LIMIT >= chunk * big
+    res, _ = run_packed([_flat_group(n, big)], chunk=chunk, seg_steps=64)
+    ref, _ = run_packed([_flat_group(n, 100_000)], chunk=chunk,
+                        seg_steps=64)
     assert res[0].halted.all()
-    # a same-shape run under the bound stays resident
-    ok = PackedGroup(code=prog.code, source=engine.array_source(mems),
-                     n_items=4, max_steps=100_000, mem_words=32,
-                     out_addr=1)
-    _, stats = run_packed([ok], chunk=4, seg_steps=32)
-    assert stats.refill == "device"
+    for f in ("n_instr", "n_two_stage", "halted", "out", "mix"):
+        np.testing.assert_array_equal(getattr(res[0], f),
+                                      getattr(ref[0], f), err_msg=f)
+
+
+_MILD = FaultSpec(rate=0.0008, seed=5, targets=("regs", "mem", "pc"))
+
+
+@pytest.mark.parametrize("mode", ["branchless", "pallas", "faults", "dmr",
+                                  "mesh"])
+def test_mix_flush_is_bit_exact(monkeypatch, mode):
+    """With the mix-row bound lowered above lanes x budget but far below
+    items x budget, the loop flushes the rows to the host several times
+    a run, no row it reads ever passes the bound, and every result, the
+    mix totals included, is bit-exact with the run that needs no
+    flush."""
+    n, chunk, seg, budget = 64, 16, 64, 2000
+    kw = dict(chunk=chunk, seg_steps=seg, keep_state=True,
+              stepper="pallas" if mode == "pallas" else "branchless",
+              faults=_MILD if mode in ("faults", "dmr") else None,
+              redundancy="dmr" if mode == "dmr" else "none",
+              max_retries=6,
+              mesh=jax.make_mesh((1,), ("fleet",)) if mode == "mesh"
+              else None)
+    ref, rs = run_packed([_flat_group(n, budget)], **kw)
+    limit = chunk * budget + 3 * chunk * seg
+    assert limit < n * budget // 3
+    monkeypatch.setattr(engine, "_RESIDENT_MIX_LIMIT", limit)
+    rows = []                  # the largest mix row of each read
+    fetch = engine._SyncClock.fetch
+
+    def spy(clock, x):
+        out = fetch(clock, x)
+        if out.ndim == 3:      # (n_shards, n_groups, 8): the mix rows
+            rows.append(int(out.max()))
+        return out
+
+    monkeypatch.setattr(engine._SyncClock, "fetch", spy)
+    res, st = run_packed([_flat_group(n, budget)], **kw)
+    assert st.host_syncs - rs.host_syncs == len(rows) - 1 >= 3
+    assert max(rows) <= limit
+    assert st.n_segments == rs.n_segments
+    for f in _STATE_FIELDS:
+        np.testing.assert_array_equal(getattr(res[0], f),
+                                      getattr(ref[0], f), err_msg=f)
+
+
+def test_dmr_plan_past_the_old_mix_bound_runs():
+    """A DMR plan under transient faults whose n_items x max_steps
+    passes 2^31 - 1 runs on the resident loop and returns the
+    fault-free answers."""
+    n, chunk, big = 64, 16, 2**26
+    assert n * big > engine._RESIDENT_MIX_LIMIT >= chunk * big
+    gold, _ = run_packed([_flat_group(n, 100_000)], chunk=16, seg_steps=64)
+    dm, ds = run_packed([_flat_group(n, big)], chunk=chunk, seg_steps=64,
+                        faults=_MILD, redundancy="dmr", max_retries=6)
+    assert ds.detected > 0
+    for f in ("n_instr", "n_two_stage", "halted", "out", "mix"):
+        np.testing.assert_array_equal(getattr(dm[0], f),
+                                      getattr(gold[0], f), err_msg=f)
+
+
+@pytest.mark.parametrize("bound", ["keep_state", "mix_row"])
+def test_resident_refuses_plans_past_its_bounds(monkeypatch, bound):
+    """keep_state past its device-row bound, and a plan in which one
+    refill could wrap an int32 mix row (lanes a shard x budget past
+    2^31 - 1), are refused with the bound's name."""
+    if bound == "keep_state":
+        monkeypatch.setattr(engine, "_RESIDENT_KEEP_STATE_WORDS", 1000)
+        with pytest.raises(ValueError, match="_RESIDENT_KEEP_STATE_WORDS"):
+            run_packed([_flat_group(64, 2000)], chunk=16, keep_state=True)
+    else:
+        with pytest.raises(ValueError, match="_RESIDENT_MIX_LIMIT"):
+            run_packed([_flat_group(4, 2**30)], chunk=4)
 
 
 def test_resident_single_group_stream_parity():
-    """run_stream (the single-group special case) is bit-exact between
-    the resident and host-refill loops, including keep_state."""
+    """run_stream (the single-group special case) is bit-exact with the
+    monolithic `iss.run_fleet`, with fixed and adaptive supersteps,
+    including keep_state."""
     prog = skew_program()
     mems = skew_fleet(prog, 50, short_iters=8, long_iters=600,
                       long_frac=0.25, seed=3)
     kw = dict(n_items=50, mem_words=32, max_steps=100_000, chunk=16,
               seg_steps=64, out_addr=1, keep_state=True)
-    a = engine.run_stream(prog.code, engine.array_source(mems),
-                          refill="host", **kw)
-    b = engine.run_stream(prog.code, engine.array_source(mems),
-                          refill="device", **kw)
-    c = engine.run_stream(prog.code, engine.array_source(mems),
-                          refill="device", adaptive=True, **kw)
-    for f in _STATE_FIELDS:
-        np.testing.assert_array_equal(getattr(a, f), getattr(b, f),
-                                      err_msg=f)
-        np.testing.assert_array_equal(getattr(a, f), getattr(c, f),
-                                      err_msg=f)
+    want = _oracle(prog.code, mems, 100_000, 1)
+    for adaptive in (False, True):
+        r = engine.run_stream(prog.code, engine.array_source(mems),
+                              adaptive=adaptive, **kw)
+        for f in _STATE_FIELDS:
+            np.testing.assert_array_equal(getattr(r, f), want[f],
+                                          err_msg=f)
 
 
 @pytest.mark.slow
 def test_resident_adaptive_sharded_multi_device_bit_exact():
     """Resident + adaptive streaming under shard_map on 4 forced host
-    devices stays bit-exact with the host-refill baseline for all three
+    devices stays bit-exact with the single-device run for both
     steppers — FULL final state (mems/regs/pc/mix_items included) and
     per-group stats, not just the scalar tallies — and the adaptive
     schedule is identical across reruns. Shard-locally (§9.12): staged
@@ -269,15 +377,14 @@ groups = [
 ]
 FIELDS = ("n_instr", "n_two_stage", "halted", "out", "mix",
           "mems", "regs", "pc", "mix_items")
-refs, _ = run_packed(groups, chunk=16, seg_steps=64, refill="host",
-                     keep_state=True)
+refs, _ = run_packed(groups, chunk=16, seg_steps=64, keep_state=True)
 mesh = jax.make_mesh((len(jax.devices()),), ("fleet",))
-for stepper in ("branchless", "pallas", "switch"):
+for stepper in ("branchless", "pallas"):
     scheds = []
     for _ in range(2):
         res, stats = run_packed(groups, chunk=16, seg_steps=64,
                                 mesh=mesh, stepper=stepper,
-                                refill="device", adaptive=True,
+                                adaptive=True,
                                 keep_state=True)
         assert stats.n_devices == 4, stats.n_devices
         assert stats.n_shards == 4, stats.n_shards

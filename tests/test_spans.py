@@ -60,11 +60,9 @@ def plan(**kw):
     ), chunk=16, seg_steps=128, **kw)
 
 
-@pytest.mark.parametrize("refill", ["device", "host"])
-def test_fleet_spans_count_the_syncs_and_nest_in_the_job(tmp_path, refill):
-    rep, spans = traced(tmp_path, lambda: run_plan(plan(refill=refill)))
+def test_fleet_spans_count_the_syncs_and_nest_in_the_job(tmp_path):
+    rep, spans = traced(tmp_path, lambda: run_plan(plan()))
     stats = rep.packed
-    assert stats.refill == refill
     syncs = named(spans, "fleet.sync")
     assert len(syncs) == stats.host_syncs > 0
     job, stream = named(spans, "fleet.job"), named(spans, "fleet.stream")
@@ -77,21 +75,18 @@ def test_fleet_spans_count_the_syncs_and_nest_in_the_job(tmp_path, refill):
         "fleet.sync", "fleet.dispatch", "fleet.restock", "fleet.upload",
         "fleet.drain")]
     assert inside(loop, stream)
-    # one dispatch per segment, the resident loop's trailing one too
+    # one dispatch per segment, and the trailing one
     n_dispatch = len(named(spans, "fleet.dispatch"))
-    assert n_dispatch == stats.n_segments + (refill == "device")
+    assert n_dispatch == stats.n_segments + 1
     # the stats total the spans they are taken in
     sync_s = sum(e - s for s, e in syncs) / 1e9
     restock_s = sum(e - s for s, e in named(spans, "fleet.restock")) / 1e9
     assert stats.sync_wait_s <= sync_s + 1e-3
     assert stats.refill_wall_s <= restock_s + 1e-3
     assert stats.sync_wait_s >= 0.5 * sync_s - 1e-3
-    if refill == "device":
-        assert len(named(spans, "fleet.drain")) == 1
-        assert len(named(spans, "fleet.upload")) == n_dispatch
-        assert len(named(spans, "fleet.restock")) == n_dispatch + 1
-    else:
-        assert named(spans, "fleet.drain") == []
+    assert len(named(spans, "fleet.drain")) == 1
+    assert len(named(spans, "fleet.upload")) == n_dispatch
+    assert len(named(spans, "fleet.restock")) == n_dispatch + 1
 
 
 def test_fleet_checkpoint_is_a_span_of_the_stream(tmp_path):

@@ -213,28 +213,30 @@ def test_pallas_subset_segment_parity():
 
 
 def test_engine_stepper_ab_parity():
-    """run_stream is bit-exact across all three steppers (switch,
-    branchless, pallas), including full final state and the engine's
-    lane-step accounting."""
+    """run_stream is bit-exact with the monolithic `iss.run_fleet` on
+    both steppers (branchless, pallas), including full final state, and
+    the two agree on the engine's lane-step accounting."""
     from benchmarks.fleet import skew_fleet, skew_program
     from repro.fleet import array_source, run_stream
     prog = skew_program()
     mems = skew_fleet(prog, 48, short_iters=8, long_iters=900,
                       long_frac=0.25, seed=5)
+    mono = iss.run_fleet(jnp.asarray(prog.code.view(np.int32)),
+                         jnp.asarray(mems), 100_000)
     kw = dict(n_items=48, mem_words=32, max_steps=100_000, chunk=16,
               seg_steps=64, out_addr=1, keep_state=True)
-    a = run_stream(prog.code, array_source(mems), stepper="switch", **kw)
-    assert a.stepper == "switch"
+    lane_steps = set()
     for stepper in ("branchless", "pallas"):
         b = run_stream(prog.code, array_source(mems), stepper=stepper,
                        **kw)
-        np.testing.assert_array_equal(a.mems, b.mems)
-        np.testing.assert_array_equal(a.regs, b.regs)
-        np.testing.assert_array_equal(a.n_instr, b.n_instr)
-        np.testing.assert_array_equal(a.out, b.out)
-        np.testing.assert_array_equal(a.mix, b.mix)
-        assert a.lane_steps == b.lane_steps
+        np.testing.assert_array_equal(b.mems, np.asarray(mono.mem))
+        np.testing.assert_array_equal(b.regs, np.asarray(mono.regs))
+        np.testing.assert_array_equal(b.n_instr, np.asarray(mono.n_instr))
+        np.testing.assert_array_equal(b.out, np.asarray(mono.mem)[:, 1])
+        np.testing.assert_array_equal(b.mix_items, np.asarray(mono.mix))
+        lane_steps.add(b.lane_steps)
         assert b.stepper == stepper
+    assert len(lane_steps) == 1
 
 
 def test_prefetcher_preserves_stream_order():
@@ -396,7 +398,7 @@ mems = skew_fleet(prog, 64, short_iters=8, long_iters=400,
 mono = iss.run_fleet(jnp.asarray(prog.code.view(np.int32)),
                      jnp.asarray(mems), 100_000)
 mesh = jax.make_mesh((len(jax.devices()),), ("fleet",))
-for stepper in ("branchless", "pallas", "switch"):
+for stepper in ("branchless", "pallas"):
     res = run_stream(prog.code, array_source(mems), n_items=64,
                      mem_words=32, max_steps=100_000, chunk=16,
                      seg_steps=64, out_addr=1, keep_state=True,

@@ -1,9 +1,10 @@
 """Cycle-accurate timing differential tests (DESIGN.md §9.10).
 
 The per-lane timing layer must not disturb architectural state, and its
-tick tallies must be *exact*: every stepper (legacy lax.switch,
-branchless one-hot, fused Pallas segment — including the banked packed
-runtime with on-device refill) is stepped in lockstep against the PyISS
+tick tallies must be *exact*: every stepper (the scalar lax.switch
+reference, branchless one-hot, fused Pallas segment — including the
+banked packed runtime with on-device refill) is stepped in lockstep
+against the PyISS
 cycle oracle on random instruction soups and on all 11 FlexiBench
 workloads, comparing full architectural state AND per-lane cycle
 counters bit-for-bit across all three core widths.
@@ -41,7 +42,7 @@ except ModuleNotFoundError:
     HAVE_HYPOTHESIS = False
 
 CORE_NAMES = ("SERV", "QERV", "HERV")
-STEPPERS = ("switch", "branchless", "pallas")
+STEPPERS = ("branchless", "pallas")
 MEM_WORDS = 128
 # any u32 address in [MEM_WORDS*4, 2^31) exercises clamp-on-read /
 # drop-on-write; bit-31 addresses are outside the contract (iss.py)
